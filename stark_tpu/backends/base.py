@@ -62,6 +62,14 @@ class AdaptiveParts(NamedTuple):
     get_block: Any = None
 
 
+def annotate_dispatch(sample_stats: Dict[str, Any], dispatch_steps) -> None:
+    """Record the dispatch bound a run executed under in its sample stats
+    (0 = one monolithic device program).  Bounded and monolithic runs
+    draw different RNG streams from the same seed, so the bound must be
+    readable in the results themselves."""
+    sample_stats["dispatch_steps"] = int(dispatch_steps or 0)
+
+
 @runtime_checkable
 class SamplerBackend(Protocol):
     def run(

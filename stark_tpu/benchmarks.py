@@ -542,8 +542,9 @@ def bench_hier_logistic(
 
     16 vmapped chains measured 13.0 ESS/s vs 7.6 at 8 (2026-07-31);
     R-hat ~1.013 at this smoke budget is the depth-6 tree's honest
-    limit on the 1034-dim posterior (depth 7 runs past the runtime's
-    device-program limits at smoke scale) — the judged flagship path is
+    limit on the 1034-dim posterior (depth 7 was ruled out by a
+    device-program limit of the runtime this was tuned on, which the
+    v5e machine does not have) — the judged flagship path is
     the converged ChEES run in bench.py, this leg is the NUTS
     comparison.
     """
@@ -552,9 +553,9 @@ def bench_hier_logistic(
         jax.random.PRNGKey(seed), n, d, num_groups=groups
     )
     if backend is None:
-        # bound device programs on accelerators: the 450+300-step
-        # monolithic scan runs past the runtime's ~1-min device-program
-        # limit (measured fault at warmup 450; 600 total steps was fine)
+        # bounded device programs on accelerators (progress granularity;
+        # the bound predates PR 21, whose chip run found no
+        # device-program time limit on the v5e machine)
         on_accel = jax.devices()[0].platform != "cpu"
         backend = JaxBackend(dispatch_steps=100 if on_accel else None)
     post, wall = _timed(
@@ -600,9 +601,11 @@ def bench_consensus_logistic(
     data, _ = synth_logistic_data(jax.random.PRNGKey(seed), n, d)
 
     if sampler == "chees":
-        # bound device programs on accelerators (6 transitions x the
-        # 512-leapfrog warmup cap ~ the 3k-grad dispatch budget); on CPU
-        # the monolithic dispatch avoids per-segment overhead
+        # bounded device programs on accelerators (6 transitions x the
+        # 512-leapfrog warmup cap ~ 3k gradients a program; the bound
+        # predates PR 21, whose chip run found no device-program time
+        # limit); on CPU the monolithic dispatch avoids per-segment
+        # overhead
         dispatch = 6 if on_accel else None
 
         def run():
@@ -685,12 +688,11 @@ def bench_lmm(
     mk = FusedLinearMixedModelGrouped if on_accel else LinearMixedModel
     model = mk(num_features=d, num_groups=groups, num_random=2)
     data, _ = synth_lmm_data(jax.random.PRNGKey(seed), n, d, groups)
-    # d ~ 2*groups+... is large here; bound each device program so a single
-    # dispatch stays within the ~3k-grad-eval budget device execution
-    # limits allow at benchmark scale (50 x depth-8 trees measured a
-    # device fault): chees transitions can reach the 512-leapfrog warmup
-    # cap, so 6 transitions bound the worst case; NUTS depth-9 trees are
-    # 2^9 grads, so 6 transitions ~ 3k there too
+    # bounded device programs of ~3k gradients: chees transitions can
+    # reach the 512-leapfrog warmup cap, so 6 transitions bound the worst
+    # case; NUTS depth-9 trees are 2^9 grads, so 6 transitions ~ 3k there
+    # too (the bound predates PR 21, whose chip run found no
+    # device-program time limit on the v5e machine)
     backend = JaxBackend(dispatch_steps=6)
     if sampler == "chees":
         post, wall = _timed(
@@ -818,7 +820,7 @@ def bench_bnn_sghmc(
     # stays as a diagnostic column: its elevation measures mode structure
     # (cycle_mode_ratio ~7 = each warm restart lands in a distinct basin;
     # R-hat<1.01 would need every chain to visit and weight the same mode
-    # set — an O(100s-of-cycles) budget, BASELINE.md r4), not
+    # set — an O(100s-of-cycles) budget), not
     # non-convergence.  The gate is therefore measured accuracy against
     # the 0.5 chance floor: 0.75 sits below the 0.80-0.82 band measured
     # stable across a 4x chain-budget escalation.

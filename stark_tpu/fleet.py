@@ -828,9 +828,7 @@ class FleetProblemResult:
                 fn = self._cache[key] = jax.jit(
                     jax.vmap(jax.vmap(self.flat_model.constrain))
                 )
-            cpu = jax.local_devices(backend="cpu")[0]
-            with jax.default_device(cpu):
-                out = fn(jax.device_put(np.asarray(self.draws_flat), cpu))
+            out = fn(np.asarray(self.draws_flat))
             self._draws = {k: np.asarray(v) for k, v in out.items()}
         return self._draws
 

@@ -1,7 +1,7 @@
 """Cross-run performance ledger: append-only perf rows + a regression gate.
 
-The bench trajectory (BENCH_r0*.json) measured five rounds of the flagship
-and never compared any two of them — a perf regression would ship silently
+The first five driver captures of the flagship were never compared with
+one another — a perf regression would ship silently
 as long as the run still converged.  This module turns that trajectory
 into a *gate*: every bench (or any traced run) appends one schema'd JSONL
 row of its headline numbers to ``bench_artifacts/ledger.jsonl``, and
@@ -19,8 +19,8 @@ Row schema (``LEDGER_SCHEMA`` = 1)::
     note         str?   — freeform operator annotation
     git_sha / jax_version / jaxlib_version   — telemetry.provenance()
     platform / device_kind / device_count    — telemetry.device_info()
-    fingerprint  str?   — platform.hardware_fingerprint() (the autotuner's
-                          hardware comparability key; best-effort)
+    fingerprint  str    — platform.hardware_fingerprint() (the autotuner's
+                          hardware comparability key)
     profile      str?   — the active autotuned profile id (stark_tpu.profile),
                           or None when the run used default/explicit-env
                           knobs.  Rows with DIFFERENT profiles are distinct
@@ -156,14 +156,10 @@ def make_row(
     row.update(telemetry.provenance())
     info = telemetry.device_info()
     for k in ("platform", "device_kind", "device_count"):
-        if k in info:
-            row[k] = info[k]
-    try:
-        from . import platform as _platform
+        row[k] = info[k]
+    from . import platform as _platform
 
-        row["fingerprint"] = _platform.hardware_fingerprint()
-    except Exception:  # noqa: BLE001 — provenance must never fault a run
-        pass
+    row["fingerprint"] = _platform.hardware_fingerprint()
     # profile provenance is ALWAYS written (null-not-absent for new rows:
     # the column is part of the series key); a bench artifact that stamped
     # its own "profile" wins over the ambient application state, because
@@ -203,9 +199,8 @@ def make_row(
                 metrics[k] = v
         if bench.get("converged") is not None:
             metrics["converged"] = bool(bench["converged"])
-        for k in ("platform", "accelerator_fallback"):
-            if bench.get(k) is not None:
-                row[k] = bench[k]
+        if bench.get("platform") is not None:
+            row["platform"] = bench["platform"]
     row.update(metrics)
     return row
 

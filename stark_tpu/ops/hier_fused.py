@@ -4,12 +4,12 @@ The offset-path hierarchical likelihood (`logistic_offset_loglik`) leaves
 the group-intercept machinery to XLA: per gradient evaluation it gathers
 ``alpha[g]`` into a (C, N) offsets array, streams it into the kernel,
 streams a (C, N) residual back out, and segment-sums the residual into
-(C, G).  Measured on one v5e chip at the flagship shape (N=1M, C=32):
-the Pallas kernel itself runs 1.16 ms but the full potential gradient
-costs 19.3 ms — the XLA gather (11.9 ms), segment-sum scatter (16.6 ms),
-and the (C, N) intermediate streams all crawl at ~10 GB/s, an order of
-magnitude under the chip's ~330 GB/s streaming rate (commit-trailed
-microbenchmarks, BASELINE.md r3).
+(C, G).  Builder-measured before PR 1 on one v5e chip at the flagship
+shape (N=1M, C=32), not in the driver's ledger: the Pallas kernel itself
+ran 1.16 ms but the full potential gradient cost 19.3 ms — the XLA gather
+(11.9 ms), segment-sum scatter (16.6 ms), and the (C, N) intermediate
+streams all crawled at ~10 GB/s, an order of magnitude under the ~330 GB/s
+the offset kernel streamed at.
 
 This kernel removes every (C, N) intermediate.  Rows are PRE-SORTED by
 group (a one-time host-side permutation in ``prepare_data`` — the
@@ -42,7 +42,12 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 
-from .logistic_fused import _LOG_2PI, _default_lane_tile, _link_parts
+from .logistic_fused import (
+    _LOG_2PI,
+    _default_lane_tile,
+    _link_parts,
+    _resolve_interpret,
+)
 from .precision import (
     dot_precision as _dot_precision,
     stream_arg as _stream_arg,
@@ -184,8 +189,9 @@ def _check_chain_vmem(cpad, lane_tile, interpret, k_loc=0, q=1):
             f"chain batch C={cpad} at lane_tile={lane_tile} "
             f"(k_loc={k_loc}, q={q}) needs ~{need / 2**20:.1f} MB scoped "
             f"VMEM, more than the TPU core's ~16MB allows with headroom; "
-            f"reduce chains per device program or use the offset-path "
-            f"Fused model which tiles chains independently"
+            f"reduce chains per device program, halve the tile with "
+            f"STARK_GROUPED_LANE_TILE, or use the offset-layout Fused "
+            f"model, whose lane tile shrinks with the chain count"
         )
 
 
@@ -234,8 +240,7 @@ def _grouped_call(beta, alpha, xt, y, gl, first_gid, *, k_loc, lane_tile,
     beta: (C, D), alpha: (C, G) -> (val (C,), gbeta (C, D),
     galpha (C, G)).  C pads to a sublane multiple of 8.
     """
-    if interpret is None:
-        interpret = jax.default_backend() == "cpu"
+    interpret = _resolve_interpret(interpret)
     c, d = beta.shape
     g_total = alpha.shape[1]
     n = xt.shape[1]
@@ -435,8 +440,7 @@ def _grouped_lmm_call(beta, u, intercept, xt, zt, y, gl, first_gid, *,
                       k_loc, lane_tile, interpret):
     """beta (C, D), u (C, G, Q), intercept (C,) ->
     (ssr (C,), sum_resid (C,), gbeta (C, D), gu (C, G, Q))."""
-    if interpret is None:
-        interpret = jax.default_backend() == "cpu"
+    interpret = _resolve_interpret(interpret)
     c, d = beta.shape
     g_total, q = u.shape[1], u.shape[2]
     n = xt.shape[1]

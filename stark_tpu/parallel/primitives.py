@@ -2,8 +2,8 @@
 layer under every parallel composition (ROADMAP item 4).
 
 Before this module, `parallel/consensus.py`, `parallel/tempering.py`,
-`parallel/mesh.py`, and `backends/sharded.py` each re-imported
-`compat.shard_map` and hand-rolled their own spec/placement boilerplate —
+`parallel/mesh.py`, and `backends/sharded.py` each called
+`shard_map` and hand-rolled their own spec/placement boilerplate —
 four bespoke collective call sites whose compositions only worked by
 bespoke test matrix.  Following DrJAX ("Scalable and Differentiable
 MapReduce Primitives in JAX", PAPERS.md), everything they (and the fleet's
@@ -14,7 +14,7 @@ implementation:
     named mesh axis: ``jit(shard_map(fn))`` on a mesh, a plain
     ``jit(fn)`` identity fast path with no mesh (the vmapped lanes ARE
     the shards on one device).  The only place in the repo that touches
-    `compat.shard_map`.
+    `jax.shard_map`.
   * `reduce_tree`  — cross-shard reduction inside a mapped function
     (``lax.psum``/``pmax``/``pmin`` over the axis; identity with no
     axis), the MapReduce "reduce".
@@ -81,10 +81,10 @@ from typing import Any, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from .. import faults
-from ..compat import shard_map
 
 PyTree = Any
 

@@ -457,7 +457,7 @@ def resolve_port(cli_port: Optional[int] = None) -> Optional[int]:
     None/unset/empty/invalid → no server (the default-off contract).
 
     ``STARK_STATUS_PORT=0`` DISABLES the exporter — the repo-wide
-    ``=0 opts out`` env convention (STARK_PERF_LEDGER, STARK_COMPILE_CACHE,
+    ``=0 opts out`` env convention (STARK_PERF_LEDGER,
     STARK_STREAM_DIAG), and the opt-out a nested job needs when CI
     exports a port globally.  An explicit CLI ``--status-port 0`` still
     requests an ephemeral bind (a deliberate flag, not an inherited

@@ -137,8 +137,23 @@ def test_chain_vmem_guard():
 
     _check_chain_vmem(64, 8192, False)  # the flagship config: fine
     _check_chain_vmem(128, 8192, True)  # interpreter: no VMEM, no guard
-    with pytest.raises(ValueError, match="chains"):
+    with pytest.raises(ValueError, match="chains") as err:
         _check_chain_vmem(128, 8192, False)
+    # every remedy the message names exists
+    assert "STARK_GROUPED_LANE_TILE" in str(err.value)
+    assert "offset-layout Fused" in str(err.value)
+
+
+def test_interpret_mode_is_decided_in_one_place():
+    """None means "compiled, unless the default backend is the CPU" (this
+    suite); an explicit choice passes through.  Both kernel modules route
+    through the same function."""
+    from stark_tpu.ops import hier_fused, logistic_fused
+
+    assert logistic_fused._resolve_interpret(None) is True  # CPU suite
+    assert logistic_fused._resolve_interpret(False) is False
+    assert logistic_fused._resolve_interpret(True) is True
+    assert hier_fused._resolve_interpret is logistic_fused._resolve_interpret
 
 
 @pytest.mark.slow

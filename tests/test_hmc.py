@@ -74,3 +74,24 @@ def test_segmented_backend_matches_posterior():
     assert abs(float(s["mu"]["mean"]) - 4.4) < 1.0
     assert abs(float(s["tau"]["mean"]) - 3.6) < 1.2
     assert post.max_rhat() < 1.02
+    assert post.sample_stats["dispatch_steps"] == 130
+
+
+def test_dispatch_bound_is_recorded_and_never_invented(monkeypatch):
+    """The bound a run executed under rides in its sample stats (0 = one
+    monolithic program), and an unset bound stays unset on every platform:
+    nothing re-bounds a run behind the caller's back."""
+    from stark_tpu.backends import JaxBackend, ShardedBackend
+    from stark_tpu.backends.base import annotate_dispatch
+    from stark_tpu.parallel.mesh import make_mesh
+
+    stats = {}
+    annotate_dispatch(stats, None)
+    assert stats == {"dispatch_steps": 0}
+    annotate_dispatch(stats, 50)
+    assert stats == {"dispatch_steps": 50}
+    monkeypatch.delenv("STARK_DISPATCH_STEPS", raising=False)
+    assert JaxBackend().dispatch_steps is None
+    assert ShardedBackend(make_mesh()).dispatch_steps is None
+    monkeypatch.setenv("STARK_DISPATCH_STEPS", "7")
+    assert JaxBackend().dispatch_steps == 7

@@ -39,8 +39,7 @@ def test_row_carries_schema_provenance_and_metrics(tmp_path):
     row = ledger.make_row(
         source="test", config="c1",
         bench=_bench(10.0, device_idle_frac=0.05, overshoot_draws=46,
-                     diag_bytes_to_host=4900, platform="cpu",
-                     accelerator_fallback=True),
+                     diag_bytes_to_host=4900, platform="cpu"),
         note="hello",
     )
     ledger.append_row(row, str(p))
@@ -56,7 +55,6 @@ def test_row_carries_schema_provenance_and_metrics(tmp_path):
     assert read["overshoot_draws"] == 46
     assert read["diag_bytes_to_host"] == 4900
     assert read["converged"] is True
-    assert read["accelerator_fallback"] is True
 
 
 def test_non_finite_bench_values_become_null():

@@ -277,33 +277,27 @@ def test_block_post_failpoint_fires_after_checkpoint(tmp_path):
     assert meta["blocks_done"] == 1
 
 
-def test_compilation_cache_helper(tmp_path, monkeypatch):
-    """enable_compilation_cache: workdir-keyed default, env precedence,
-    STARK_COMPILE_CACHE override/disable."""
+def test_compilation_cache_helper(monkeypatch):
+    """enable_compilation_cache: JAX_COMPILATION_CACHE_DIR set -> nothing
+    is touched; unset -> the fixed <checkout>/.jax_cache, never a path
+    built from a workdir or a temporary name."""
     import jax
 
     from stark_tpu.platform import enable_compilation_cache
 
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     prev = jax.config.jax_compilation_cache_dir
     try:
-        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
-        monkeypatch.delenv("STARK_COMPILE_CACHE", raising=False)
-        d = str(tmp_path / "cache")
-        assert enable_compilation_cache(d) == d
-        assert jax.config.jax_compilation_cache_dir == d
-        assert os.path.isdir(d)
-        # an env-configured cache always wins and is never overridden
+        jax.config.update("jax_compilation_cache_dir", None)
+        # an env-configured cache is jax's own business: returned as the
+        # directory in effect, and no other directory is set in code
         monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/env/cache")
-        assert enable_compilation_cache(str(tmp_path / "x")) == "/env/cache"
-        assert jax.config.jax_compilation_cache_dir == d  # untouched
+        assert enable_compilation_cache() == "/env/cache"
+        assert jax.config.jax_compilation_cache_dir is None
         monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
-        # STARK_COMPILE_CACHE=0 disables library-level enabling
-        monkeypatch.setenv("STARK_COMPILE_CACHE", "0")
-        assert enable_compilation_cache(str(tmp_path / "y")) is None
-        # ...and a path value redirects it
-        override = str(tmp_path / "override")
-        monkeypatch.setenv("STARK_COMPILE_CACHE", override)
-        assert enable_compilation_cache(str(tmp_path / "z")) == override
-        assert jax.config.jax_compilation_cache_dir == override
+        fixed = os.path.join(repo, ".jax_cache")
+        assert enable_compilation_cache() == fixed
+        assert jax.config.jax_compilation_cache_dir == fixed
+        assert os.path.isdir(fixed)
     finally:
         jax.config.update("jax_compilation_cache_dir", prev)

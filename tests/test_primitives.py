@@ -172,7 +172,7 @@ def test_scan_shards_gather_forward_and_reverse():
     """Gather mode hands ``combine`` the shard-ordered totals and the
     strictly-before mask (strictly-after under ``reverse``) — the
     masked-sum combine reproduces the exclusive prefix per shard."""
-    from stark_tpu.compat import shard_map
+    from jax import shard_map
     from stark_tpu.parallel.primitives import scan_shards
 
     mesh = _mesh(4)
@@ -218,7 +218,7 @@ def test_scan_shards_replicated_ordered_slices():
     """Replicated mode returns shard s's contiguous slice of the full
     replicated sequence — gathering the per-shard slices along the shard
     axis reassembles the sequence exactly."""
-    from stark_tpu.compat import shard_map
+    from jax import shard_map
     from stark_tpu.parallel.primitives import scan_shards
 
     mesh = _mesh(4)
@@ -234,7 +234,7 @@ def test_scan_shards_replicated_ordered_slices():
 
 
 def test_scan_shards_mode_and_divisibility_errors():
-    from stark_tpu.compat import shard_map
+    from jax import shard_map
     from stark_tpu.parallel.primitives import scan_shards
 
     with pytest.raises(ValueError, match="combine"):
@@ -257,7 +257,7 @@ def test_scan_shards_comm_accounted_and_silenceable(tmp_path, monkeypatch):
     x axis size — the allgather); replicated mode moves nothing and
     emits nothing; STARK_COMM_TELEMETRY=0 silences the accounting with
     bit-identical results."""
-    from stark_tpu.compat import shard_map
+    from jax import shard_map
     from stark_tpu.parallel.primitives import scan_shards
     from stark_tpu.telemetry import RunTrace, read_trace, use_trace
 

@@ -10,7 +10,7 @@ import pytest
 from jax.sharding import PartitionSpec as P
 
 import stark_tpu
-from stark_tpu.compat import shard_map
+from jax import shard_map
 from stark_tpu.backends.jax_backend import JaxBackend
 from stark_tpu.backends.sharded import ShardedBackend
 from stark_tpu.model import flatten_model

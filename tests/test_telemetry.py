@@ -341,7 +341,7 @@ def test_summarize_trace_counts_restarts(tmp_path):
         tr.emit("chain_health", status="restart", attempt=1,
                 error="ChainHealthError: boom")
         tr.emit("chain_health", status="restart", attempt=2,
-                error="XlaRuntimeError: tunnel")
+                error="XlaRuntimeError: device")
         tr.emit("run_end", dur_s=2.0)
     s = summarize_trace(read_trace(str(p)))
     assert s["restarts"] == 2

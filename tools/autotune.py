@@ -471,10 +471,8 @@ def main(argv=None):
     # legs (or parity cells) under a previously emitted profile
     os.environ["STARK_PROFILE"] = "0"
 
-    from stark_tpu.platform import ensure_live_platform, hardware_fingerprint
-
-    ensure_live_platform()
     from stark_tpu import ledger, profile, telemetry
+    from stark_tpu.platform import hardware_fingerprint
 
     fingerprint = hardware_fingerprint()
     info = telemetry.device_info()
@@ -569,7 +567,7 @@ def main(argv=None):
         # 0.0) and ``converged`` carries the parity verdict
         row = ledger.make_row(
             source="tools/autotune.py",
-            config=f"autotune:{info.get('platform', 'unknown')}",
+            config=f"autotune:{info['platform']}",
             bench={
                 "value": None,
                 "converged": parity["ok"],

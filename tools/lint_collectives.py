@@ -12,9 +12,8 @@ This lint pins the invariant statically (mirroring
 ``tools/lint_failpoints.py``):
 
 1. AST-collect every call to a raw-collective name under ``stark_tpu/``.
-2. Fail on any call outside the allowed homes —
-   ``stark_tpu/parallel/primitives.py`` (the accounting layer itself)
-   and ``stark_tpu/compat.py`` (version-shim lookups, not dispatches).
+2. Fail on any call outside the allowed home,
+   ``stark_tpu/parallel/primitives.py`` (the accounting layer itself).
 
 ``lax.pmean`` / ``lax.pmax`` stay un-linted by design: they are
 in-kernel reductions over the chains axis whose traffic rides the same
@@ -38,10 +37,9 @@ _COLLECTIVE_FUNCS = frozenset({
 })
 
 #: repo-relative files allowed to touch raw collectives: the accounting
-#: layer itself, and the version shim that only RESOLVES the symbols
+#: layer itself
 _ALLOWED = frozenset({
     os.path.join("stark_tpu", "parallel", "primitives.py"),
-    os.path.join("stark_tpu", "compat.py"),
 })
 
 
