@@ -524,7 +524,6 @@ def main():
         )
         os.makedirs(workdir, exist_ok=True)
         run_trace = telemetry.RunTrace(trace_path)
-        span_rec = None  # installed inside the try below
         t0 = time.perf_counter()
 
         def on_progress(r):
@@ -610,39 +609,35 @@ def main():
                     file=sys.stderr,
                 )
         try:
-            # STARK_PROFILE_SPANS=1: record first-class span events
-            # into the bench trace (off by default — trace bytes
-            # unchanged).  Installed inside the try so the finally's
-            # uninstall is unskippable — a leaked recorder would
-            # re-emit every later leg's phases onto the closed trace
+            # STARK_PROFILE_SPANS=1: the program's spans are written as
+            # first-class span events into the bench trace (off by
+            # default — trace bytes unchanged)
             from stark_tpu import profiling as _profiling
 
-            span_rec = _profiling.maybe_record_spans(run_trace)
-            post = supervised_sample(
-                fused, data, workdir=workdir, chains=cc,
-                trace=run_trace,
-                kernel="chees", num_warmup=chees_warm,
-                map_init_steps=map_steps,
-                adapt_path=adapt_path,
-                # structural invariant: exports NEVER land on the
-                # import candidate, so the tracked bench_artifacts/
-                # copy cannot be dirtied even if the runner
-                # re-validation disagrees with the pre-check above
-                adapt_export_path=cache if adapt_path else None,
-                init_step_size=0.1, block_size=block,
-                max_blocks=math.ceil(chees_samp / block),
-                min_blocks=math.ceil(chees_samp / block),
-                rhat_target=0.0,  # full draw budget, no early stop
-                max_restarts=_env_int("BENCH_MAX_RESTARTS", 3),
-                progress_cb=on_progress,
-                time_budget_s=remaining,
-                seed=1,
-            )
+            with _profiling.span_events(run_trace):
+                post = supervised_sample(
+                    fused, data, workdir=workdir, chains=cc,
+                    trace=run_trace,
+                    kernel="chees", num_warmup=chees_warm,
+                    map_init_steps=map_steps,
+                    adapt_path=adapt_path,
+                    # structural invariant: exports NEVER land on the
+                    # import candidate, so the tracked bench_artifacts/
+                    # copy cannot be dirtied even if the runner
+                    # re-validation disagrees with the pre-check above
+                    adapt_export_path=cache if adapt_path else None,
+                    init_step_size=0.1, block_size=block,
+                    max_blocks=math.ceil(chees_samp / block),
+                    min_blocks=math.ceil(chees_samp / block),
+                    rhat_target=0.0,  # full draw budget, no early stop
+                    max_restarts=_env_int("BENCH_MAX_RESTARTS", 3),
+                    progress_cb=on_progress,
+                    time_budget_s=remaining,
+                    seed=1,
+                )
         finally:
             # the trace must close on the failure path too — the
             # chees-leg except below otherwise leaks the handle
-            if span_rec is not None:
-                span_rec.uninstall()
             run_trace.close()
         wall = time.perf_counter() - t0
         budget_hit = getattr(post, "budget_exhausted", False)

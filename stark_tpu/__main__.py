@@ -57,19 +57,15 @@ def _traced(args):
     if not path and server is None:
         yield None
         return
-    from .profiling import maybe_record_spans
+    from .profiling import span_events
     from .telemetry import RunTrace, use_trace
 
-    with RunTrace(path if path else None) as tr, use_trace(tr):
-        # STARK_PROFILE_SPANS=1: re-emit the derived timeline as
-        # first-class ``span`` events (tools/timeline_report.py reads
-        # them; off by default — traces stay byte-identical)
-        spans = maybe_record_spans(tr)
-        try:
-            yield tr
-        finally:
-            if spans is not None:
-                spans.uninstall()
+    # STARK_PROFILE_SPANS=1: the program's spans are written as
+    # first-class ``span`` events (tools/timeline_report.py reads them;
+    # off by default — traces stay byte-identical)
+    with RunTrace(path if path else None) as tr, use_trace(tr), \
+            span_events(tr):
+        yield tr
     if path:
         log.info("trace written to %s", path)
 

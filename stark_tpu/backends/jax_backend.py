@@ -274,7 +274,8 @@ class JaxBackend:
             collect=lambda t: jax.tree.map(np.asarray, t),
         )
         if cfg.kernel == "chees":
-            from ..chees import make_chees_parts
+            from ..chees import CHEES_PROGRAMS, make_chees_parts
+            from ..platform import named_jit
 
             parts = self._cached(
                 model, cfg, "chees_parts", lambda: make_chees_parts(fm, cfg)
@@ -289,7 +290,9 @@ class JaxBackend:
                 # data-ness is part of the key: the wrapper's arity differs
                 return self._cached(
                     model, cfg, ("chees_j", tag, data is None, donate),
-                    lambda: jax.jit(wrapped, donate_argnums=donate),
+                    lambda: named_jit(
+                        wrapped, CHEES_PROGRAMS[tag], donate_argnums=donate
+                    ),
                 )
 
             def samp_diag(donate=False):

@@ -60,7 +60,19 @@ from .kernels.chees import (
     init_ensemble,
 )
 from .model import Model, flatten_model, prepare_model_data
+from .platform import named_jit
 from .sampler import Posterior, SamplerConfig, _constrain_draws
+
+#: the fixed names of the ensemble sampler's compiled programs (`named_jit`),
+#: by the tag the backends build them under: what a trace reduction keys on.
+#: The sampling program has one name with and without the streaming
+#: diagnostics carry; a run compiles one of the two.
+CHEES_PROGRAMS = {
+    "init": "stark_chees_init",
+    "warm": "stark_chees_warm",
+    "samp": "stark_chees_sample",
+    "samp_diag": "stark_chees_sample",
+}
 
 
 class AdamState(NamedTuple):
@@ -527,9 +539,12 @@ def run_chees(
         seed=seed,
         init_params=init_params,
         dispatch_steps=dispatch_steps,
-        init_j=cached("chees_init", lambda: jax.jit(parts.init_carry)),
-        warm_j=cached("chees_warm", lambda: jax.jit(parts.warm_segment)),
-        samp_j=cached("chees_sample", lambda: jax.jit(parts.sample_segment)),
+        init_j=cached("chees_init", lambda: named_jit(
+            parts.init_carry, CHEES_PROGRAMS["init"])),
+        warm_j=cached("chees_warm", lambda: named_jit(
+            parts.warm_segment, CHEES_PROGRAMS["warm"])),
+        samp_j=cached("chees_sample", lambda: named_jit(
+            parts.sample_segment, CHEES_PROGRAMS["samp"])),
         extra=(data,),
         put_z0=put,
         put_aux=put,

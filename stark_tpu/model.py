@@ -21,6 +21,7 @@ from typing import Any, Callable, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from . import telemetry
 from .bijectors import Bijector, Identity
 from .tree import make_unflatten
 
@@ -127,7 +128,16 @@ def prepare_model_data(model: Model, data: PyTree) -> PyTree:
     (the fused Pallas models crash on a missing ``xT``)."""
     if data is None:
         return None
-    return jax.tree.map(jnp.asarray, model.prepare_data(data))
+    with telemetry.span("prepare_data", model=type(model).__name__) as sp:
+        out = jax.block_until_ready(
+            jax.tree.map(jnp.asarray, model.prepare_data(data))
+        )
+        sp.note(bytes_in=_tree_nbytes(data), bytes_out=_tree_nbytes(out))
+    return out
+
+
+def _tree_nbytes(tree: PyTree) -> int:
+    return sum(int(getattr(x, "nbytes", 0)) for x in jax.tree.leaves(tree))
 
 
 class Potential:

@@ -42,6 +42,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 
+from .. import telemetry
 from .logistic_fused import (
     _LOG_2PI,
     _default_lane_tile,
@@ -134,32 +135,45 @@ def prepare_grouped(data, d_eff, transpose_keys=("x",)):
     None when `grouped_layout` finds no workable tile (caller falls back
     to the offset-path layout).
     """
-    g = np.asarray(data["g"])
-    order = np.argsort(g, kind="stable")
-    layout = grouped_layout(g[order], d_eff)
-    if layout is None:
-        return None
-    lane_tile, k_loc, first_gid, gl = layout
-    out = {
-        k: jnp.asarray(np.asarray(v)[order])
-        for k, v in data.items()
-        if k not in transpose_keys
-    }
+    # the three legs of the host round trip, each a span under the
+    # caller's `prepare_data`: rows down, the stable sort by group and the
+    # re-ordered (transposed) copies, rows up
+    with telemetry.span("prepare_data.to_host") as sp:
+        host = {k: np.asarray(v) for k, v in data.items()}
+        sp.note(bytes=sum(v.nbytes for v in host.values()))
+    with telemetry.span("prepare_data.sort", rows=int(host["g"].shape[0])):
+        g = host["g"]
+        order = np.argsort(g, kind="stable")
+        layout = grouped_layout(g[order], d_eff)
+        if layout is None:
+            return None
+        lane_tile, k_loc, first_gid, gl = layout
+        host = {
+            k: v[order].T if k in transpose_keys else v[order]
+            for k, v in host.items()
+        }
     xdt = _x_stream_dtype()
     from .quantize import is_packed_dtype, pack_slab
 
-    for k in transpose_keys:
-        slab = jnp.asarray(np.asarray(data[k])[order].T)
-        if is_packed_dtype(xdt):
-            # per-column calibrated scales ride next to each packed slab
-            # (ops/quantize.py); the models fold them into the parameter
-            # operands (beta for xT, the u windows for zT), so the
-            # kernel streams packed bytes untouched
-            out[k + "T"], out[k + "T_scale"] = pack_slab(
-                slab.astype(jnp.float32), xdt
-            )
-        else:
-            out[k + "T"] = slab.astype(xdt)
+    with telemetry.span("prepare_data.to_device") as sp:
+        out = {
+            k: jnp.asarray(v) for k, v in host.items()
+            if k not in transpose_keys
+        }
+        for k in transpose_keys:
+            slab = jnp.asarray(host[k])
+            if is_packed_dtype(xdt):
+                # per-column calibrated scales ride next to each packed
+                # slab (ops/quantize.py); the models fold them into the
+                # parameter operands (beta for xT, the u windows for zT),
+                # so the kernel streams packed bytes untouched
+                out[k + "T"], out[k + "T_scale"] = pack_slab(
+                    slab.astype(jnp.float32), xdt
+                )
+            else:
+                out[k + "T"] = slab.astype(xdt)
+        jax.block_until_ready(out)
+        sp.note(bytes=sum(int(v.nbytes) for v in out.values()))
     out["gl"] = jnp.asarray(gl)
     out["first_gid"] = jnp.asarray(first_gid)
     # static window size and lane tile ride in SHAPES (never values)
@@ -292,6 +306,7 @@ def _grouped_call(beta, alpha, xt, y, gl, first_gid, *, k_loc, lane_tile,
         out_specs=out_specs,
         out_shape=out_shape,
         interpret=interpret,
+        name="stark_hier_ll_grouped",
     )(*args)
     val = jnp.sum(out[0], axis=0)[:c, 0]
     gbeta = jnp.sum(out[1], axis=0)[:c]
@@ -495,6 +510,7 @@ def _grouped_lmm_call(beta, u, intercept, xt, zt, y, gl, first_gid, *,
         out_specs=out_specs,
         out_shape=out_shape,
         interpret=interpret,
+        name="stark_lmm_ll_grouped",
     )(*args)
     acc = jnp.sum(out[0], axis=0)  # (cpad, 2)
     ssr, sresid = acc[:c, 0], acc[:c, 1]

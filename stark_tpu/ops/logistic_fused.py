@@ -246,6 +246,7 @@ def _batched_call(beta, xt, y, offsets, *, lane_tile, interpret,
         out_specs=out_specs,
         out_shape=out_shape,
         interpret=interpret,
+        name="stark_logistic_ll",
     )(*args)
     val = jnp.sum(out[0], axis=0)[:c, 0]
     grad = jnp.sum(out[1], axis=0)[:c]
@@ -304,6 +305,7 @@ def _fused_call(beta, xt, y, offsets, *, lane_tile, interpret,
         out_specs=out_specs,
         out_shape=out_shape,
         interpret=interpret,
+        name="stark_logistic_ll_1chain",
     )(*args)
     val, grad = jnp.sum(out[0]), jnp.sum(out[1], axis=0)[:, 0]
     if offsets is not None:

@@ -20,6 +20,27 @@ _REPO_CACHE_DIR = os.path.join(
 )
 
 
+def named_jit(fn, name: str, **jit_kwargs):
+    """``jax.jit(fn, **jit_kwargs)`` under a fixed program name.  jax
+    names a compiled program after the function's ``__name__``
+    (``jit_<name>``: the event of the profiler's modules line, the HLO
+    module, the compile log), and a trace reduction that keys on it has
+    to survive a refactor of the function behind it.  The names in use:
+    ``stark_chees_init`` / ``stark_chees_warm`` / ``stark_chees_sample``
+    (the ensemble sampler's three programs) and ``stark_constrain`` (the
+    final layout of all draws)."""
+    import functools
+
+    import jax
+
+    @functools.wraps(fn)
+    def call(*args, **kwargs):
+        return fn(*args, **kwargs)
+
+    call.__name__ = call.__qualname__ = name
+    return jax.jit(call, **jit_kwargs)
+
+
 def enable_compilation_cache() -> str:
     """Make sure jax's persistent compilation cache has a directory and
     return the one in effect.  Called by every entry point that compiles

@@ -20,13 +20,13 @@ layer:
     stamps into perf-ledger rows.  Works on ANY trace, including
     pre-PR-11 files (missing fields degrade to coarser attribution,
     never an error).
-  * **``span`` event family** — `SpanRecorder` is a telemetry event
-    listener that re-emits the derived spans as first-class ``span``
-    trace events (registered in `telemetry.ALL_EVENT_TYPES`) onto the
-    same trace, so downstream consumers can read attribution without
-    re-deriving it.  Opt-in (``STARK_PROFILE_SPANS=1`` or an explicit
-    `record_spans`): with the recorder off, traces are byte-identical
-    to historical behavior.
+  * **``span`` event family** — the program measures real spans at the
+    site (`telemetry.span`: start, end, parent, run; always on, in
+    memory).  `span_events` writes them onto a trace as first-class
+    ``span`` events (registered in `telemetry.ALL_EVENT_TYPES`) where
+    ``STARK_PROFILE_SPANS=1`` asks for them; with the knob off, traces
+    are byte-identical to historical behavior and `spans_from_events`
+    falls back to subtracting durations from emission times.
   * **`DispatchProbe`** — the PR 8 ``benchmarks._GradEvalProbe``
     promoted to a first-class, installable dispatch-count probe: wraps
     a FlatModel's bound potential (``bind``) or any callable
@@ -55,19 +55,17 @@ from . import telemetry
 __all__ = [
     "DispatchProbe",
     "SPAN_KINDS",
-    "SpanRecorder",
     "deregister_probe",
     "get_probe",
-    "maybe_record_spans",
     "probe_counts",
-    "record_spans",
     "register_probe",
+    "span_events",
     "spans_from_events",
     "timeline_summary",
     "timeline_summary_from_file",
 ]
 
-#: opt-in knob for live ``span`` event emission (`maybe_record_spans`)
+#: opt-in knob for ``span`` event emission (`span_events`)
 PROFILE_SPANS_ENV = "STARK_PROFILE_SPANS"
 
 #: span kinds, in the order the per-block decomposition emits them.
@@ -213,8 +211,8 @@ def spans_from_events(
     """Build the non-overlapping span timeline for one run.
 
     Uses literal ``span`` events when the writer emitted them
-    (`SpanRecorder`), otherwise synthesizes spans from the phase
-    events.  Overlapping phases (the fleet's warmup blocks nest inside
+    (`span_events`: the program's own spans), otherwise synthesizes
+    spans from the phase events.  Overlapping phases (the fleet's warmup blocks nest inside
     its ``compile`` setup phase) are resolved in emission order —
     inner phases end (and are emitted) first, so they claim their
     interval and the outer phase keeps only its unclaimed remainder.
@@ -250,12 +248,12 @@ def spans_from_events(
                 and en > s and isinstance(e.get("kind"), str)
             ):
                 sp = {"kind": e["kind"], "start": float(s), "end": float(en)}
-                for k in ("src", "block", "stage", "gap"):
+                for k in ("src", "block", "stage", "gap", "id", "parent"):
                     if e.get(k) is not None:
                         sp[k] = e[k]
                 raw.append(sp)
-        # emission order == end order for the live recorder too
-        raw.sort(key=lambda sp: sp["end"])
+        # inner spans claim first: by end, the later start first
+        raw.sort(key=lambda sp: (sp["end"], -sp["start"]))
     else:
         # prev_end: wall clock of the latest phase-event completion seen
         # so far — the cursor the block-loop gap attribution (below)
@@ -418,100 +416,68 @@ def timeline_summary_from_file(
     return timeline_summary(events, run=run)
 
 
-class SpanRecorder:
-    """Event listener re-emitting derived spans as ``span`` trace events.
-
-    Subscribes to the telemetry fan-out and, for every phase event it
-    observes, emits the decomposed spans back onto the SAME trace as
-    ``span`` events (``kind`` / ``start_s`` / ``end_s`` / ``dur_s`` +
-    the source event's block/stage tags).  Its own ``span`` records are
-    skipped on re-entry, so the recursion is depth-one by construction.
-    Opt-in: nothing installs one unless `record_spans` /
-    `maybe_record_spans` is called, keeping default traces byte-
-    identical to historical behavior.
-    """
-
-    def __init__(self, trace):
-        self._trace = trace
-        self._installed = False
-        # latest phase-event completion seen: the cursor for the same
-        # block-loop gap attribution the synthesized path applies, so
-        # literal and synthesized timelines agree on coverage
-        self._prev_end: Optional[float] = None
-
-    def install(self) -> "SpanRecorder":
-        if not self._installed:
-            telemetry.add_event_listener(self.on_event)
-            self._installed = True
-        return self
-
-    def uninstall(self) -> None:
-        if self._installed:
-            telemetry.remove_event_listener(self.on_event)
-            self._installed = False
-
-    def _emit_span(self, sp: Dict[str, Any]) -> None:
-        fields = {
-            "kind": sp["kind"],
-            "start_s": round(sp["start"], 4),
-            "end_s": round(sp["end"], 4),
-            "dur_s": round(sp["end"] - sp["start"], 4),
-            "src": sp.get("src"),
-        }
-        for k in ("block", "stage", "gap"):
-            if sp.get(k) is not None:
-                fields[k] = sp[k]
-        self._trace.emit("span", **fields)
-
-    def on_event(self, rec: Dict[str, Any]) -> None:
-        if rec.get("event") == "span":
-            return
-        if rec.get("event") == "run_start":
-            self._prev_end = None
-        spans = _spans_from_phase_event(rec)
-        if not spans:
-            return
-        s0 = min(sp["start"] for sp in spans)
-        if (
-            rec.get("event") in _BLOCK_EVENTS
-            and self._prev_end is not None
-            and s0 > self._prev_end
-        ):
-            # same pipelined-enqueue gap rule as spans_from_events —
-            # without it, turning the recorder ON would lower the
-            # coverage number versus the synthesized read path
-            self._emit_span({"kind": "dispatch", "start": self._prev_end,
-                             "end": s0, "src": rec.get("event"),
-                             "gap": True})
-        for sp in spans:
-            self._emit_span(sp)
-        end = rec.get("wall_s")
-        if isinstance(end, (int, float)):
-            self._prev_end = (
-                float(end) if self._prev_end is None
-                else max(self._prev_end, float(end))
-            )
+#: span name (`telemetry.span`) -> kind of the ``span`` event written for
+#: it; a name that is not here is ``host``.  The root ``run`` span is not
+#: written: it would claim the whole run and hide what no span covers.
+_KIND_OF_SPAN = {
+    "compile": "compile",
+    "map_init": "warmup",
+    "warmup": "warmup",
+    "warmup_block": "warmup",
+    "block.dispatch": "dispatch",
+    "block.wait": "dispatch",
+    "sample_block": "dispatch",
+    "fleet_block": "dispatch",
+    "block.checkpoint": "checkpoint",
+    "checkpoint": "checkpoint",
+}
 
 
 @contextlib.contextmanager
-def record_spans(trace) -> Iterator[SpanRecorder]:
-    """Scoped live span recording onto ``trace``."""
-    rec = SpanRecorder(trace).install()
+def span_events(trace) -> Iterator[None]:
+    """``STARK_PROFILE_SPANS=1`` (and a real trace): write the program's
+    spans onto ``trace`` as ``span`` events — those that closed inside
+    this block, at each ``run_end`` (so that they carry their run's
+    ordinal) and at the block's exit.  Start, end and parent are the
+    span's own; nothing is derived.  The CLI/bench wiring point."""
+    if os.environ.get(PROFILE_SPANS_ENV, "") != "1" or not getattr(
+            trace, "enabled", False):
+        yield
+        return
+    written = (telemetry.span_log() or [None])[-1]  # the newest one out
+    zero_ns = int(trace.clock_zero() * 1e9)
+
+    def flush(rec=None):
+        nonlocal written
+        if rec is not None and rec.get("event") != "run_end":
+            return
+        log = telemetry.span_log()
+        first = next((i + 1 for i in range(len(log) - 1, -1, -1)
+                      if log[i] is written), 0)
+        written = (log or [written])[-1]
+        for sp in log[first:]:
+            if sp.name == "run" and sp.parent is None:
+                continue
+            fields = {
+                "kind": _KIND_OF_SPAN.get(sp.name, "host"),
+                "start_s": round((sp.start_ns - zero_ns) / 1e9, 4),
+                "end_s": round((sp.end_ns - zero_ns) / 1e9, 4),
+                "dur_s": round((sp.end_ns - sp.start_ns) / 1e9, 4),
+                "src": sp.name,
+                "id": sp.id,
+                "parent": sp.parent,
+            }
+            for k in ("block", "stage", "compile_s", "error"):
+                if sp.fields.get(k) is not None:
+                    fields[k] = sp.fields[k]
+            trace.emit("span", **fields)
+
+    telemetry.add_event_listener(flush)
     try:
-        yield rec
+        yield
     finally:
-        rec.uninstall()
-
-
-def maybe_record_spans(trace) -> Optional[SpanRecorder]:
-    """Install a `SpanRecorder` iff ``STARK_PROFILE_SPANS=1`` (and the
-    trace is a real one).  Returns the recorder (caller owns uninstall)
-    or None — the CLI/bench wiring point."""
-    if os.environ.get(PROFILE_SPANS_ENV, "") != "1":
-        return None
-    if trace is None or not getattr(trace, "enabled", False):
-        return None
-    return SpanRecorder(trace).install()
+        telemetry.remove_event_listener(flush)
+        flush()
 
 
 # ---------------------------------------------------------------------------

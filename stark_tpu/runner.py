@@ -153,7 +153,9 @@ def sample_until_converged(model: Model, data: Any = None, **kwargs):
     autotuned profile's knob defaults for the run — stark_tpu.profile;
     explicit env always wins, STARK_PROFILE=0 disables)."""
     trace = telemetry.resolve_trace(kwargs.pop("trace", None))
-    with telemetry.use_trace(trace):
+    with telemetry.use_trace(trace), telemetry.run_span(
+        resumed=bool(kwargs.get("resume_from"))
+    ):
         if lineage.enabled():
             # single-run lineage parity: one ambient job for the whole
             # run (the supervisor's outer job wins, so every restart
@@ -445,7 +447,7 @@ def _sample_until_converged(
             """Warmup-phase checkpoint: the full CheesWarmCarry, so a
             fault mid-warmup resumes at the last finished segment instead
             of burning the whole (dominant) warmup budget again."""
-            t_ckpt = time.perf_counter()
+            ckpt_span = telemetry.span("block.checkpoint", stage="warmup").open()
             from .checkpoint import save_checkpoint
 
             # ap.collect (gather_draws on a mesh) materializes the
@@ -493,13 +495,14 @@ def _sample_until_converged(
                     "model": type(model).__name__,
                 },
             )
+            ckpt_span.close()
             if trace.enabled:
                 trace.emit(
                     "checkpoint",
                     stage="warmup",
                     warm_done=done,
                     path=path,
-                    dur_s=round(time.perf_counter() - t_ckpt, 4),
+                    dur_s=round(ckpt_span.seconds, 4),
                 )
 
         def run_chees_touchup(carry, key_warm):
@@ -731,7 +734,8 @@ def _sample_until_converged(
             rec["resumed_from_step"] = int(resumed_from)
         if adapt_imported:
             rec["adapt_imported"] = True
-        emit(rec)
+        with telemetry.span("block.record", event="warmup_done"):
+            emit(rec)
         if trace.enabled:
             trace.emit(
                 "chain_health",
@@ -745,9 +749,16 @@ def _sample_until_converged(
     budget_exhausted = False
     history = []
     draw_blocks = []
+    # a resumed run's way to its first dispatch, as one span: checkpoint
+    # load, state restore, the rebuild of the streaming statistics from the
+    # stored draws (closed where the block loop starts)
+    resume_span = None
     if resume_from:
         from .checkpoint import load_checkpoint
 
+        resume_span = telemetry.span(
+            "resume_load", bytes_read=os.path.getsize(resume_from)
+        ).open()
         arrays, meta = load_checkpoint(resume_from)
         ckpt_kernel = meta.get("kernel")
         if ckpt_kernel is None and is_chees:
@@ -815,14 +826,18 @@ def _sample_until_converged(
             key_warm = jnp.asarray(arrays["key_warm"])
             if reseed is not None:
                 key_warm = jax.random.fold_in(key_warm, reseed)
-            carry, n_div, n_warm_leap = run_chees_warmup(
-                carry,
-                int(meta["warm_done"]),
-                key,
-                key_warm,
-                int(meta.get("warm_div", 0)),
-                int(meta.get("warm_leap", 0)),
-            )
+            with telemetry.span(
+                "warmup", steps=cfg.num_warmup - int(meta["warm_done"])
+            ) as warm_span:
+                carry, n_div, n_warm_leap = run_chees_warmup(
+                    carry,
+                    int(meta["warm_done"]),
+                    key,
+                    key_warm,
+                    int(meta.get("warm_div", 0)),
+                    int(meta.get("warm_leap", 0)),
+                )
+                warm_span.note(grad_evals=int(n_warm_leap) * chains)
             run_carry = parts.finalize(carry)
             state = run_carry.states
             step_size = jnp.exp(run_carry.log_eps)
@@ -865,26 +880,39 @@ def _sample_until_converged(
                 # (n, chains, d) on disk -> (chains, n, d) in memory
                 draw_blocks = [np.ascontiguousarray(stored.transpose(1, 0, 2))]
     else:
-        key = jax.random.PRNGKey(seed)
-        key, key_init, key_warm = jax.random.split(key, 3)
-        warm_import = None
+        # a span, no phase event: the run's keys and the ensemble's start
+        # positions (the per-chain path has its `chain_init` phase below)
+        with telemetry.span("compile", stage="chain_init"):
+            key = jax.random.PRNGKey(seed)
+            key, key_init, key_warm = jax.random.split(key, 3)
+            warm_import = None
+            if is_chees:
+                warm_import = load_adapt_import()
+                if warm_import is not None:
+                    # imported adaptation: start AT the saved typical-set
+                    # positions; the short touch-up below replaces the
+                    # full warmup (docstring: adapt_path)
+                    z0 = ap.put_chains(jnp.asarray(warm_import["z"]))
+                else:
+                    z0 = ap.put_chains(
+                        chees_init_positions(
+                            fm, key_init, chains, init_params
+                        )
+                    )
         if is_chees:
-            warm_import = load_adapt_import()
-            if warm_import is not None:
-                # imported adaptation: start AT the saved typical-set
-                # positions; the short touch-up below replaces the full
-                # warmup (docstring: adapt_path)
-                z0 = ap.put_chains(jnp.asarray(warm_import["z"]))
-            else:
-                z0 = ap.put_chains(
-                    chees_init_positions(fm, key_init, chains, init_params)
-                )
             # init dispatch = first compile + MAP descent (map_init_steps)
             with trace.phase("compile", stage="init+map",
                              map_init_steps=cfg.map_init_steps):
-                carry = jax.block_until_ready(
-                    chees_init_j(key_init, z0, *extra)
-                )
+                # the MAP descent itself; its compile counters say how
+                # much of it was compilation
+                with telemetry.span(
+                    "map_init", steps=cfg.map_init_steps,
+                    grad_evals=cfg.map_init_steps * chains,
+                ):
+                    carry = jax.block_until_ready(
+                        chees_init_j(key_init, z0, *extra)
+                    )
+            warm_span = telemetry.span("warmup", steps=cfg.num_warmup).open()
             if warm_import is not None:
                 from .adaptation import da_init
 
@@ -910,6 +938,7 @@ def _sample_until_converged(
             state = run_carry.states
             step_size = jnp.exp(run_carry.log_eps)
             inv_mass = run_carry.inv_mass
+            warm_span.close(grad_evals=int(n_warm_leap) * chains)
             if adapt_export_path and warm_import is None:
                 # populate the reuse cache from a FULL warmup only.  A
                 # successful import leaves the artifact byte-identical: a
@@ -940,10 +969,12 @@ def _sample_until_converged(
                 jax.block_until_ready(z0)
             # the segmented warmup driver reads the ambient trace, which
             # the public wrapper pinned to THIS run's trace
-            state, step_size, inv_mass, n_div = seg_warmup(
-                warm_keys, z0, data, block_size
-            )
-            n_div = ap.collect(n_div)  # per-chain counts are chain-sharded
+            with telemetry.span("warmup", steps=cfg.num_warmup):
+                state, step_size, inv_mass, n_div = seg_warmup(
+                    warm_keys, z0, data, block_size
+                )
+                # per-chain counts are chain-sharded
+                n_div = ap.collect(n_div)
         # chees: ensemble gradient evals spent before sampling — MAP
         # descent (one fused gradient per Adam step per chain) + warm
         # leapfrogs; per-chain kernels have no shared-budget equivalent
@@ -958,6 +989,12 @@ def _sample_until_converged(
             adapt_imported=(is_chees and warm_import is not None) or None,
         )
 
+    # what stands between here and the first dispatch is `resume_load`'s on
+    # a resumed run and this span's on a fresh one (no phase event): the
+    # streaming statistics and the diagnostics carry are built and placed
+    loop_span = resume_span or telemetry.span(
+        "compile", stage="loop_init"
+    ).open()
     suff = diagnostics.ChainSuffStats(chains, fm.ndim)
     # full draw history in ONE growing preallocated host buffer: each block
     # is written exactly once, the per-block worst-k ESS subset is a single
@@ -1202,7 +1239,8 @@ def _sample_until_converged(
             # crash discards it and the supervisor replays from block
             # k-1's checkpoint.
             faults.fail_point("runner.block.pre")
-            t_blk = time.perf_counter()
+            blk = blocks_done + 1
+            wait_span = telemetry.span("block.wait", block=blk).open()
             outs = pend["outs"]
             if is_chees:
                 # chain-sharded outputs cross to host via collect (an
@@ -1245,7 +1283,19 @@ def _sample_until_converged(
                 sched_fields = lane_occupancy_fields(
                     ap.collect(outs["lane_iters"])
                 )
-            t_wait = time.perf_counter() - t_blk
+            wait_span.close()
+            t_wait = wait_span.seconds
+
+            def host_since_wait():
+                # the host cycle so far: everything since the device's
+                # outputs arrived
+                return (time.perf_counter_ns() - wait_span.end_ns) / 1e9
+
+            # the host's work on the block up to its record: health gate,
+            # draw persistence, streaming R-hat / ESS, stop validation
+            gate_span = telemetry.span(
+                "block.gate", block=blk, block_grad_evals=blk_grads
+            ).open()
             if health_check:
                 # poisoned state must never reach the checkpoint; the
                 # supervisor (supervise.supervised_sample) restarts from
@@ -1338,7 +1388,9 @@ def _sample_until_converged(
                 # when the pipeline hides host work) vs host diagnostics;
                 # grad_evals divides out to device cost per gradient
                 "t_dispatch_s": round(pend["t_enq"] + t_wait, 3),
-                "t_diag_s": round(time.perf_counter() - t_blk - t_wait, 3),
+                # the length of the `block.gate` span, stamped where it
+                # closes (below)
+                "t_diag_s": None,
                 # Normalized to GRADIENT EVALUATIONS on all paths: the
                 # ChEES/HMC count is leapfrog steps (1 grad eval each),
                 # the NUTS count is tree leaves (1 grad eval each).
@@ -1399,18 +1451,19 @@ def _sample_until_converged(
                 rec["full_max_rank_rhat"] = float(
                     np.max(diagnostics.rank_rhat(full_draws))
                 )
-                # the full pass is host diagnostics too — re-stamp so the
-                # attribution covers the expensive validation blocks
-                rec["t_diag_s"] = round(
-                    time.perf_counter() - t_blk - t_wait, 3
-                )
+                # the full pass is host diagnostics too (inside the gate
+                # span) — re-stamp the wall so it covers the expensive
+                # validation blocks
                 rec["wall_s"] = time.perf_counter() - t_start
                 if full_rhat < rhat_target and full_ess > ess_target:
                     converged = True
                 else:
                     next_full_check = blocks_done + max(1, blocks_done // 4)
+            gate_span.close(diag_bytes_to_host=diag_bytes)
+            rec["t_diag_s"] = round(gate_span.seconds, 3)
             history.append(rec)
-            emit(rec)
+            with telemetry.span("block.record", block=blk):
+                emit(rec)
             if monitor is not None:
                 # per-block warning sweep — host-side only, AFTER the
                 # block record so the metrics trail stays byte-identical
@@ -1438,7 +1491,7 @@ def _sample_until_converged(
 
             t_ckpt_dur = 0.0
             if checkpoint_path:
-                t_ckpt = time.perf_counter()
+                ckpt_span = telemetry.span("block.checkpoint", block=blk).open()
                 from .checkpoint import save_checkpoint
 
                 arrays = ap.collect({
@@ -1476,7 +1529,8 @@ def _sample_until_converged(
                         "kernel": cfg.kernel,
                     },
                 )
-                t_ckpt_dur = time.perf_counter() - t_ckpt
+                ckpt_span.close()
+                t_ckpt_dur = ckpt_span.seconds
                 if trace.enabled:
                     trace.emit(
                         "checkpoint",
@@ -1501,7 +1555,7 @@ def _sample_until_converged(
                 # starved).  Both are bounded by the host-cycle totals, so
                 # the summarized idle fraction (idle over sample_block +
                 # checkpoint phase time) stays in [0, 1].
-                host_cycle = time.perf_counter() - t_blk - t_wait
+                host_cycle = host_since_wait()
                 if sync_blocks:
                     hidden, idle = 0.0, pipe["t_host_prev"]
                 else:
@@ -1520,8 +1574,7 @@ def _sample_until_converged(
                     # (own phase event), so per-run phases still tile the
                     # wall
                     dur_s=round(
-                        pend["t_enq"]
-                        + time.perf_counter() - t_blk - t_ckpt_dur,
+                        pend["t_enq"] + t_wait + host_cycle - t_ckpt_dur,
                         4,
                     ),
                     t_dispatch_s=rec["t_dispatch_s"],
@@ -1577,7 +1630,7 @@ def _sample_until_converged(
                 pipe["dev_est"] = (
                     t_wait if sync_blocks else pipe["t_host_prev"] + t_wait
                 )
-            pipe["t_host_prev"] = time.perf_counter() - t_blk - t_wait
+            pipe["t_host_prev"] = host_since_wait()
 
             if converged:
                 return True
@@ -1606,13 +1659,16 @@ def _sample_until_converged(
                 # stop AFTER the block is emitted and checkpointed, so the
                 # returned (and persisted) result accounts for every draw
                 budget_exhausted = True
-                emit(
-                    {
-                        "event": "budget_exhausted",
-                        "time_budget_s": float(time_budget_s),
-                        "wall_s": time.perf_counter() - t_start,
-                    }
-                )
+                with telemetry.span(
+                    "block.record", block=blk, event="budget_exhausted"
+                ):
+                    emit(
+                        {
+                            "event": "budget_exhausted",
+                            "time_budget_s": float(time_budget_s),
+                            "wall_s": time.perf_counter() - t_start,
+                        }
+                    )
                 if trace.enabled:
                     trace.emit(
                         "budget", time_budget_s=float(time_budget_s),
@@ -1624,6 +1680,7 @@ def _sample_until_converged(
         pending = None
         blocks_dispatched = blocks_done
         profile_next = bool(profile_dir) and blocks_done == 0
+        loop_span.close(draws_rebuilt=draws_hist.rows * chains)
 
         def dispatch_next():
             """Split the next block's key on the HOST (identical stream in
@@ -1633,8 +1690,10 @@ def _sample_until_converged(
             length = next_block_len()
             if length <= 0:
                 return None
+            enq_span = telemetry.span(
+                "block.dispatch", block=blocks_dispatched + 1, length=length
+            ).open()
             key, key_block = jax.random.split(key)
-            t_enq = time.perf_counter()
             if profile_next:
                 # the profiler wants one block's device timeline by
                 # itself: run the first block synchronously under the
@@ -1645,7 +1704,8 @@ def _sample_until_converged(
                     jax.block_until_ready(pend["outs"])
             else:
                 pend = dispatch_block(key_block, key, length)
-            pend["t_enq"] = time.perf_counter() - t_enq
+            enq_span.close()
+            pend["t_enq"] = enq_span.seconds
             blocks_dispatched += 1
             draws_dispatched += length
             return pend
@@ -1685,10 +1745,20 @@ def _sample_until_converged(
             draw_store.close()
 
     with trace.phase("collect"):
-        # one final contiguous copy out of the history buffer (the buffer
-        # over-allocates by up to 2x; the result should not pin that)
-        all_draws = np.ascontiguousarray(draws_hist.view())
-        draws = _constrain_draws(fm, all_draws)
+        with telemetry.span("collect.layout") as sp:
+            # one final contiguous copy out of the history buffer (the
+            # buffer over-allocates by up to 2x; the result should not pin
+            # that)
+            all_draws = np.ascontiguousarray(draws_hist.view())
+            sp.note(draws=draws_hist.rows * chains, bytes=all_draws.nbytes)
+        if pending is not None:
+            # the block dispatched ahead of the stop is dropped, but the
+            # device runs it to its end before anything queued behind it:
+            # wait here, so that `collect.constrain` is the layout alone
+            with telemetry.span("collect.drain", block=blocks_dispatched):
+                jax.block_until_ready(pending["outs"])
+        with telemetry.span("collect.constrain", bytes=all_draws.nbytes):
+            draws = _constrain_draws(fm, all_draws)
     stats = {"num_divergent": np.asarray(total_div)}
     result = AdaptiveResult(
         draws,

@@ -36,6 +36,7 @@ from .kernels.base import HMCState, init_state
 from .kernels.hmc import hmc_step
 from .kernels.nuts import nuts_step
 from .model import FlatModel, Model, Potential, flatten_model
+from .platform import named_jit
 
 Array = jax.Array
 
@@ -730,7 +731,9 @@ class Posterior:
 def _constrain_draws(fm: FlatModel, zs) -> Dict[str, np.ndarray]:
     # elementwise over the full draw history, on the default device: the
     # host copy goes up once and the constrained draws come back once
-    constrained = jax.jit(jax.vmap(jax.vmap(fm.constrain)))(np.asarray(zs))
+    constrained = named_jit(
+        jax.vmap(jax.vmap(fm.constrain)), "stark_constrain"
+    )(np.asarray(zs))
     return {k: np.asarray(v) for k, v in constrained.items()}
 
 

@@ -13,10 +13,19 @@ breakdown instead of re-deriving it from stdout.
 
 Design rules:
 
-  * **Zero cost when off.**  The default trace is the `NullTrace` singleton:
-    every emit is a constant-time no-op, `phase()` returns a shared no-op
-    context manager, and nothing here imports jax at module load.  Hot
-    paths (the per-block runner loop) pay one attribute call per block.
+  * **Events cost nothing when off; spans are always on.**  The default
+    trace is the `NullTrace` singleton: every emit is a constant-time
+    no-op and nothing here imports jax at module load.  `phase()` always
+    records a `span` (below) — a few microseconds, in memory — and only a
+    real trace adds the phase event.
+  * **One span primitive.**  `span(name, **fields)` measures at the site:
+    start, end, parent and run on ``time.perf_counter_ns()``, kept in one
+    bounded in-memory log (`span_log`).  Every ``trace.phase(...)`` is a
+    span; the runner adds spans where it has no phase event.  Each span
+    also opens a ``jax.profiler.TraceAnnotation("stark.<name>")``, so a
+    profile shows the host spans on the device's timeline, and collects
+    what jax reports about compilation while it is the innermost open
+    span (`_SPAN_COUNTERS`).
   * **Host-side only, block-bounded.**  Events are emitted from the host
     driver after `jax.block_until_ready` readbacks — never from inside a
     device program.  The one exception is the opt-in in-loop heartbeat
@@ -67,13 +76,16 @@ and not just a log.
 
 from __future__ import annotations
 
+import collections
 import contextlib
+import itertools
 import json
 import os
+import sys
 import threading
 import time
 from contextvars import ContextVar
-from typing import Any, Dict, Iterator, List, Optional
+from typing import Any, Dict, Iterator, List, NamedTuple, Optional
 
 SCHEMA_VERSION = 1
 
@@ -135,9 +147,10 @@ FLEET_EVENT_TYPES = frozenset({"fleet_block", "problem_converged",
 #: profiling event types (stark_tpu.profiling): ``span`` — one
 #: attributed slice of the run timeline (``kind`` in
 #: `profiling.SPAN_KINDS`, ``start_s``/``end_s``/``dur_s`` on the
-#: trace's wall clock) derived from the phase events by an opt-in
-#: `profiling.SpanRecorder` (STARK_PROFILE_SPANS=1; default traces
-#: carry none and stay byte-identical)
+#: trace's wall clock, ``id`` / ``parent`` / ``src`` of the program's own
+#: span) written from the span log by the opt-in `profiling.span_events`
+#: (STARK_PROFILE_SPANS=1; default traces carry none and stay
+#: byte-identical)
 PROFILING_EVENT_TYPES = frozenset({"span"})
 
 #: statistical-health event types (stark_tpu.health): ``health_warning``
@@ -377,14 +390,188 @@ def _rotate_locked(st: "_TraceState") -> Optional[Dict[str, Any]]:
     return rec
 
 
+
+# ---------------------------------------------------------------------------
+# spans: one measurement at the site, always on, in memory
+# ---------------------------------------------------------------------------
+
+
+class SpanRecord(NamedTuple):
+    """One closed span.  ``(run, id)`` names it: ``run`` is the ordinal of
+    the entry call it belongs to (1, 2, ... in this process; 0 outside any
+    entry call, as `model.prepare_model_data`), ``id`` the ordinal of its
+    opening within that run (the run's root span is 1), ``parent`` the id
+    of the innermost span open in the thread when it opened (None for a
+    root and at top level).  Times are ``time.perf_counter_ns()``.
+    ``fields`` holds what the site gave, the jax compile counters that
+    fired while this was the innermost open span (`_SPAN_COUNTERS`) and,
+    for a span that died, ``error`` (the exception class)."""
+
+    id: int
+    parent: Optional[int]
+    run: int
+    name: str
+    start_ns: int
+    end_ns: int
+    fields: Dict[str, Any]
+
+
+#: set-up plus a 30 s window of the benchmark is under 500 spans
+SPAN_LOG_SIZE = 4096
+
+_SPAN_LOG: "collections.deque[SpanRecord]" = collections.deque(
+    maxlen=SPAN_LOG_SIZE)
+_OPEN_SPAN: ContextVar[Optional["Span"]] = ContextVar(
+    "stark_tpu_span", default=None)
+_RUN_IDS = itertools.count(1)
+_TOP_LEVEL_IDS = itertools.count(1)
+
+#: jax.monitoring events -> the field of the innermost open span they add
+#: to.  Durations (seconds): backend compilation (on a persistent-cache hit
+#: jax times the retrieval under the same event), the retrieval alone, and
+#: tracing + lowering.  Counts: programs that asked the persistent cache,
+#: and hits.
+_SPAN_COUNTERS = {
+    "/jax/core/compile/backend_compile_duration": "compile_s",
+    "/jax/compilation_cache/cache_retrieval_time_sec": "cache_s",
+    "/jax/core/compile/jaxpr_trace_duration": "lower_s",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower_s",
+    "/jax/compilation_cache/compile_requests_use_cache": "cache_requests",
+    "/jax/compilation_cache/cache_hits": "cache_hits",
+}
+
+#: `jax.profiler.TraceAnnotation` once jax is in the process (the span
+#: layer never imports it first: a process without jax has no profiler
+#: session to annotate and no compilation to count)
+_ANNOTATE: Any = None
+
+
+def _on_jax_event(event: str, amount: Any = 1, **_: Any) -> None:
+    """The one listener behind both jax.monitoring registrations (events
+    come without an amount and count 1, durations come in seconds)."""
+    key = _SPAN_COUNTERS.get(event)
+    sp = _OPEN_SPAN.get() if key is not None else None
+    if sp is not None:
+        sp.fields[key] = sp.fields.get(key, 0) + amount
+
+
+def _hook_jax() -> Any:
+    """Register the compile counters and find TraceAnnotation, once, when
+    jax has been imported by someone else."""
+    global _ANNOTATE
+    if _ANNOTATE is None and "jax" in sys.modules:
+        import jax.profiler
+        from jax import monitoring
+
+        monitoring.register_event_listener(_on_jax_event)
+        monitoring.register_event_duration_secs_listener(_on_jax_event)
+        _ANNOTATE = jax.profiler.TraceAnnotation
+    return _ANNOTATE
+
+
+class Span:
+    """An open span; use `span` / `run_span`.  A context manager, or
+    ``open()`` ... ``close()`` where the interval does not fit one block
+    of code (an ancestor that closes first closes it too, with its
+    error)."""
+
+    __slots__ = ("name", "fields", "id", "parent", "run", "start_ns",
+                 "end_ns", "_root", "_outer", "_token", "_ids", "_mark")
+
+    def __init__(self, name: str, fields: Dict[str, Any], root: bool = False):
+        self.name = name
+        self.fields = fields
+        self._root = root
+        self.end_ns = None
+
+    def open(self) -> "Span":
+        outer = self._outer = _OPEN_SPAN.get()
+        if self._root:
+            self.run, self.id, self.parent = next(_RUN_IDS), 1, None
+            self._ids = itertools.count(2)
+        elif outer is None:
+            self.run, self.id, self.parent = 0, next(_TOP_LEVEL_IDS), None
+            self._ids = _TOP_LEVEL_IDS
+        else:
+            self.run, self.parent = outer.run, outer.id
+            self.id = next(outer._ids)
+            self._ids = outer._ids
+        annotate = _ANNOTATE or _hook_jax()
+        self._mark = annotate("stark." + self.name) if annotate else None
+        if self._mark is not None:
+            self._mark.__enter__()
+        self._token = _OPEN_SPAN.set(self)
+        self.start_ns = time.perf_counter_ns()
+        return self
+
+    def close(self, error: Optional[str] = None, **fields: Any) -> None:
+        end = time.perf_counter_ns()
+        if self.end_ns is not None:
+            return
+        # spans opened inside and left open (an exception passed their
+        # close) end here, innermost first, with the same error
+        inner = _OPEN_SPAN.get()
+        while inner is not None and inner is not self:
+            inner._finish(end, error or "unclosed")
+            inner = inner._outer
+        self.fields.update(fields)
+        self._finish(end, error)
+        _OPEN_SPAN.reset(self._token)
+
+    def _finish(self, end_ns: int, error: Optional[str]) -> None:
+        self.end_ns = end_ns
+        if error is not None:
+            self.fields.setdefault("error", error)
+        if self._mark is not None:
+            self._mark.__exit__(None, None, None)
+        _SPAN_LOG.append(SpanRecord(
+            self.id, self.parent, self.run, self.name, self.start_ns,
+            end_ns, self.fields))
+
+    def note(self, **fields: Any) -> "Span":
+        """Attach fields discovered while the span runs."""
+        self.fields.update(fields)
+        return self
+
+    @property
+    def seconds(self) -> float:
+        """The span's length once closed, else the time since it opened."""
+        end = self.end_ns if self.end_ns is not None else time.perf_counter_ns()
+        return (end - self.start_ns) / 1e9
+
+    def __enter__(self) -> "Span":
+        return self.open()
+
+    def __exit__(self, exc_type, exc, tb) -> None:
+        self.close(exc_type.__name__ if exc_type is not None else None)
+
+
+def span(name: str, **fields: Any) -> Span:
+    """``with span("block.gate", block=3): ...`` — see `SpanRecord`."""
+    return Span(name, fields)
+
+
+def run_span(**fields: Any) -> Span:
+    """The root span ``run`` of one entry call: spans opened inside it
+    carry its run ordinal."""
+    return Span("run", fields, root=True)
+
+
+def span_log() -> List[SpanRecord]:
+    """The closed spans the bounded log still holds, oldest close first."""
+    return list(_SPAN_LOG)
+
+
 class _Phase:
-    """Context manager for a timed phase: emits ONE event at exit with the
-    measured ``dur_s`` (plus any fields captured at enter or added via
-    ``note()`` while the phase runs)."""
+    """Context manager for a timed phase: a `span` named after the event,
+    and ONE event at exit with the span's ``dur_s`` (plus any fields
+    captured at enter or added via ``note()`` while the phase runs).  Under
+    `NullTrace` (``trace`` None) the span is all there is."""
 
-    __slots__ = ("_trace", "_event", "_fields", "_t0")
+    __slots__ = ("_trace", "_event", "_fields", "_span")
 
-    def __init__(self, trace: "RunTrace", event: str, fields: Dict[str, Any]):
+    def __init__(self, trace: Optional["RunTrace"], event: str,
+                 fields: Dict[str, Any]):
         self._trace = trace
         self._event = event
         self._fields = fields
@@ -396,17 +583,23 @@ class _Phase:
         return self
 
     def __enter__(self) -> "_Phase":
-        self._t0 = time.perf_counter()
+        # the span keeps its own dict: the compile counters it gathers
+        # are no field of the event
+        self._span = Span(self._event, dict(self._fields)).open()
         return self
 
     def __exit__(self, exc_type, exc, tb) -> None:
-        dur = time.perf_counter() - self._t0
         if exc_type is not None:
             # a phase that died still leaves its timing + the error class
             # in the trace — that is exactly the stall/fault evidence the
             # layer exists for
             self._fields.setdefault("error", exc_type.__name__)
-        self._trace.emit(self._event, dur_s=round(dur, 4), **self._fields)
+        self._span.fields.update(self._fields)
+        self._span.close(self._fields.get("error"))
+        if self._trace is not None:
+            self._trace.emit(self._event,
+                             dur_s=round(self._span.seconds, 4),
+                             **self._fields)
 
 
 class RunTrace:
@@ -490,6 +683,11 @@ class RunTrace:
         emits one event at exit carrying the measured ``dur_s``."""
         return _Phase(self, event, dict(fields))
 
+    def clock_zero(self) -> float:
+        """``time.perf_counter()`` at ``wall_s`` 0: what puts a span
+        (``perf_counter_ns``, the same clock) on this trace's wall."""
+        return self._state.t0
+
     def tagged(self, **tags) -> "RunTrace":
         """A view writing to the same file with extra constant tags — how
         the parallel paths stamp shard/replica ids on their events."""
@@ -522,8 +720,8 @@ class RunTrace:
 
 
 class NullTrace:
-    """No-op trace: the default everywhere, so untraced hot paths pay one
-    method call per block and allocate nothing."""
+    """The default trace everywhere: it emits nothing; a phase still
+    records its span."""
 
     enabled = False
     path = None
@@ -531,8 +729,8 @@ class NullTrace:
     def emit(self, event: str, **fields) -> None:
         return None
 
-    def phase(self, event: str, **fields):
-        return _NULL_PHASE
+    def phase(self, event: str, **fields) -> _Phase:
+        return _Phase(None, event, fields)
 
     def tagged(self, **tags) -> "NullTrace":
         return self
@@ -550,20 +748,6 @@ class NullTrace:
         return None
 
 
-class _NullPhase:
-    """Shared no-op phase context (``note`` chains like the real one)."""
-
-    def note(self, **fields) -> "_NullPhase":
-        return self
-
-    def __enter__(self) -> "_NullPhase":
-        return self
-
-    def __exit__(self, *exc) -> None:
-        return None
-
-
-_NULL_PHASE = _NullPhase()
 NULL_TRACE = NullTrace()
 
 # ambient trace: entry points (CLI --trace, bench.py) install a trace once;
@@ -983,9 +1167,9 @@ class FlightRecorder:
     def _on_event(self, rec: Dict[str, Any]) -> None:
         ev = rec.get("event")
         if ev == "span":
-            # pure re-derivations of phase events already in the ring
-            # (profiling.SpanRecorder): ringing them would shrink the
-            # forensic window ~4x under STARK_PROFILE_SPANS=1
+            # the span log's copy of what the phase events in the ring
+            # already say (profiling.span_events): ringing them would
+            # shrink the forensic window under STARK_PROFILE_SPANS=1
             return
         with self._lock:
             self._ring.append(rec)

@@ -19,7 +19,6 @@ import pytest
 from stark_tpu import profiling, telemetry
 from stark_tpu.profiling import (
     DispatchProbe,
-    SpanRecorder,
     deregister_probe,
     probe_counts,
     register_probe,
@@ -147,7 +146,7 @@ def test_summary_picks_last_run_by_default():
 
 
 # ---------------------------------------------------------------------------
-# span event family (SpanRecorder)
+# span event family (written from the span log)
 # ---------------------------------------------------------------------------
 
 
@@ -156,87 +155,113 @@ def test_span_event_registered_in_schema():
     assert "span" in telemetry.PROFILING_EVENT_TYPES
 
 
-def test_span_recorder_emits_literal_span_events(tmp_path):
+def test_span_events_written_from_the_span_log(tmp_path, monkeypatch):
+    """``span`` events are the program's own spans: real start, end and
+    parent on the trace's wall clock, nothing derived from ``dur_s``."""
+    import time as _time
+
+    monkeypatch.setenv("STARK_PROFILE_SPANS", "1")
     path = str(tmp_path / "t.jsonl")
-    # no run_start here: these synthetic dur_s values predate the trace
-    # clock, and the run window would (correctly) clip them — the span
-    # content is what's under test
     with telemetry.RunTrace(path) as tr:
-        rec = SpanRecorder(tr).install()
-        try:
-            tr.emit("sample_block", dur_s=2.0, block=1,
-                    t_host_hidden_s=0.5, device_idle_s=0.25)
-        finally:
-            rec.uninstall()
-        tr.emit("checkpoint", dur_s=0.1)  # after uninstall: no span
+        with telemetry.span("before"):  # closed before the block: not written
+            pass
+        with profiling.span_events(tr), telemetry.run_span():
+            tr.emit("run_start")
+            with tr.phase("compile", stage="init+map"):
+                with telemetry.span("map_init", steps=3):
+                    _time.sleep(0.02)
+            with telemetry.span("block.wait", block=1):
+                _time.sleep(0.01)
+            with telemetry.span("block.gate", block=1):
+                pass
+            tr.emit("run_end", dur_s=0.05)
+        with telemetry.span("block.gate", block=9):  # after the block
+            pass
     events = telemetry.read_trace(path)
     spans = [e for e in events if e["event"] == "span"]
-    assert {e["kind"] for e in spans} == {"dispatch", "host_hidden",
-                                          "device_idle"}
+    assert [e["src"] for e in spans] == [
+        "map_init", "compile", "block.wait", "block.gate"]
+    assert [e["kind"] for e in spans] == [
+        "warmup", "compile", "dispatch", "host"]
+    by_src = {e["src"]: e for e in spans}
+    assert by_src["map_init"]["parent"] == by_src["compile"]["id"]
+    assert by_src["compile"]["stage"] == "init+map"
+    assert by_src["block.wait"]["block"] == 1
+    # written at run_end: inside the run's envelope, with its ordinal
+    order = [e["event"] for e in events]
+    assert order.index("run_end") < order.index("span") or all(
+        e["run"] == 1 for e in spans)
+    assert all(e["run"] == 1 for e in spans)
+    compile_ev = next(e for e in events if e["event"] == "compile")
     for e in spans:
-        assert e["src"] == "sample_block"
         assert e["end_s"] - e["start_s"] == pytest.approx(e["dur_s"],
                                                           abs=1e-3)
         telemetry.validate_event(e)
-    assert not any(
-        e["event"] == "span" and e.get("src") == "checkpoint"
-        for e in events
-    )
-    # the read path prefers literal spans over synthesis
+    # one clock: the phase event's emission time is the span's end
+    assert by_src["compile"]["end_s"] == pytest.approx(
+        compile_ev["wall_s"], abs=2e-3)
+    assert by_src["compile"]["dur_s"] == pytest.approx(
+        compile_ev["dur_s"], abs=1e-3)
+    assert by_src["map_init"]["dur_s"] >= 0.02
+    # the read path prefers literal spans over synthesis; the inner span
+    # claims its interval, the outer keeps the remainder
     tl = spans_from_events(events)
     assert tl["synthesized"] is False
-    assert {sp["kind"] for sp in tl["spans"]} == {"dispatch",
-                                                  "host_hidden",
-                                                  "device_idle"}
+    assert {sp["kind"] for sp in tl["spans"]} >= {"warmup", "dispatch"}
+    total = sum(sp["dur"] for sp in tl["spans"])
+    assert total <= tl["wall_s"] + 1e-6
 
 
-def test_span_recorder_gap_attribution_matches_synthesis(tmp_path):
-    """Turning the recorder ON must not lower coverage: the literal
-    span stream carries the same block-loop gap attribution the
-    synthesized read path applies (the pipelined runner's out-of-line
-    enqueue wall)."""
-    # pipelined shape: block 2's [end-dur, end] leaves a gap after
-    # block 1 (its enqueue ran while block 1 computed)
-    phase_events = [
-        ("sample_block", dict(dur_s=1.0, block=1)),
-        ("sample_block", dict(dur_s=1.0, block=2)),
-    ]
-    path = str(tmp_path / "t.jsonl")
+def test_span_events_need_no_subtraction(tmp_path, monkeypatch):
+    """The pipelined loop's enqueue ran while the previous block
+    computed: the subtraction path has to GUESS that wall (its ``gap``
+    spans); the span log measured it, so a file with ``span`` events
+    carries no gap span and covers the same wall."""
     import time as _time
 
-    with telemetry.RunTrace(path) as tr:
-        rec = SpanRecorder(tr).install()
-        try:
-            for ev, fields in phase_events:
-                _time.sleep(1.2)  # real wall gap between completions
-                tr.emit(ev, **fields)
-        finally:
-            rec.uninstall()
+    monkeypatch.setenv("STARK_PROFILE_SPANS", "1")
+    path = str(tmp_path / "t.jsonl")
+    with telemetry.RunTrace(path) as tr, profiling.span_events(tr):
+        for blk in (1, 2):
+            with telemetry.span("block.dispatch", block=blk):
+                _time.sleep(0.05)  # the enqueue, out of line
+            with telemetry.span("block.wait", block=blk):
+                _time.sleep(0.02)
+            tr.emit("sample_block", dur_s=0.02, block=blk)
     events = telemetry.read_trace(path)
     literal = spans_from_events(events)
     assert literal["synthesized"] is False
-    gap_spans = [sp for sp in literal["spans"] if sp.get("gap")]
-    assert gap_spans and gap_spans[0]["kind"] == "dispatch"
-    # the literal timeline covers the inter-block wall like the
-    # synthesized one would
-    synth = spans_from_events(
-        [e for e in events if e["event"] != "span"]
-    )
+    assert not any(sp.get("gap") for sp in literal["spans"])
+    synth = spans_from_events([e for e in events if e["event"] != "span"])
+    assert synth["synthesized"] is True
+    assert any(sp.get("gap") for sp in synth["spans"])
     lit_cov = sum(sp["dur"] for sp in literal["spans"])
     syn_cov = sum(sp["dur"] for sp in synth["spans"])
-    assert lit_cov == pytest.approx(syn_cov, rel=0.05)
+    assert lit_cov == pytest.approx(0.14, abs=0.03)
+    assert lit_cov >= syn_cov - 1e-3
 
 
-def test_maybe_record_spans_env_gate(tmp_path, monkeypatch):
+def test_span_events_env_gate(tmp_path, monkeypatch):
+    def spans_written(trace_path):
+        return [e for e in telemetry.read_trace(trace_path)
+                if e["event"] == "span"]
+
     monkeypatch.delenv("STARK_PROFILE_SPANS", raising=False)
-    with telemetry.RunTrace(str(tmp_path / "a.jsonl")) as tr:
-        assert profiling.maybe_record_spans(tr) is None
+    a = str(tmp_path / "a.jsonl")
+    with telemetry.RunTrace(a) as tr, profiling.span_events(tr):
+        with tr.phase("compile"):
+            pass
+        assert not telemetry._EVENT_LISTENERS
+    assert spans_written(a) == []  # default traces carry no span event
     monkeypatch.setenv("STARK_PROFILE_SPANS", "1")
-    assert profiling.maybe_record_spans(telemetry.NULL_TRACE) is None
-    with telemetry.RunTrace(str(tmp_path / "b.jsonl")) as tr:
-        rec = profiling.maybe_record_spans(tr)
-        assert rec is not None
-        rec.uninstall()
+    with profiling.span_events(telemetry.NULL_TRACE):
+        assert not telemetry._EVENT_LISTENERS
+    b = str(tmp_path / "b.jsonl")
+    with telemetry.RunTrace(b) as tr, profiling.span_events(tr):
+        assert telemetry._EVENT_LISTENERS
+        with tr.phase("compile"):
+            pass
+    assert [e["src"] for e in spans_written(b)] == ["compile"]
     assert not telemetry._EVENT_LISTENERS
 
 

@@ -164,8 +164,155 @@ def test_nulltrace_is_default_and_noop(tmp_path):
         assert inner.note(x=1) is inner
     NULL_TRACE.heartbeat(label="x", step=0)
     NULL_TRACE.close()
-    # the shared no-op phase is a singleton: no per-block allocation
-    assert NULL_TRACE.phase("a") is NULL_TRACE.phase("b")
+    # it emits nothing, but the phase is still a span (always on)
+    last = telemetry.span_log()[-1]
+    assert last.name == "sample_block" and last.fields == {"x": 1}
+
+
+# ---------------------------------------------------------------------------
+# spans: one measurement at the site, always on, in memory
+# ---------------------------------------------------------------------------
+
+
+def _closed_since(mark):
+    log = telemetry.span_log()
+    return log[[r is mark for r in log].index(True) + 1:]
+
+
+def _mark():
+    with telemetry.span("mark"):
+        pass
+    return telemetry.span_log()[-1]
+
+
+def test_span_records_start_end_parent_run():
+    mark = _mark()
+    with telemetry.span("outer", x=1) as outer:
+        with telemetry.span("inner"):
+            time.sleep(0.002)
+        outer.note(y=2)
+    inner, out = _closed_since(mark)
+    assert (inner.name, out.name) == ("inner", "outer")  # oldest close first
+    assert inner.run == out.run == 0  # outside any entry call
+    assert out.parent is None and inner.parent == out.id
+    assert out.start_ns <= inner.start_ns <= inner.end_ns <= out.end_ns
+    assert inner.end_ns - inner.start_ns >= 2_000_000
+    assert out.fields == {"x": 1, "y": 2}
+    assert outer.seconds == (out.end_ns - out.start_ns) / 1e9
+
+
+def test_span_ids_are_ordinals_within_their_run():
+    """Two identical entry calls in one process leave identical logs but
+    for the run ordinal and the times."""
+    mark = _mark()
+
+    def entry():
+        with telemetry.run_span(resumed=False):
+            with telemetry.span("a"):
+                with telemetry.span("b"):
+                    pass
+            with telemetry.span("c"):
+                pass
+
+    entry()
+    entry()
+    recs = _closed_since(mark)
+    first, second = recs[:4], recs[4:]
+    shape = [(r.id, r.parent, r.name) for r in first]
+    assert shape == [(3, 2, "b"), (2, 1, "a"), (4, 1, "c"), (1, None, "run")]
+    assert shape == [(r.id, r.parent, r.name) for r in second]
+    assert len({r.run for r in first}) == 1 and first[0].run >= 1
+    assert second[0].run == first[0].run + 1
+    # a span opened after the entry call returned is outside any run again
+    with telemetry.span("after"):
+        pass
+    assert telemetry.span_log()[-1].run == 0
+
+
+def test_span_exception_path_and_unclosed_children():
+    mark = _mark()
+    with pytest.raises(KeyError):
+        with telemetry.span("dies"):
+            telemetry.span("left_open").open()
+            raise KeyError("x")
+    left, dies = _closed_since(mark)
+    assert dies.fields["error"] == "KeyError"
+    # an ancestor that closes first closes what was left open inside it,
+    # with the same error and end
+    assert left.name == "left_open" and left.parent == dies.id
+    assert left.fields["error"] == "KeyError" and left.end_ns == dies.end_ns
+    # and the thread's innermost open span is restored
+    with telemetry.span("next"):
+        pass
+    assert telemetry.span_log()[-1].parent is None
+
+
+def test_span_log_is_bounded():
+    n = telemetry.SPAN_LOG_SIZE
+    for i in range(n + 10):
+        with telemetry.span("fill", i=i):
+            pass
+    log = telemetry.span_log()
+    assert len(log) == n
+    assert log[-1].fields["i"] == n + 9 and log[0].fields["i"] == 10
+
+
+def test_phase_is_a_span_and_the_event_is_unchanged(tmp_path):
+    """`RunTrace.phase` = span + today's event (no new field); under
+    `NullTrace` the span alone."""
+    p = tmp_path / "t.jsonl"
+    mark = _mark()
+    with RunTrace(str(p)) as tr:
+        with tr.phase("compile", stage="build") as ph:
+            ph.note(k=1)
+        with NULL_TRACE.phase("warmup_block", start=0, end=5):
+            pass
+    (ev,) = read_trace(str(p))
+    assert set(ev) == set(telemetry.ENVELOPE_KEYS) | {"dur_s", "stage", "k"}
+    real, null = _closed_since(mark)
+    assert (real.name, real.fields) == ("compile", {"stage": "build", "k": 1})
+    assert (null.name, null.fields) == ("warmup_block", {"start": 0, "end": 5})
+    assert ev["dur_s"] == round((real.end_ns - real.start_ns) / 1e9, 4)
+
+
+def test_compile_counters_land_on_the_span_that_compiled():
+    import jax
+    import jax.numpy as jnp
+
+    mark = _mark()
+    f = jax.jit(lambda x: jnp.sin(x) * 3.0 + 0.125)
+    with telemetry.span("outer"):
+        with telemetry.span("compiles"):
+            jax.block_until_ready(f(jnp.ones(7)))
+        with telemetry.span("reuses"):
+            jax.block_until_ready(f(jnp.ones(7)))
+    compiles, reuses, outer = _closed_since(mark)
+    assert compiles.fields["compile_s"] > 0 and compiles.fields["lower_s"] > 0
+    assert not {"compile_s", "lower_s"} & set(reuses.fields)
+    assert "compile_s" not in outer.fields  # innermost open span only
+
+
+def test_span_opens_a_profiler_annotation(monkeypatch):
+    import jax  # noqa: F401 — the span layer hooks jax once it is imported
+
+    seen = []
+
+    class Annotation:
+        def __init__(self, name):
+            self.name = name
+
+        def __enter__(self):
+            seen.append(("enter", self.name))
+
+        def __exit__(self, *exc):
+            seen.append(("exit", self.name))
+
+    telemetry._hook_jax()
+    monkeypatch.setattr(telemetry, "_ANNOTATE", Annotation)
+    with telemetry.span("block.gate"):
+        seen.append("body")
+    assert seen == [("enter", "stark.block.gate"), "body",
+                    ("exit", "stark.block.gate")]
 
 
 def test_use_trace_scopes_and_restores(tmp_path):

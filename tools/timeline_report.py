@@ -10,10 +10,12 @@
 Where ``tools/trace_report.py`` answers "what happened", this answers
 "where did every wall-second go": the run decomposes into non-
 overlapping, kind-tagged spans — compile / warmup / dispatch /
-host_hidden / device_idle / checkpoint / comm / host — derived by
-`stark_tpu.profiling` from the trace's phase events (or read directly
-from ``span`` events when the writer recorded them via
-STARK_PROFILE_SPANS).  The coverage line states how much of the run
+host_hidden / device_idle / checkpoint / comm / host.  A file written
+under STARK_PROFILE_SPANS=1 carries the program's own spans as ``span``
+events (`telemetry.span`: measured at the site, with start, end and
+parent) and those are what is shown; any other file gets spans derived
+by `stark_tpu.profiling` from the phase events' durations and emission
+times.  The coverage line states how much of the run
 wall the attribution accounts for; healthy post-PR-3 traces tile >=95%,
 and the remainder is host-driver slack between phases.
 
@@ -75,7 +77,7 @@ def render_run(events, run, show_spans=False) -> str:
         f"attributed {_fmt(cov if cov is None else 100.0 * cov)}"
         + ("%" if cov is not None else "")
         + (" (spans synthesized from phase events)"
-           if s["synthesized"] else " (literal span events)")
+           if s["synthesized"] else " (the program's own spans)")
     )
     out.append(
         f"compile {_fmt(s['compile_s'])}s, "
@@ -122,10 +124,13 @@ def render_run(events, run, show_spans=False) -> str:
                     round(sp["dur"], 4),
                     sp.get("src"),
                     sp.get("block"),
+                    sp.get("id"),
+                    sp.get("parent"),
                 )
                 for sp in tl["spans"]
             ],
-            ("kind", "start_s", "end_s", "dur_s", "src", "block"),
+            ("kind", "start_s", "end_s", "dur_s", "src", "block", "id",
+             "parent"),
         ))
     return "\n".join(out)
 
