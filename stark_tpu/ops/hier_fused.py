@@ -17,15 +17,31 @@ log-likelihood is a sum, so the posterior is row-order invariant), which
 makes group membership *locally dense*: one (D, LANE_TILE) slab of X
 spans only a handful of consecutive groups.  Per tile the kernel
   - builds a (K_LOC, TILE) one-hot of the LOCAL group ids (iota compare
-    — K_LOC is the padded max groups-per-tile, static from the layout),
-  - computes the offsets as (C, K_LOC) x (K_LOC, TILE) on the MXU from
-    the tile's alpha window (no (C, N) gather, no offsets stream),
-  - reduces the group gradient as (C, TILE) x (TILE, K_LOC) partials
-    (no (C, N) residual write, no scatter over 1M indices).
+    — K_LOC is the padded max groups-per-tile, static from the layout)
+    and stacks it under the X slab: one (D + K_LOC, TILE) operand,
+  - computes the logits X.beta + alpha[g] as ONE MXU dot of the tile's
+    parameters [beta | alpha window] (C, D + K_LOC) with that operand (no
+    (C, N) gather, no offsets stream),
+  - reduces both gradients as ONE dot of the residual with the operand's
+    transpose: the first D columns are the beta partial, the last K_LOC
+    the group-gradient partials (no (C, N) residual write, no scatter
+    over 1M indices).
 Outside, the (grid, C, K_LOC) partials scatter-add into (C, G) over
 grid*K_LOC ≈ 2k windowed indices — thousands of elements, not millions.
 HBM traffic per evaluation drops from ~644 MB (C=32) to ~136 MB, nearly
 all of it the unavoidable X stream.
+
+Why two dots and not four: at ``highest`` an f32 dot is six bf16 MXU
+passes, and a matmul instruction costs the same whether it uses 8 or 128
+of the array's rows, so a dot over the K_LOC window costs as much as the
+dot over D.  With the window contracted on its own (two more dots a tile)
+the kernel was MXU-issue-bound: 36.6 ms a call at N=16M, C=64, D=32,
+K_LOC=8 on one v5e, 7.26 % of its HBM roofline (PERF_LEDGER.jsonl, PR 26,
+`hier_n16m.sample`); folded, 19.3 ms and 13.76 % (builder's chip run,
+PR 27: PERF.md §6; §3 there has the recipe to read the kernel's static
+schedule without a chip).  D + K_LOC <= 128 is one MXU tile of
+contraction; beyond it ceil((D + K_LOC)/128) <= ceil(D/128) +
+ceil(K_LOC/128), so the folded form never takes more tiles.
 
 Capability parity: same posterior as `HierLogistic`/`FusedHierLogistic`
 (BASELINE.json:8 flagship config); reference tree absent (SURVEY.md §0),
@@ -182,20 +198,23 @@ def prepare_grouped(data, d_eff, transpose_keys=("x",)):
     return out
 
 
-def _check_chain_vmem(cpad, lane_tile, interpret, k_loc=0, q=1):
+def _check_chain_vmem(cpad, lane_tile, interpret, k_loc=0, q=1, slab_rows=0):
     """The kernel holds ~3 (C, TILE) f32 intermediates (logits, resid,
     value terms) in scoped VMEM; past ~16 MB Mosaic refuses to compile
     (measured: C=128 at TILE=8192 asked for 20 MB).  The grouped kernels
     additionally hold a (K_LOC, TILE) one-hot plus its iota slab and the
     per-tile (C, Q*K_LOC) group window (ADVICE r3: a small-C /
-    large-K_LOC config could OOM past the C-only estimate).  Fail with an
-    actionable message instead of the compiler OOM."""
+    large-K_LOC config could OOM past the C-only estimate), and the
+    hierarchical kernel a stacked copy of the design slab and the one-hot
+    (``slab_rows`` = D + K_LOC).  Fail with an actionable message instead
+    of the compiler OOM."""
     if interpret:
         return
     budget = 10 * 1024 * 1024  # conservative: the OOM had >3 live (C,TILE)s
     need = (
         3 * cpad * lane_tile * 4        # (C, TILE) logits/resid/val terms
         + 2 * k_loc * lane_tile * 4     # (K_LOC, TILE) one-hot + iota
+        + slab_rows * lane_tile * 4     # (D + K_LOC, TILE) stacked slab
         + cpad * q * k_loc * 4          # (C, Q*K_LOC) group window block
     )
     if need > budget:
@@ -218,31 +237,32 @@ def _make_grouped_kernel(n, lane_tile, k_loc, link):
         mask = lane0 + iota < n  # (1, TILE)
         xt = jnp.where(mask, xt_ref[...].astype(jnp.float32), 0.0)  # (D, TILE)
         y = jnp.where(mask, y_ref[...], 0.0)  # (1, TILE)
-        beta = beta_ref[...]  # (C, D)
-        alpha = alpha_ref[0]  # (C, K_LOC) — this tile's group window
         # local one-hot: gl is in [0, K_LOC) for every valid lane (layout
         # guarantee); masked/ragged lanes contribute nothing because their
         # resid and val terms are zeroed below
         gl = jnp.where(mask, gl_ref[...], 0)  # (1, TILE) int32
         krows = jax.lax.broadcasted_iota(jnp.int32, (k_loc, lane_tile), 0)
         onehot = jnp.where(krows == gl, 1.0, 0.0)  # (K_LOC, TILE)
+        # the group window rides in the design slab: [beta | alpha window]
+        # against [X ; one-hot] is X.beta + alpha[g] in one contraction
+        # over D + K_LOC, summed in the MXU's f32 accumulator
+        slab = jnp.concatenate([xt, onehot], axis=0)  # (D + K_LOC, TILE)
+        params = jnp.concatenate(
+            [beta_ref[...], alpha_ref[0]], axis=1
+        )  # (C, D + K_LOC) — beta resident, this tile's group window
         logits = jax.lax.dot(
-            beta, xt, precision=prec,
+            params, slab, precision=prec,
             preferred_element_type=jnp.float32,
-        ) + jax.lax.dot(
-            alpha, onehot, precision=prec,
-            preferred_element_type=jnp.float32,
-        )  # (C, TILE) — both MXU; offsets never touch HBM
+        )  # (C, TILE) — offsets never touch HBM
         val_terms, resid = _link_parts(link, y, logits, mask)  # (C, TILE)
         val_ref[...] = jnp.sum(val_terms, axis=1)[None, :, None]
-        gbeta_ref[...] = jax.lax.dot(
-            resid, xt.T, precision=prec,
+        grads = jax.lax.dot(
+            resid, slab.T, precision=prec,
             preferred_element_type=jnp.float32,
-        )[None]  # (1, C, D)
-        galpha_ref[...] = jax.lax.dot(
-            resid, onehot.T, precision=prec,
-            preferred_element_type=jnp.float32,
-        )[None]  # (1, C, K_LOC) — the group-gradient partials
+        )  # (C, D + K_LOC): [beta partial | group-gradient partials]
+        d = xt.shape[0]
+        gbeta_ref[...] = grads[:, :d][None]  # (1, C, D)
+        galpha_ref[...] = grads[:, d:][None]  # (1, C, K_LOC)
 
     return kernel
 
@@ -260,7 +280,8 @@ def _grouped_call(beta, alpha, xt, y, gl, first_gid, *, k_loc, lane_tile,
     n = xt.shape[1]
     grid = -(-n // lane_tile)
     cpad = -(-c // 8) * 8
-    _check_chain_vmem(cpad, lane_tile, interpret, k_loc=k_loc)
+    _check_chain_vmem(cpad, lane_tile, interpret, k_loc=k_loc,
+                      slab_rows=d + k_loc)
     if cpad != c:
         beta = jnp.pad(beta, ((0, cpad - c), (0, 0)))
         alpha = jnp.pad(alpha, ((0, cpad - c), (0, 0)))

@@ -68,10 +68,12 @@ def dot_precision():
 
     f32 matmuls on the TPU MXU are EMULATED in bf16 passes: DEFAULT is
     one pass (inputs truncated to bf16), HIGH three passes (~f32-accurate),
-    HIGHEST six.  The grouped hierarchical kernel runs four dots per tile
-    over a stream one-third the offset kernel's, so at HIGHEST it is
-    MXU-pass-bound, not HBM-bound (pass-count arithmetic + the measured
-    65 GB/s effective rate, BASELINE.md r5) — the knob exists so the
+    HIGHEST six.  The grouped hierarchical kernel runs two dots per tile
+    (the group window is folded into the design slab, ops/hier_fused.py)
+    and at HIGHEST is bound by MXU and VPU issue slots, not by HBM:
+    13.76 % of its HBM roofline on `hier_n16m.sample` (builder's chip
+    run, PR 27; 7.26 % with four dots, PERF_LEDGER.jsonl PR 26), while the
+    chain-batched logistic kernel reads 61.9 % — the knob exists so the
     on-chip roofline can measure the precision/throughput trade and the
     sampler can adopt the cheapest setting whose posterior matches
     (tools/precision_parity.py is that gate).  Default stays HIGHEST:

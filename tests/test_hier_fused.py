@@ -88,6 +88,110 @@ def test_grouped_matches_autodiff_value_and_grads():
         )
 
 
+def _sorted_groups(n, groups):
+    return np.sort(np.random.RandomState(0).randint(0, groups, size=n))
+
+
+#: (n, d, chains, sorted group ids, STARK_GROUPED_LANE_TILE, lane tile, k_loc)
+_FOLDED_CASES = {
+    # the cell's shape at toy N: d + k_loc = 40, one MXU tile deep
+    "d32_kloc8": (2 * 8192, 32, 8, _sorted_groups(2 * 8192, 12), None, 8192, 8),
+    # the slab's one-hot rows start off a sublane boundary
+    "d_not_multiple_of_8": (2048, 10, 8, _sorted_groups(2048, 40), "1024", 1024, 24),
+    # the chain batch pads to 8 rows of parameters
+    "chains_not_multiple_of_8": (2048, 8, 5, _sorted_groups(2048, 40), "1024", 1024, 24),
+    # 37 rows of a fourth tile: the mask zeroes the slab's tail
+    "ragged_last_tile": (3 * 1024 + 37, 8, 8, _sorted_groups(3 * 1024 + 37, 50), "1024", 1024, 24),
+    # two rows a group: the window fills _K_LOC_MAX and d + k_loc = 136
+    # spans two MXU tiles of contraction (and of output columns)
+    "kloc128_two_mxu_tiles": (1024 + 9, 8, 8, np.arange(1024 + 9) // 2, None, 256, 128),
+    # the cap sets a 512-lane tile: eight tiles share the resident beta
+    "lane_tile_cap": (4096, 8, 8, _sorted_groups(4096, 50), "512", 512, 8),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_FOLDED_CASES))
+def test_folded_kernel_matches_autodiff(case, monkeypatch):
+    """The kernel contracts [beta | alpha window] with [xT ; one-hot] in
+    one dot and takes both gradients from one product with the slab's
+    transpose: value, grad beta and grad alpha against jax.grad of
+    HierLogistic.log_lik on the same sorted rows, chain-batched."""
+    from stark_tpu.ops.hier_fused import hier_logistic_loglik
+
+    n, d, chains, g, cap, lane_tile, k_loc = _FOLDED_CASES[case]
+    if cap is None:
+        monkeypatch.delenv("STARK_GROUPED_LANE_TILE", raising=False)
+    else:
+        monkeypatch.setenv("STARK_GROUPED_LANE_TILE", cap)
+    groups = int(g.max()) + 1
+    data, _ = synth_logistic_data(jax.random.PRNGKey(11), n, d, num_groups=groups)
+    data["g"] = jnp.asarray(g, jnp.int32)  # already sorted: one row order
+    gdata = prepare_model_data(
+        FusedHierLogisticGrouped(num_features=d, num_groups=groups), data
+    )
+    assert 128 * gdata["lt128"].shape[0] == lane_tile
+    assert gdata["k_loc"].shape[0] == k_loc
+    kb, ka = jax.random.split(jax.random.PRNGKey(7))
+    beta = 0.3 * jax.random.normal(kb, (chains, d), jnp.float32)
+    alpha = 0.5 * jax.random.normal(ka, (chains, groups), jnp.float32)
+
+    def fused_ll(b, a):
+        return hier_logistic_loglik(
+            b, a, gdata["xT"], gdata["y"], gdata["gl"], gdata["first_gid"],
+            gdata["k_loc"], gdata["lt128"],
+        )
+
+    ref = HierLogistic(num_features=d, num_groups=groups)
+
+    def ref_ll(b, a):
+        p = {"beta": b, "alpha0": 0.0, "sigma_alpha": 1.0, "alpha_raw": a}
+        return ref.log_lik(p, data)
+
+    def batched(fn):
+        return jax.vmap(jax.value_and_grad(fn, argnums=(0, 1)))
+
+    v, (gb, ga) = batched(fused_ll)(beta, alpha)
+    v0, (gb0, ga0) = batched(ref_ll)(beta, alpha)
+    for name, a, b in (("value", v, v0), ("grad_beta", gb, gb0),
+                       ("grad_alpha", ga, ga0)):
+        a, b = np.asarray(a), np.asarray(b)
+        assert a.shape == b.shape, name
+        # float32 sums in two orders, relative to the largest entry
+        assert np.max(np.abs(a - b)) <= 2e-5 * np.max(np.abs(b)), name
+
+
+def _count_primitive(jaxpr, name):
+    count = 0
+    for eqn in jaxpr.eqns:
+        count += eqn.primitive.name == name
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            count += _count_primitive(sub, name)
+    return count
+
+
+def test_grouped_kernel_body_holds_two_dots():
+    """One contraction forward and one backward a tile: at `highest` every
+    further dot costs six MXU passes over the whole (C, TILE) block, however
+    few of the array's rows it uses (the four-dot form ran at 7 % of the
+    roofline: PERF.md, PR 27)."""
+    from stark_tpu.ops.hier_fused import _grouped_call
+
+    n, d, chains, groups, k_loc, lane_tile = 1024, 8, 8, 40, 24, 512
+    jaxpr = jax.make_jaxpr(
+        lambda b, a, xt, y, gl, fg: _grouped_call(
+            b, a, xt, y, gl, fg, k_loc=k_loc, lane_tile=lane_tile,
+            interpret=True,
+        )
+    )(
+        jnp.zeros((chains, d)), jnp.zeros((chains, groups)),
+        jnp.zeros((d, n)), jnp.zeros((n,)), jnp.zeros((n,), jnp.int32),
+        jnp.zeros((n // lane_tile,), jnp.int32),
+    ).jaxpr
+    (call,) = [e for e in jaxpr.eqns if e.primitive.name == "pallas_call"]
+    assert call.params["name"] == "stark_hier_ll_grouped"
+    assert _count_primitive(call.params["jaxpr"], "dot_general") == 2
+
+
 @pytest.mark.slow
 def test_grouped_chain_batched_matches_per_chain():
     _, _, grp, gdata = _models()
@@ -142,6 +246,12 @@ def test_chain_vmem_guard():
     # every remedy the message names exists
     assert "STARK_GROUPED_LANE_TILE" in str(err.value)
     assert "offset-layout Fused" in str(err.value)
+    # the stacked (D + K_LOC, TILE) slab counts: 96 chains fit the budget
+    # without it (the LMM kernel's call) and not with it
+    _check_chain_vmem(64, 8192, False, k_loc=8, slab_rows=40)  # the cell
+    _check_chain_vmem(96, 8192, False, k_loc=8)
+    with pytest.raises(ValueError, match="chains"):
+        _check_chain_vmem(96, 8192, False, k_loc=8, slab_rows=40)
 
 
 def test_interpret_mode_is_decided_in_one_place():
