@@ -260,18 +260,20 @@ def leg_eight_schools():
 
 
 def leg_sharded_flagship(cfg, data, dry_run):
-    """Rows over a data=4 mesh: xT really lives in four shards on four
-    devices, the psum'd potential matches the single-chip one, and the
+    """Rows over a data=4 mesh: they arrive already sharded by row and are
+    prepared where they lie (nothing moves), xT really lives in four shards
+    on four devices, the mesh run's programs carry the `stark_chees_*`
+    names, the psum'd potential matches the single-chip one, and the
     flagship budget runs through the same supervised entry point."""
     import jax
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
 
-    from stark_tpu import flatten_model, prepare_model_data
+    from stark_tpu import flatten_model, prepare_model_data, telemetry
     from stark_tpu.backends import ShardedBackend
     from stark_tpu.models import FusedHierLogistic
     from stark_tpu.parallel.mesh import make_mesh, row_partition_specs
-    from stark_tpu.parallel.primitives import map_shards
+    from stark_tpu.parallel.primitives import map_shards, shard_put
     from stark_tpu.sampler import SamplerConfig
 
     mesh = make_mesh({"data": 4, "chains": 1}, devices=jax.devices()[:4])
@@ -279,7 +281,15 @@ def leg_sharded_flagship(cfg, data, dry_run):
     # the grouped model refuses row sharding by design; the mesh runs the
     # offset-layout model
     model = FusedHierLogistic(cfg["d"], cfg["groups"])
-    ap = backend.adaptive_parts(model, SamplerConfig(kernel="chees"), data)
+    # the rows as a job too large for one chip has them: global arrays
+    # already sharded by row, which the backend must leave where they are
+    rows = shard_put(data, mesh, row_partition_specs(data, "data"))
+    logged = len(telemetry.span_log())
+    ap = backend.adaptive_parts(model, SamplerConfig(kernel="chees"), rows)
+    (placing,) = [r for r in telemetry.span_log()[logged:]
+                  if r.name == "shard_data"]
+    assert placing.fields["moved_bytes"] == 0, placing.fields
+    assert ap.samp_j.__name__ == "stark_chees_sample", ap.samp_j.__name__
     xT = ap.data["xT"]
     shard_shapes = sorted({s.data.shape for s in xT.addressable_shards})
     assert len(xT.sharding.device_set) == 4, xT.sharding

@@ -195,21 +195,23 @@ class ShardedBackend:
             x, self.mesh, P("chains"), from_host_replica=True
         )
 
-    def _smap(self, fn, in_specs, out_specs, data, data_specs, donate=()):
+    def _smap(self, fn, in_specs, out_specs, data, data_specs, donate=(),
+              name=None):
         """`primitives.map_shards` over the backend mesh; a ``None``
         dataset is bound here so every compiled segment shares the
         (*args, *extra) calling convention with the single-device
         backend.  ``donate`` forwards to the outer jit's
         ``donate_argnums`` (buffer donation of carried state, e.g. the
-        streaming-diagnostics accumulators)."""
+        streaming-diagnostics accumulators), ``name`` to the program's
+        fixed name."""
         if data is None:
             return map_shards(
                 lambda *a: fn(*a, None), mesh=self.mesh, in_specs=in_specs,
-                out_specs=out_specs, donate=donate,
+                out_specs=out_specs, donate=donate, name=name,
             )
         return map_shards(
             fn, mesh=self.mesh, in_specs=in_specs + (data_specs,),
-            out_specs=out_specs, donate=donate,
+            out_specs=out_specs, donate=donate, name=name,
         )
 
     def _data_specs(self, data, row_axes):
@@ -230,6 +232,7 @@ class ShardedBackend:
         summary on the hosts once per block."""
         from ..adaptation import DualAveragingState, WelfordState
         from ..chees import (
+            CHEES_PROGRAMS,
             AdamState,
             CheesRunCarry,
             CheesWarmCarry,
@@ -241,6 +244,10 @@ class ShardedBackend:
 
         S, R = P("chains"), P()
         state_spec = HMCState(z=S, potential_energy=S, grad=S)
+        # the carries' centre, where the flat model has one (`chees.py`)
+        center_spec = (
+            R if fm.centering is not None and data is not None else None
+        )
         warm_spec = CheesWarmCarry(
             states=state_spec,
             da=DualAveragingState(R, R, R, R, R),
@@ -248,9 +255,11 @@ class ShardedBackend:
             log_T=R,
             wf=WelfordState(R, R, R),
             inv_mass=R,
+            pe_center=center_spec,
         )
         run_spec = CheesRunCarry(
-            states=state_spec, log_eps=R, log_T=R, inv_mass=R
+            states=state_spec, log_eps=R, log_T=R, inv_mass=R,
+            pe_center=center_spec,
         )
         out_spec = (P(None, "chains"), P(None, "chains"), P(None, "chains"), R)
         data_specs = self._data_specs(data, row_axes)
@@ -271,18 +280,24 @@ class ShardedBackend:
                         parts.sample_segment_diag, (run_spec, S, R, R),
                         (run_spec, S, out_spec), data, data_specs,
                         donate=(1,) if donate else (),
+                        name=CHEES_PROGRAMS["samp_diag"],
                     )
                 return self._cache[dkey]
 
             self._cache[cache_key] = (
-                self._smap(parts.init_carry, (R, S), warm_spec, data, data_specs),
+                self._smap(
+                    parts.init_carry, (R, S), warm_spec, data, data_specs,
+                    name=CHEES_PROGRAMS["init"],
+                ),
                 self._smap(
                     parts.warm_segment, (warm_spec, R, R, R, R, R),
                     (warm_spec, (R, R)), data, data_specs,
+                    name=CHEES_PROGRAMS["warm"],
                 ),
                 self._smap(
                     parts.sample_segment, (run_spec, R, R),
                     (run_spec, out_spec), data, data_specs,
+                    name=CHEES_PROGRAMS["samp"],
                 ),
                 samp_diag,
             )
@@ -374,7 +389,14 @@ class ShardedBackend:
         from ..distributed import gather_draws
 
         multiproc = jax.process_count() > 1
-        fm = flatten_model(model, axis_name="data" if data is not None else None)
+        # one flat model a (model, data-ness): the cached programs below
+        # closed over it, and its `comm` is what their traces wrote
+        fm_key = (model, "fm", data is None)
+        if fm_key not in self._cache:
+            self._cache[fm_key] = flatten_model(
+                model, axis_name="data" if data is not None else None
+            )
+        fm = self._cache[fm_key]
         row_axes = None
         if data is not None:
             data = prepare_model_data(model, data)

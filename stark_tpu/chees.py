@@ -129,6 +129,10 @@ class CheesWarmCarry(NamedTuple):
     log_T: jax.Array
     wf: WelfordState
     inv_mass: jax.Array
+    # what ``states.potential_energy`` is summed relative to (a replicated
+    # scalar; potential = carried + centre), None where the potential is
+    # the plain sum: see ``recentre`` in `make_chees_parts`
+    pe_center: Optional[jax.Array] = None
 
 
 class CheesRunCarry(NamedTuple):
@@ -138,6 +142,7 @@ class CheesRunCarry(NamedTuple):
     log_eps: jax.Array
     log_T: jax.Array
     inv_mass: jax.Array
+    pe_center: Optional[jax.Array] = None  # as `CheesWarmCarry.pe_center`
 
 
 class CheesParts(NamedTuple):
@@ -190,6 +195,30 @@ def make_chees_parts(
         L = jnp.ceil(u * jnp.exp(log_T - log_eps)).astype(jnp.int32)
         return jnp.clip(L, 1, cap)
 
+    def recentre(carry: CheesWarmCarry, data):
+        """(potential, carry) of a warm-up program.  A flat model that can
+        centre (a data-sharded potential over a model with `center_data`)
+        sums its potential relative to a constant held in the carry, so
+        that the carried energies are small numbers and their differences
+        keep float32's resolution at any number of rows.  Warm-up moves
+        far (from where MAP stopped to the typical set), so each of its
+        programs first moves the constant to the potential where the first
+        chain now stands and evaluates the ensemble again relative to it:
+        one gradient a program.  Sampling stays within tens of nats and
+        keeps the constant warm-up's last program left (`_sample_scan`)."""
+        if carry.pe_center is None:
+            return fm.bind(data), carry
+        st = carry.states
+        # one constant for the whole ensemble: the mean of the chain
+        # shards' first chains
+        pe_center = carry.pe_center + _cmean(
+            fm.centering.at(st.z[:1], st.potential_energy[:1]), chains_axis
+        )
+        potential_fn = fm.bind(data, pe_center)
+        return potential_fn, carry._replace(
+            states=init_ensemble(potential_fn, st.z), pe_center=pe_center
+        )
+
     def init_carry(key, z0, data=None) -> CheesWarmCarry:
         potential_fn = fm.bind(data)
         if cfg.map_init_steps > 0:
@@ -223,7 +252,8 @@ def make_chees_parts(
                 None,
                 length=cfg.map_init_steps,
             )
-        return CheesWarmCarry(
+        centres = fm.centering is not None and data is not None
+        carry = CheesWarmCarry(
             states=init_ensemble(potential_fn, z0),
             da=da_init(jnp.asarray(cfg.init_step_size)),
             adam=AdamState(
@@ -232,11 +262,13 @@ def make_chees_parts(
             log_T=jnp.log(jnp.asarray(T0)),
             wf=welford_init(d),
             inv_mass=jnp.ones((d,)),
+            pe_center=jnp.zeros(()) if centres else None,
         )
+        return recentre(carry, data)[1]
 
     def warm_body(potential_fn):
         def body(carry: CheesWarmCarry, x):
-            states, da, adam, log_T, wf, inv_mass = carry
+            states, da, adam, log_T, wf, inv_mass, pe_center = carry
             key, u, idx, accum, at_window = x
             log_eps = da.log_step
             states, info = chees_transition(
@@ -283,15 +315,14 @@ def make_chees_parts(
                 da_init(jnp.exp(da.log_step)),
                 da,
             )
-            return CheesWarmCarry(states, da, adam, log_T, wf, inv_mass), (
-                info.is_divergent,
-                info.num_leapfrog,
-            )
+            return CheesWarmCarry(
+                states, da, adam, log_T, wf, inv_mass, pe_center
+            ), (info.is_divergent, info.num_leapfrog)
 
         return body
 
     def warm_segment(carry, keys, us, idxs, aflags, wflags, data=None):
-        potential_fn = fm.bind(data)
+        potential_fn, carry = recentre(carry, data)
         carry, (div, nleap) = jax.lax.scan(
             warm_body(potential_fn), carry, (keys, us, idxs, aflags, wflags)
         )
@@ -304,7 +335,10 @@ def make_chees_parts(
         # nleap is the SHARED per-transition length (replicated across the
         # chains axis) — summed so the host can see where the warmup
         # gradient budget goes (the flagship wall is warmup-dominated)
-        return carry, (n_div, jnp.sum(nleap))
+        n_grad = jnp.sum(nleap)
+        if carry.pe_center is not None:
+            n_grad = n_grad + 1  # `recentre`'s evaluation of the ensemble
+        return carry, (n_div, n_grad)
 
     def finalize(carry: CheesWarmCarry) -> CheesRunCarry:
         return CheesRunCarry(
@@ -312,6 +346,7 @@ def make_chees_parts(
             log_eps=carry.da.log_avg_step,
             log_T=carry.log_T,
             inv_mass=carry.inv_mass,
+            pe_center=carry.pe_center,
         )
 
     # telemetry opt-in (cfg.progress_every): jit-safe in-loop heartbeat
@@ -329,7 +364,7 @@ def make_chees_parts(
         only CONSUMES states.z, so draws match bit-for-bit either way."""
         from .kernels.base import stream_diag_update
 
-        potential_fn = fm.bind(data)
+        potential_fn = fm.bind(data, carry.pe_center)
         # built at trace time so the interval clamps to THIS segment's
         # length (keys.shape is static per compiled variant): an interval
         # longer than one dispatch still heartbeats once per segment
@@ -362,10 +397,7 @@ def make_chees_parts(
                 info.is_divergent,
                 info.num_leapfrog,
             )
-            return (
-                (CheesRunCarry(states, c.log_eps, c.log_T, c.inv_mass), dg),
-                out,
-            )
+            return (c._replace(states=states), dg), out
 
         xs = (
             (jnp.arange(keys.shape[0]), keys, us)

@@ -16,7 +16,7 @@ covers the capability surface of `StarkModel` as documented in SURVEY.md §2/§3
 from __future__ import annotations
 
 import dataclasses
-from typing import Any, Callable, Dict, Optional, Tuple
+from typing import Any, Callable, Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -105,6 +105,21 @@ class Model:
         """
         return jax.tree.map(lambda _: 0, data)
 
+    def center_data(self, data: PyTree, center: Array) -> Optional[PyTree]:
+        """Optional: ``data`` such that ``log_lik`` returns its value less
+        the scalar ``center``, with the subtraction done INSIDE the sum
+        over rows (partial sums less their share of it), so that the
+        difference keeps float32's resolution.  None (default): the model
+        has no such sum, and the potential stays as it is.
+
+        Why: over tens of millions of rows the log-likelihood is a
+        float32 near 1e7-1e8 whose last bit is 1 to 4 nats, and the
+        accept step of every sampler lives on energy differences of a
+        tenth of a nat.  The data-sharded ChEES programs ask for the
+        potential relative to its value where the chains are (`Centering`,
+        `chees.make_chees_parts`)."""
+        return None
+
     def data_shard_row_axes(self, data: PyTree) -> PyTree:
         """Row axes for CONTIGUOUS, ORDER-PRESERVING data-axis sharding
         (the mesh "data" axis).  Defaults to ``data_row_axes``.
@@ -125,7 +140,15 @@ def prepare_model_data(model: Model, data: PyTree) -> PyTree:
 
     Entry points must NOT call ``jax.tree.map(jnp.asarray, data)`` directly —
     that skips ``Model.prepare_data`` and breaks models with custom layouts
-    (the fused Pallas models crash on a missing ``xT``)."""
+    (the fused Pallas models crash on a missing ``xT``).
+
+    Rows that arrive as global device arrays already sharded over a mesh
+    (a row stream too large for one device or a host round trip) are
+    prepared where they lie: nothing here converts a leaf to a host array
+    or puts it on one device, and a ``prepare_data`` written in
+    ``jax.numpy`` (the fused models' transpose) runs shard by shard,
+    computation following the data.  The caller owns the raw rows and
+    frees them; until then they sit beside the prepared copy."""
     if data is None:
         return None
     with telemetry.span("prepare_data", model=type(model).__name__) as sp:
@@ -180,15 +203,45 @@ class FlatModel:
     # optional: data -> Potential, replacing the default autodiff assembly
     # (used by fused Pallas paths, e.g. ops.logistic_fused)
     potential_factory: Optional[Callable[..., Potential]] = None
+    # what one gradient of the data-sharded potential sends over the mesh
+    # axis, written when ``potential_and_grad`` is traced (empty off the
+    # mesh and before any trace): ``psums_per_gradient`` and
+    # ``psum_bytes_per_chain`` (the packed operand of one chain)
+    comm: Dict[str, int] = dataclasses.field(
+        default_factory=dict, compare=False
+    )
+    # optional (data-sharded potentials of models with `center_data`): the
+    # potential summed relative to a constant, see `Centering`
+    centering: Optional["Centering"] = None
 
-    def bind(self, data=None) -> Potential:
-        """Close over a dataset -> a Potential for the kernels."""
+    def bind(self, data=None, pe_center=None) -> Potential:
+        """Close over a dataset -> a Potential for the kernels.  With
+        ``pe_center`` (`Centering`) the potential comes back less it."""
+        if pe_center is not None:
+            data = self.centering.data(data, pe_center)
         if self.potential_factory is not None:
             return self.potential_factory(data)
         return Potential(
             lambda z: self.potential(z, data),
             lambda z: self.potential_and_grad(z, data),
         )
+
+
+class Centering(NamedTuple):
+    """A potential summed relative to a constant ``pe_center``, so that
+    near the positions the constant was taken at it is a small number whose
+    differences keep float32's resolution at any number of rows
+    (`Model.center_data`; held and moved by `chees.make_chees_parts`).
+
+      at(z (C, d), pe (C,)) -> (C,)   the constant at each position ``z``
+                                      with plain potential ``pe``: the
+                                      likelihood's part of it
+      data(data, pe_center) -> data'  bound over data' the potential is the
+                                      plain one less the scalar
+    """
+
+    at: Callable[[Array, Array], Array]
+    data: Callable[[PyTree, Array], PyTree]
 
 
 def flatten_model(
@@ -240,6 +293,7 @@ def flatten_model(
     # inflate the gradient by the axis size (measured: exactly 8x on the
     # 8-shard mesh before this contract was fixed).
     sharded_ll_fn = getattr(model, "log_lik_sharded", None)
+    comm: Dict[str, int] = {}
 
     def _local_ll(params, data):
         if axis_name is not None and sharded_ll_fn is not None:
@@ -258,6 +312,10 @@ def flatten_model(
             lp = lp + lik_scale * ll
         return -lp
 
+    def prior_part(z):
+        params, fldj = constrain_with_fldj(z)
+        return prior_scale * model.log_prior(params) + fldj
+
     def potential_and_grad(flat: Array, data: PyTree = None):
         if data is None or axis_name is None:
             return jax.value_and_grad(potential)(flat, data)
@@ -267,20 +325,40 @@ def flatten_model(
             params, _ = constrain_with_fldj(z)
             return _local_ll(params, data)
 
-        from .parallel.primitives import reduce_tree
+        from .parallel.primitives import predict_tree_bytes, reduce_tree
 
         ll, ll_grad = jax.value_and_grad(local_ll)(flat)
-        packed = reduce_tree(jnp.concatenate([ll[None], ll_grad]), axis_name)
+        packed = jnp.concatenate([ll[None], ll_grad])
+        comm.update(
+            psums_per_gradient=1,
+            psum_bytes_per_chain=predict_tree_bytes(packed),
+        )
+        packed = reduce_tree(packed, axis_name)
         ll_tot, ll_grad_tot = packed[0], packed[1:]
-
-        def prior_part(z):
-            params, fldj = constrain_with_fldj(z)
-            return prior_scale * model.log_prior(params) + fldj
-
         pp, pp_grad = jax.value_and_grad(prior_part)(flat)
         pe = -(pp + lik_scale * ll_tot)
         grad = -(pp_grad + lik_scale * ll_grad_tot)
         return pe, grad
+
+    def center_at(z: Array, pe: Array):
+        # pe = -(prior + lik): what is left when the prior's part goes is
+        # the whole mesh's log-likelihood term (0 where it is not finite)
+        c = pe + jax.vmap(prior_part)(z)
+        return jax.lax.stop_gradient(jnp.where(jnp.isfinite(c), c, 0.0))
+
+    def centered_data(data: PyTree, pe_center: Array):
+        # each shard's sums take an even share of it off: rows dealt to
+        # shards at random differ by a few thousand nats, still small
+        from .parallel.primitives import mapped_axis_size
+
+        shards = lik_scale * mapped_axis_size(axis_name)
+        return model.center_data(data, -pe_center / shards)
+
+    centers = (
+        axis_name is not None
+        and getattr(type(model), "center_data", Model.center_data)
+        is not Model.center_data
+    )
 
     def init_flat(key: Array) -> Array:
         init = model.init_params(key)
@@ -295,4 +373,6 @@ def flatten_model(
         constrain=constrain,
         unconstrain=unconstrain,
         init_flat=init_flat,
+        comm=comm,
+        centering=Centering(center_at, centered_data) if centers else None,
     )

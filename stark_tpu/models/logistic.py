@@ -240,8 +240,14 @@ class FusedLogistic(TransposedXMixin, Logistic):
         from ..ops.logistic_fused import logistic_loglik
 
         return logistic_loglik(
-            _fold_scale(p["beta"], data), data["xT"], data["y"]
+            _fold_scale(p["beta"], data), data["xT"], data["y"],
+            data.get("ll_center"),
         )
+
+    def center_data(self, data, center):
+        """`Model.center_data`: the kernel's tile sums take ``center`` off
+        before they are added (`ops.logistic_fused._sum_tiles`)."""
+        return {**data, "ll_center": center}
 
 
 class FusedHierLogistic(TransposedXMixin, HierLogistic):

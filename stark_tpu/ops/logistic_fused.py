@@ -114,6 +114,21 @@ def _link_parts(link, y, logits, mask):
     raise ValueError(f"unknown link {link!r}")
 
 
+def _sum_tiles(partials, center):
+    """The tiles' partial values (grid, ...) added up, less ``center`` when
+    one is given: each tile's share of it comes off before the tiles are
+    added.  A log-likelihood over tens of millions of rows is a float32
+    near 1e7 whose last bit is a whole nat, coarser than the energy
+    differences an accept step lives on; the tiles' partials (thousands,
+    last bit 1e-4) still hold those differences, and taking a constant
+    close to the total off them tile by tile keeps them: the sum comes
+    out as a small number, the same constant away from the plain sum at
+    every position."""
+    if center is None:
+        return jnp.sum(partials, axis=0)
+    return jnp.sum(partials - center / partials.shape[0], axis=0)
+
+
 def _make_kernel(n, lane_tile, with_offset, link):
     """Tile kernel for a dataset of ``n`` rows (static)."""
 
@@ -194,12 +209,13 @@ def _make_batched_kernel(n, lane_tile, with_offset, link):
 
 
 def _batched_call(beta, xt, y, offsets, *, lane_tile, interpret,
-                  link="bernoulli_logit"):
+                  link="bernoulli_logit", center=None):
     """Chain-batched fused pass.
 
     beta: (C, D); offsets: (C, N) or None -> (val (C,), grad (C, D)
     [, resid (C, N)]).  C is padded to a sublane multiple of 8 for Mosaic
-    tiling; padded rows are discarded on return.
+    tiling; padded rows are discarded on return.  ``center`` (a scalar):
+    val comes back less it (`_sum_tiles`).
     """
     interpret = _resolve_interpret(interpret)
     c, d = beta.shape
@@ -248,7 +264,7 @@ def _batched_call(beta, xt, y, offsets, *, lane_tile, interpret,
         interpret=interpret,
         name="stark_logistic_ll",
     )(*args)
-    val = jnp.sum(out[0], axis=0)[:c, 0]
+    val = _sum_tiles(out[0], center)[:c, 0]
     grad = jnp.sum(out[1], axis=0)[:c]
     if offsets is not None:
         return val, grad, out[2][:c, :n]
@@ -256,7 +272,7 @@ def _batched_call(beta, xt, y, offsets, *, lane_tile, interpret,
 
 
 def _fused_call(beta, xt, y, offsets, *, lane_tile, interpret,
-                link="bernoulli_logit"):
+                link="bernoulli_logit", center=None):
     """Build specs and invoke the tile kernel.
 
     -> (val scalar, X-weighted resid (D,)), plus the (N,) per-row
@@ -307,7 +323,11 @@ def _fused_call(beta, xt, y, offsets, *, lane_tile, interpret,
         interpret=interpret,
         name="stark_logistic_ll_1chain",
     )(*args)
-    val, grad = jnp.sum(out[0]), jnp.sum(out[1], axis=0)[:, 0]
+    grad = jnp.sum(out[1], axis=0)[:, 0]
+    val = (
+        jnp.sum(out[0]) if center is None
+        else _sum_tiles(out[0], center)[0, 0]
+    )
     if offsets is not None:
         return val, grad, out[2][0, :n]
     return val, grad
@@ -325,30 +345,34 @@ def _bcast(x, batched, axis_size):
 
 
 def _make_vg_noff(link):
-    """No-offset fused op with the chain-batching rule, per link."""
+    """No-offset fused op with the chain-batching rule, per link.
+    ``center``: None, or the scalar the value comes back less
+    (`_sum_tiles`)."""
 
     @jax.custom_batching.custom_vmap
-    def vg_noff(beta, xt, y):
+    def vg_noff(beta, xt, y, center):
         return _fused_call(
-            beta, xt, y, None, lane_tile=None, interpret=None, link=link
+            beta, xt, y, None, lane_tile=None, interpret=None, link=link,
+            center=center,
         )
 
     @vg_noff.def_vmap
-    def _vmap_rule(axis_size, in_batched, beta, xt, y):
-        beta_b, xt_b, y_b = in_batched
-        if xt_b or y_b:  # batched data: nothing to share — map chain-wise
+    def _vmap_rule(axis_size, in_batched, beta, xt, y, center):
+        beta_b, *rest_b = in_batched
+        if any(rest_b):  # batched data or centre: nothing to share
             out = jax.lax.map(
                 lambda a: vg_noff(*a),
                 tuple(
-                    _bcast(v, b, axis_size)
-                    for v, b in zip((beta, xt, y), in_batched)
+                    v if v is None else _bcast(v, b, axis_size)
+                    for v, b in zip((beta, xt, y, center), in_batched)
                 ),
             )
             return out, (True, True)
         beta = _bcast(beta, beta_b, axis_size)
         return (
             _batched_call(
-                beta, xt, y, None, lane_tile=None, interpret=None, link=link
+                beta, xt, y, None, lane_tile=None, interpret=None, link=link,
+                center=center,
             ),
             (True, True),
         )
@@ -462,7 +486,7 @@ logistic_offset_loglik.defvjp(_off_fwd, _off_bwd)
 
 
 @jax.custom_vjp
-def logistic_loglik(beta, xt, y):
+def logistic_loglik(beta, xt, y, center=None):
     """Differentiable fused op: Bernoulli-logit log-lik of Xβ (no offset).
 
     ``xt`` is X transposed, (D, N).  One Pallas pass yields both the value
@@ -470,18 +494,23 @@ def logistic_loglik(beta, xt, y):
     ``logistic_offset_loglik`` with a zeros offset — no (N,) offset input
     is streamed in and no (N,) residual output is written back per
     evaluation.
+
+    ``center``: a scalar close to the log-lik where the chains are; the
+    value comes back less it, taken off tile by tile (`_sum_tiles`), so
+    that it keeps float32's resolution however many rows there are.  A
+    constant of the program: it gets no cotangent.
     """
-    val, _ = _vg_noff(beta, xt, y)
+    val, _ = _vg_noff(beta, xt, y, center)
     return val
 
 
-def _noff_fwd(beta, xt, y):
-    val, gbeta = _vg_noff(beta, xt, y)
+def _noff_fwd(beta, xt, y, center):
+    val, gbeta = _vg_noff(beta, xt, y, center)
     return val, gbeta
 
 
 def _noff_bwd(gbeta, ct):
-    return ct * gbeta, None, None
+    return ct * gbeta, None, None, None
 
 
 logistic_loglik.defvjp(_noff_fwd, _noff_bwd)
@@ -547,13 +576,13 @@ def gaussian_loglik(beta, xt, y, sigma):
     in and no (N,) residual written back per evaluation — only the SSR
     and X·resid leave the kernel.
     """
-    ssr, _ = _vg_gauss_noff(beta, xt, y)
+    ssr, _ = _vg_gauss_noff(beta, xt, y, None)
     n = y.shape[-1]
     return -0.5 * ssr / sigma**2 - n * jnp.log(sigma) - 0.5 * n * _LOG_2PI
 
 
 def _gauss_noff_fwd(beta, xt, y, sigma):
-    ssr, xresid = _vg_gauss_noff(beta, xt, y)
+    ssr, xresid = _vg_gauss_noff(beta, xt, y, None)
     n = y.shape[-1]
     val = -0.5 * ssr / sigma**2 - n * jnp.log(sigma) - 0.5 * n * _LOG_2PI
     return val, (xresid, ssr, sigma, jnp.asarray(float(n), jnp.float32))

@@ -76,8 +76,15 @@ def shard_data(data, mesh: Mesh, axis: str = "data", row_axes=None):
     Rows must divide evenly by the axis size (benchmark datasets are sized
     accordingly; use ``truncate_to_multiple`` first otherwise).
     row_axes: see ``row_partition_specs``.
+
+    Rows that arrive as global arrays already laid out this way stay where
+    they are (a ``device_put`` to the sharding an array has moves nothing):
+    the span ``shard_data`` says how many bytes there are, over how many
+    shards, and how many of them had to move (``moved_bytes``: 0 for rows
+    born on their chips).
     """
-    from .primitives import shard_put
+    from .. import telemetry
+    from .primitives import placed, predict_tree_bytes, shard_put
 
     size = mesh.shape[axis]
     if row_axes is None:
@@ -85,7 +92,9 @@ def shard_data(data, mesh: Mesh, axis: str = "data", row_axes=None):
     specs = row_partition_specs(data, axis, row_axes)
 
     def check(x, ax):
-        x = jnp.asarray(x)
+        # shape metadata only: a host array goes from the host straight to
+        # its shards, never whole onto one device first
+        x = x if hasattr(x, "shape") else jnp.asarray(x)
         if ax >= 0 and x.shape[ax] % size:  # row-less sentinels replicate
             raise ValueError(
                 f"rows {x.shape[ax]} not divisible by mesh axis {axis}={size}; "
@@ -93,7 +102,15 @@ def shard_data(data, mesh: Mesh, axis: str = "data", row_axes=None):
             )
         return x
 
-    return shard_put(jax.tree.map(check, data, row_axes), mesh, specs)
+    data = jax.tree.map(check, data, row_axes)
+    to_move = jax.tree.map(
+        lambda x, spec: None if placed(x, mesh, spec) else x, data, specs
+    )
+    with telemetry.span(
+        "shard_data", bytes=predict_tree_bytes(data), shards=int(size),
+        moved_bytes=predict_tree_bytes(to_move),
+    ):
+        return jax.block_until_ready(shard_put(data, mesh, specs))
 
 
 def truncate_to_multiple(data, k: int):

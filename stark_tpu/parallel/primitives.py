@@ -73,6 +73,7 @@ wrapper and restores byte-identical traces.
 
 from __future__ import annotations
 
+import functools
 import os
 import sys
 import time
@@ -270,6 +271,7 @@ def map_shards(
     out_specs: Any = None,
     check_vma: bool = False,
     donate: Sequence[int] = (),
+    name: Optional[str] = None,
 ):
     """The map primitive: ``fn`` runs once per shard of its inputs along
     the mesh ``axis``, compiled as one program.
@@ -284,7 +286,9 @@ def map_shards(
       or per-leaf spec pytrees) for mixed replicated/sharded signatures.
 
     ``donate`` forwards to the outer jit's ``donate_argnums`` (buffer
-    donation of carried state) on both paths.
+    donation of carried state) on both paths.  ``name`` is the on-mesh
+    program's fixed name (`platform.named_jit`: the trace's modules line
+    reads ``jit_<name>``); without one jax names it after ``fn``.
 
     On-mesh dispatches are comm-accounted: the returned callable wraps
     the jit so each call emits one ``comm`` event (primitive
@@ -328,13 +332,16 @@ def map_shards(
             in_specs = tuple(spec for _ in range(len(params)))
         if out_specs is None:
             out_specs = spec
-    jitted = jax.jit(
-        shard_map(
-            fn, mesh=mesh, in_specs=tuple(in_specs), out_specs=out_specs,
-            check_vma=check_vma,
-        ),
-        donate_argnums=tuple(donate),
+    mapped = shard_map(
+        fn, mesh=mesh, in_specs=tuple(in_specs), out_specs=out_specs,
+        check_vma=check_vma,
     )
+    if name is None:
+        jitted = jax.jit(mapped, donate_argnums=tuple(donate))
+    else:
+        from ..platform import named_jit
+
+        jitted = named_jit(mapped, name, donate_argnums=tuple(donate))
     if not comm_telemetry_enabled():
         return jitted
     site = _caller_site()
@@ -343,6 +350,7 @@ def map_shards(
     else:
         participants = int(mesh.size)
 
+    @functools.wraps(jitted)  # the program's name, and the jit behind it
     def _dispatch(*args):
         # deterministic hung-collective drill (watchdog / shard-deadman
         # chaos): a zero-cost no-op unless the site is armed
@@ -687,6 +695,16 @@ def _shard_put_impl(
         lambda x, spec: jax.device_put(x, NamedSharding(mesh, spec)),
         tree,
         specs,
+    )
+
+
+def placed(x, mesh: Mesh, spec) -> bool:
+    """True when ``x`` is a device array that already lies over ``mesh`` as
+    ``spec`` says: `shard_put`'s ``device_put`` to an equivalent sharding
+    hands such an array back as it is, so rows born on their chips are not
+    copied (what `mesh.shard_data`'s span reports as ``moved_bytes``)."""
+    return isinstance(x, jax.Array) and x.sharding.is_equivalent_to(
+        NamedSharding(mesh, spec), x.ndim
     )
 
 

@@ -39,7 +39,7 @@ from __future__ import annotations
 import json
 import os
 import time
-from typing import Any, Dict, Optional
+from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -143,6 +143,56 @@ def load_adapt_state(path, *, kernel, model_name, ndim, data_fp=None):
         return None, repr(e)
 
 
+def _psum_counters(fm, chains: int, backend) -> Dict[str, int]:
+    """What a gradient of a data-sharded run sends over the mesh, as the
+    potential's trace wrote it down (`model.FlatModel.comm`): how many
+    ``psum``s, and the bytes of their operand on one chip (the chains one
+    chip holds, each with its packed ``[ll, ll_grad]``).  Empty off the
+    mesh."""
+    if not fm.comm:
+        return {}
+    local_chains = chains // _mesh_shape(backend)[1]
+    return {
+        "psums_per_gradient": fm.comm["psums_per_gradient"],
+        "psum_bytes_per_gradient":
+            fm.comm["psum_bytes_per_chain"] * local_chains,
+    }
+
+
+def _mesh_shape(backend) -> Tuple[int, int]:
+    """Sizes of the backend's mesh axes ("data", "chains"); (1, 1) for a
+    backend without a mesh (one device)."""
+    shape = getattr(getattr(backend, "mesh", None), "shape", {})
+    return int(shape.get("data", 1)), int(shape.get("chains", 1))
+
+
+def _with_potential(arrays: Dict[str, Any]) -> Dict[str, Any]:
+    """Checkpoint arrays whose ``pe`` is the potential itself.  An ensemble
+    that carries its energies relative to a centre
+    (`chees.CheesRunCarry.pe_center`, collected beside them) has the two
+    added in float64, which holds both to the last bit of the float32 that
+    was carried; ``pe_center`` stays in the file for the resume."""
+    center = arrays.pop("pe_center", None)
+    if center is not None:
+        arrays["pe"] = np.asarray(arrays["pe"], np.float64) + np.float64(
+            center
+        )
+        arrays["pe_center"] = center
+    return arrays
+
+
+def _carried_potential(arrays, centred: bool):
+    """-> (pe, pe_center) as an ensemble's carry holds them, from a
+    checkpoint's arrays: `_with_potential` undone.  ``centred``: whether
+    the programs that resume carry a centre; a file without one (written
+    off the mesh) then resumes relative to 0."""
+    if not centred:
+        return arrays["pe"], None
+    center = np.float32(arrays.get("pe_center", 0.0))
+    pe = np.asarray(arrays["pe"], np.float64) - np.float64(center)
+    return pe.astype(np.float32), center
+
+
 @_profile.entrypoint
 def sample_until_converged(model: Model, data: Any = None, **kwargs):
     """Run chains until converged — see `_sample_until_converged` for the
@@ -153,8 +203,10 @@ def sample_until_converged(model: Model, data: Any = None, **kwargs):
     autotuned profile's knob defaults for the run — stark_tpu.profile;
     explicit env always wins, STARK_PROFILE=0 disables)."""
     trace = telemetry.resolve_trace(kwargs.pop("trace", None))
+    mesh_data, mesh_chains = _mesh_shape(kwargs.get("backend"))
     with telemetry.use_trace(trace), telemetry.run_span(
-        resumed=bool(kwargs.get("resume_from"))
+        resumed=bool(kwargs.get("resume_from")),
+        mesh_data=mesh_data, mesh_chains=mesh_chains,
     ):
         if lineage.enabled():
             # single-run lineage parity: one ambient job for the whole
@@ -472,7 +524,9 @@ def _sample_until_converged(
                 "wf_count": carry.wf.count,
                 "wf_mean": carry.wf.mean,
                 "wf_m2": carry.wf.m2,
+                "pe_center": carry.pe_center,
             })
+            arrays = _with_potential(arrays)
             arrays["step_size"] = np.exp(arrays["da_log_step"])
             # PRNG keys are host-side driver state, never mesh-sharded
             arrays["key"] = np.asarray(key)
@@ -777,9 +831,15 @@ def _sample_until_converged(
         # (chains-sharded state, replicated ensemble adaptation on a mesh;
         # identity/device_put on a single device)
         pc, pr = ap.put_chains, ap.put_rep
+        pe, pe_center = _carried_potential(
+            arrays,
+            is_chees and ap.fm.centering is not None and ap.data is not None,
+        )
+        if pe_center is not None:
+            pe_center = pr(jnp.asarray(pe_center))
         state = HMCState(
             z=pc(jnp.asarray(arrays["z"])),
-            potential_energy=pc(jnp.asarray(arrays["pe"])),
+            potential_energy=pc(jnp.asarray(pe)),
             grad=pc(jnp.asarray(arrays["grad"])),
         )
         # chees adaptation is ensemble-shared; per-chain kernels carry
@@ -822,6 +882,7 @@ def _sample_until_converged(
                     m2=rep("wf_m2"),
                 ),
                 inv_mass=inv_mass,
+                pe_center=pe_center,
             )
             key_warm = jnp.asarray(arrays["key_warm"])
             if reseed is not None:
@@ -855,6 +916,7 @@ def _sample_until_converged(
                 log_eps=pr(jnp.asarray(arrays["log_eps"])),
                 log_T=pr(jnp.asarray(arrays["log_T"])),
                 inv_mass=inv_mass,
+                pe_center=pe_center,
             )
         blocks_done = int(meta.get("blocks_done", 0))
         total_div = int(meta.get("num_divergent", 0))
@@ -1178,6 +1240,7 @@ def _sample_until_converged(
                     "inv_mass": inv_mass,
                     "log_eps": run_carry.log_eps,
                     "log_T": run_carry.log_T,
+                    "pe_center": run_carry.pe_center,
                     "diag": diag,
                     "len": length,
                     "outs": {"zs": zs, "accept": accept,
@@ -1294,7 +1357,8 @@ def _sample_until_converged(
             # the host's work on the block up to its record: health gate,
             # draw persistence, streaming R-hat / ESS, stop validation
             gate_span = telemetry.span(
-                "block.gate", block=blk, block_grad_evals=blk_grads
+                "block.gate", block=blk, block_grad_evals=blk_grads,
+                **_psum_counters(fm, chains, backend),
             ).open()
             if health_check:
                 # poisoned state must never reach the checkpoint; the
@@ -1500,7 +1564,9 @@ def _sample_until_converged(
                     "grad": pend["state"].grad,
                     "step_size": pend["step_size"],
                     "inv_mass": pend["inv_mass"],
+                    "pe_center": pend.get("pe_center"),
                 })
+                arrays = _with_potential(arrays)
                 # host driver state AS OF this block's dispatch: the
                 # pipeline may have split further keys for in-flight
                 # blocks, but a resume from THIS checkpoint must replay
