@@ -148,7 +148,13 @@ def prepare_model_data(model: Model, data: PyTree) -> PyTree:
     or puts it on one device, and a ``prepare_data`` written in
     ``jax.numpy`` (the fused models' transpose) runs shard by shard,
     computation following the data.  The caller owns the raw rows and
-    frees them; until then they sit beside the prepared copy."""
+    frees them; until then they sit beside the prepared copy.
+
+    The span ``prepare_data`` carries ``bytes_in``, ``bytes_out`` and,
+    where the hook added leaves to a dict of rows, ``kernel_layout``: their
+    names (``"xT,y_lanes"`` for `FusedLogistic`), which is how a run says
+    that its kernels got their operands laid out here and not in the
+    sampling loop.  Data that arrives prepared adds none."""
     if data is None:
         return None
     with telemetry.span("prepare_data", model=type(model).__name__) as sp:
@@ -156,6 +162,10 @@ def prepare_model_data(model: Model, data: PyTree) -> PyTree:
             jax.tree.map(jnp.asarray, model.prepare_data(data))
         )
         sp.note(bytes_in=_tree_nbytes(data), bytes_out=_tree_nbytes(out))
+        if isinstance(data, dict) and isinstance(out, dict):
+            laid_out = [k for k in out if k not in data]
+            if laid_out:
+                sp.note(kernel_layout=",".join(laid_out))
     return out
 
 

@@ -126,8 +126,14 @@ def _transpose_x(data):
     return out
 
 
+# `FusedLogistic`'s second kernel-layout leaf: ``y`` as the (1, N) float32
+# operand `stark_logistic_ll` takes, rows on the lanes like ``xT``'s
+Y_LANES = "y_lanes"
+
+
 def _row_axes_xt(data):
-    # rows ride axis 1 of the transposed matrix, axis 0 everywhere else.
+    # rows ride axis 1 of the transposed matrix (and of the (1, N) outcome
+    # row laid out beside it), axis 0 everywhere else.
     # Zero-length sentinel keys (e.g. the grouped model's 'offsets_path'
     # fallback marker) carry no rows: mark them None = replicated so the
     # data sharder never treats a (0,)-shaped marker as row-sharded data
@@ -143,7 +149,7 @@ def _row_axes_xt(data):
             # replicate them so every row shard dequantizes its slice of
             # the packed slab against the same global calibration
             return -1
-        return 1 if k == "xT" else 0
+        return 1 if k in ("xT", Y_LANES) else 0
 
     return {k: ax(k, v) for k, v in data.items()}
 
@@ -236,12 +242,27 @@ class FusedLogistic(TransposedXMixin, Logistic):
     def fused_tag(self):
         return "logistic"
 
+    def prepare_data(self, data):
+        """``xT`` (`_transpose_x`) and, beside it, ``y`` in the kernel's own
+        operand layout: float32, shape (1, N), made here once a run, so the
+        sampling loop hands its carry to the custom call with no operation
+        in between (`ops.logistic_fused._y_operand` says what a rank-1
+        ``y`` costs).  ``data["y"]`` stays the caller's array.  Written in
+        ``jax.numpy``: rows sharded over a mesh are laid out shard by
+        shard.  Data that already holds ``xT`` is returned as it is, with
+        or without the leaf."""
+        if "xT" in data:
+            return data
+        out = _transpose_x(data)
+        out[Y_LANES] = jnp.asarray(data["y"]).astype(jnp.float32)[None, :]
+        return out
+
     def log_lik(self, p, data):
         from ..ops.logistic_fused import logistic_loglik
 
         return logistic_loglik(
-            _fold_scale(p["beta"], data), data["xT"], data["y"],
-            data.get("ll_center"),
+            _fold_scale(p["beta"], data), data["xT"],
+            data.get(Y_LANES, data["y"]), data.get("ll_center"),
         )
 
     def center_data(self, data, center):

@@ -13,8 +13,12 @@ million-row axis rides the 128-wide TPU *lane* dimension in full native
 blocks at small D (the benchmark has D=32) fill only D of 128 lanes, which
 measured ~4x slower than XLA's own matvec; transposing recovers full-width
 streaming.  Models produce ``xT`` once per run via ``Model.prepare_data``
-(a host-side transpose outside the compiled loop), so the hot path never
-pays a layout change.
+(a transpose outside the compiled loop), so the hot path never pays a
+layout change.  ``y`` shares that promise where the model keeps it:
+`FusedLogistic.prepare_data` also makes the kernel's (1, N) float32
+outcome operand once, and `_y_operand` hands a rank-2 ``y`` through
+untouched; a rank-1 ``y`` (every other caller) is cast and reshaped at the
+call, which is free only where N divides by 1024 (`_y_operand`).
 
 Each grid step handles one (D, LANE_TILE) slab and writes its OWN
 partial-sum rows (no cross-step accumulation: Mosaic rejects
@@ -26,11 +30,17 @@ last tile is masked in-kernel from the static row count with
 ``jnp.where`` selects (NOT multiplies — 0·NaN = NaN; out-of-bounds lanes
 read unspecified values).
 
-The matvec runs on the VPU (multiply + sublane/lane reductions), not the
-MXU: matrix-vector work is bandwidth-bound so the MXU buys nothing, and
-Mosaic additionally pattern-matches dot_general+add into a
-matmul-with-accumulator it cannot compile for a non-constant accumulator
-(the per-row offset).
+Two kernels.  The chain-batched one (`_make_batched_kernel`,
+``stark_logistic_ll``: what every vmapped ensemble, and so every benchmark
+cell of this model, runs) holds the (C, D) block of all chains' beta and
+does two MXU dots a tile at `_dot_precision()`: logits (C, D) x (D, TILE),
+then the gradient (C, TILE) x (TILE, D); one X pass serves all chains.
+The one-chain kernel (`_make_kernel`, ``stark_logistic_ll_1chain``) does
+its matvec on the VPU (multiply + sublane/lane reductions): with one
+chain the work is bandwidth-bound, so the MXU buys nothing, and Mosaic
+pattern-matches dot_general+add into a matmul-with-accumulator it cannot
+compile for a non-constant accumulator (the per-row offset; adding the
+offset AFTER a complete dot, as the batched kernel does, lowers fine).
 
 On the CPU backend, which has no Mosaic, the kernels run under the Pallas
 interpreter (`_resolve_interpret`): that keeps the tests and the
@@ -129,6 +139,26 @@ def _sum_tiles(partials, center):
     return jnp.sum(partials - center / partials.shape[0], axis=0)
 
 
+def _y_operand(y, n):
+    """The kernel's ``y`` operand, (1, n) float32.  A rank-2 ``y`` is that
+    operand already (`FusedLogistic.prepare_data` lays it out once a run)
+    and goes to ``pallas_call`` as it is: no operation stands between a
+    sampling loop's carry and the custom call.  A rank-1 ``y`` is cast and
+    given its leading axis here, inside whatever loop the call sits in:
+    free where n divides by 1024 (a bitcast), a chunked copy of the whole
+    vector before every call where it does not (the loop's ``f32[n]``
+    carry is tiled T(1024), the operand T(1,128), and the two pad
+    differently)."""
+    if y.ndim == 2:
+        if y.shape != (1, n) or y.dtype != jnp.float32:
+            raise ValueError(
+                f"a rank-2 y is the kernel's operand as it stands and must "
+                f"be float32 of shape (1, {n}), got {y.dtype}{y.shape}"
+            )
+        return y
+    return y.astype(jnp.float32)[None, :]
+
+
 def _make_kernel(n, lane_tile, with_offset, link):
     """Tile kernel for a dataset of ``n`` rows (static)."""
 
@@ -212,10 +242,11 @@ def _batched_call(beta, xt, y, offsets, *, lane_tile, interpret,
                   link="bernoulli_logit", center=None):
     """Chain-batched fused pass.
 
-    beta: (C, D); offsets: (C, N) or None -> (val (C,), grad (C, D)
-    [, resid (C, N)]).  C is padded to a sublane multiple of 8 for Mosaic
-    tiling; padded rows are discarded on return.  ``center`` (a scalar):
-    val comes back less it (`_sum_tiles`).
+    beta: (C, D); y: (N,), or (1, N) float32 (`_y_operand`); offsets:
+    (C, N) or None -> (val (C,), grad (C, D) [, resid (C, N)]).  C is
+    padded to a sublane multiple of 8 for Mosaic tiling; padded rows are
+    discarded on return.  ``center`` (a scalar): val comes back less it
+    (`_sum_tiles`).
     """
     interpret = _resolve_interpret(interpret)
     c, d = beta.shape
@@ -233,7 +264,7 @@ def _batched_call(beta, xt, y, offsets, *, lane_tile, interpret,
     def lane_spec(height=1):
         return pl.BlockSpec((height, lane_tile), lambda i: (0, i))
 
-    args = [_stream_arg(xt), y.astype(jnp.float32)[None, :]]
+    args = [_stream_arg(xt), _y_operand(y, n)]
     in_specs = [lane_spec(d), lane_spec()]
     if offsets is not None:
         args.append(offsets.astype(jnp.float32))
@@ -275,6 +306,7 @@ def _fused_call(beta, xt, y, offsets, *, lane_tile, interpret,
                 link="bernoulli_logit", center=None):
     """Build specs and invoke the tile kernel.
 
+    ``y``: (N,), or (1, N) float32 (`_y_operand`).
     -> (val scalar, X-weighted resid (D,)), plus the (N,) per-row
     residual when ``offsets`` is given.  Semantics are link-dependent
     (see _link_parts): for bernoulli_logit val IS the log-lik and the
@@ -290,7 +322,7 @@ def _fused_call(beta, xt, y, offsets, *, lane_tile, interpret,
     def lane_spec(height=1):
         return pl.BlockSpec((height, lane_tile), lambda i: (0, i))
 
-    args = [_stream_arg(xt), y.astype(jnp.float32)[None, :]]
+    args = [_stream_arg(xt), _y_operand(y, n)]
     in_specs = [lane_spec(d), lane_spec()]
     if offsets is not None:
         args.append(offsets.astype(jnp.float32)[None, :])
@@ -489,11 +521,12 @@ logistic_offset_loglik.defvjp(_off_fwd, _off_bwd)
 def logistic_loglik(beta, xt, y, center=None):
     """Differentiable fused op: Bernoulli-logit log-lik of Xβ (no offset).
 
-    ``xt`` is X transposed, (D, N).  One Pallas pass yields both the value
-    and ∂/∂β, so the VJP never re-reads X and — unlike routing through
-    ``logistic_offset_loglik`` with a zeros offset — no (N,) offset input
-    is streamed in and no (N,) residual output is written back per
-    evaluation.
+    ``xt`` is X transposed, (D, N); ``y`` is (N,), or the (1, N) float32
+    operand a model laid out in ``prepare_data`` (`_y_operand`).  One
+    Pallas pass yields both the value and ∂/∂β, so the VJP never re-reads
+    X and — unlike routing through ``logistic_offset_loglik`` with a zeros
+    offset — no (N,) offset input is streamed in and no (N,) residual
+    output is written back per evaluation.
 
     ``center``: a scalar close to the log-lik where the chains are; the
     value comes back less it, taken off tile by tile (`_sum_tiles`), so

@@ -18,7 +18,7 @@ from stark_tpu.model import flatten_model
 from stark_tpu.models import FusedLogistic, Logistic
 from stark_tpu.models.logistic import synth_logistic_data
 from stark_tpu.ops.logistic_fused import _sum_tiles, logistic_loglik
-from stark_tpu.parallel.mesh import make_mesh
+from stark_tpu.parallel.mesh import make_mesh, row_partition_specs
 from stark_tpu.parallel.primitives import map_shards
 from stark_tpu.sampler import SamplerConfig
 
@@ -29,6 +29,12 @@ N, D, C = 4096, 8, 8
 def rows():
     data, true = synth_logistic_data(jax.random.PRNGKey(5), N, D)
     return prepare_model_data(FusedLogistic(D), data), true["beta"]
+
+
+def _row_specs(data):
+    """The prepared leaves cut by row as the sharded backend cuts them."""
+    return row_partition_specs(
+        data, "data", FusedLogistic(D).data_shard_row_axes(data))
 
 
 @pytest.fixture(scope="module")
@@ -108,7 +114,7 @@ def test_centred_potential_is_the_plain_one_less_the_centre(rows, mesh):
         pe_c, grad_c = jax.vmap(fm.bind(data, pe_center).value_and_grad)(z)
         return pe, grad, pe_c, grad_c, pe_center
 
-    specs = {"xT": P(None, "data"), "y": P("data")}
+    specs = _row_specs(data)
     pe, grad, pe_c, grad_c, pe_center = map_shards(
         body, mesh=mesh, in_specs=(P(), specs),
         out_specs=(P(), P(), P(), P(), P()))(z, data)
@@ -169,7 +175,7 @@ def test_only_warm_up_programs_move_the_centre(rows, mesh):
 
     sharded = make_chees_parts(
         flatten_model(FusedLogistic(D), axis_name="data"), cfg)
-    specs = (P(), {"xT": P(None, "data"), "y": P("data")})
+    specs = (P(), _row_specs(data))
 
     def mapped(fn, n_args):
         return jax.shard_map(

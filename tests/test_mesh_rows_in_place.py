@@ -14,7 +14,7 @@ from stark_tpu.backends import JaxBackend, ShardedBackend
 from stark_tpu.chees import CHEES_PROGRAMS
 from stark_tpu.model import flatten_model
 from stark_tpu.models import FusedLogistic
-from stark_tpu.models.logistic import synth_logistic_data
+from stark_tpu.models.logistic import Y_LANES, synth_logistic_data
 from stark_tpu.parallel.mesh import make_mesh, shard_data
 from stark_tpu.parallel.primitives import map_shards, placed, shard_put
 from stark_tpu.runner import _mesh_shape, _psum_counters
@@ -64,6 +64,20 @@ def test_prepare_keeps_sharded_rows_on_their_devices(mesh, sharded_rows):
                                       np.asarray(st.data))
 
 
+def test_prepare_cuts_y_lanes_with_the_rows_of_xT(mesh, sharded_rows):
+    """`FusedLogistic`'s (1, N) outcome leaf (PR 29) has row axis 1: made
+    shard by shard where `y` lies, in the sharding `shard_data` asks for."""
+    model = FusedLogistic(D)
+    data = prepare_model_data(model, sharded_rows)
+    assert model.data_shard_row_axes(data) == {"xT": 1, "y": 0, Y_LANES: 1}
+    assert placed(data[Y_LANES], mesh, P(None, "data"))
+    for sy, sl in zip(sharded_rows["y"].addressable_shards,
+                      data[Y_LANES].addressable_shards):
+        assert sy.device == sl.device and sl.data.shape == (1, N // 4)
+        np.testing.assert_array_equal(np.asarray(sy.data)[None],
+                                      np.asarray(sl.data))
+
+
 def test_shard_data_moves_nothing_that_is_placed(mesh, sharded_rows):
     model = FusedLogistic(D)
     data = prepare_model_data(model, sharded_rows)
@@ -72,9 +86,10 @@ def test_shard_data_moves_nothing_that_is_placed(mesh, sharded_rows):
                      row_axes=model.data_shard_row_axes(data))
     (sp,) = _spans("shard_data", since)
     assert sp.fields["moved_bytes"] == 0 and sp.fields["shards"] == 4
-    assert sp.fields["bytes"] == N * D * 4 + N * 4
+    # xT, y and y in the kernel's layout beside it
+    assert sp.fields["bytes"] == N * D * 4 + N * 4 + N * 4
     # not a copy: the very arrays that came in
-    assert out["xT"] is data["xT"] and out["y"] is data["y"]
+    assert all(out[k] is data[k] for k in ("xT", "y", Y_LANES))
 
 
 def test_shard_data_counts_what_it_moves_from_the_host(mesh, host_rows):

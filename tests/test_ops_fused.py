@@ -1,6 +1,9 @@
 """Fused Pallas logistic kernel vs autodiff oracle (interpret mode on CPU)."""
 
+import functools
+
 import jax
+import jax.extend.core
 import jax.numpy as jnp
 import numpy as np
 
@@ -245,3 +248,136 @@ def test_gaussian_offset_loglik_matches_autodiff():
         np.testing.assert_allclose(
             np.asarray(a), np.asarray(b), rtol=2e-4, atol=2e-4
         )
+
+
+# --- y in the kernel's own layout (PR 29) -------------------------------
+# 4224 + 37 rows: not a multiple of 1024 (where XLA's re-layout of a rank-1
+# y is a copy, not a bitcast) and a ragged last tile at 128 lanes
+
+
+_N_RAGGED = 4224 + 37
+
+
+def _y_rank_case(link, with_offsets, chains):
+    d = 5
+    k = jax.random.split(jax.random.PRNGKey(11), 4)
+    xt = jax.random.normal(k[0], (d, _N_RAGGED))
+    shape = (d,) if chains is None else (chains, d)
+    beta = 0.4 * jax.random.normal(k[1], shape)
+    offsets = None
+    if with_offsets:
+        offsets = 0.3 * jax.random.normal(k[2], shape[:-1] + (_N_RAGGED,))
+    y = jax.random.uniform(k[3], (_N_RAGGED,))
+    if link == "bernoulli_logit":
+        y = (y < 0.4).astype(jnp.float32)
+    return beta, xt, y, offsets
+
+
+@pytest.mark.parametrize("centered", [False, True], ids=["plain", "centered"])
+@pytest.mark.parametrize("with_offsets", [False, True], ids=["noff", "off"])
+@pytest.mark.parametrize("link", ["bernoulli_logit", "gaussian"])
+@pytest.mark.parametrize("call", ["_batched_call", "_fused_call"])
+def test_rank2_y_is_bit_identical_to_rank1(call, link, with_offsets, centered):
+    """A (1, N) float32 `y` goes to the kernel as it is and gives the bits
+    the rank-1 path gives: value, gradient and per-row residual."""
+    from stark_tpu.ops import logistic_fused as lf
+
+    beta, xt, y, offsets = _y_rank_case(
+        link, with_offsets, 3 if call == "_batched_call" else None
+    )
+    center = jnp.float32(-1234.5) if centered else None
+    fn = jax.jit(functools.partial(
+        getattr(lf, call), lane_tile=128, interpret=True, link=link
+    ))
+    flat = fn(beta, xt, y, offsets, center=center)
+    lanes = fn(beta, xt, y[None, :], offsets, center=center)
+    assert len(flat) == (3 if with_offsets else 2)
+    for a, b in zip(flat, lanes):
+        assert a.shape == b.shape
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    assert np.all(np.isfinite(np.asarray(flat[0])))
+
+
+def test_rank2_y_must_be_the_operand_itself():
+    """No cast and no reshape on the rank-2 path: anything but (1, N)
+    float32 is refused, not repaired inside the loop."""
+    from stark_tpu.ops import logistic_fused as lf
+
+    beta, xt, y, _ = _y_rank_case("bernoulli_logit", False, 3)
+    for bad in (y[None, :].astype(jnp.bfloat16), y[None, :-1],
+                jnp.stack([y, y])):
+        with pytest.raises(ValueError, match="float32 of shape"):
+            lf._batched_call(beta, xt, bad, None, lane_tile=128,
+                             interpret=True)
+
+
+def _touches_rows(jaxpr, n, env):
+    """(each pallas_call's operands traced back to the outermost jaxpr's
+    inputs, None where an equation made them; every other equation, at any
+    depth, that reads an array with an `n`-long axis).  ``env``: this
+    jaxpr's variables that are outermost inputs."""
+    calls, others = [], []
+    for eqn in jaxpr.eqns:
+        src = [
+            env.get(v) if isinstance(v, jax.extend.core.Var) else None
+            for v in eqn.invars
+        ]
+        if eqn.primitive.name == "pallas_call":
+            calls.append(src)
+            continue
+        subs = [
+            getattr(p, "jaxpr", p) for p in eqn.params.values()
+            if hasattr(getattr(p, "jaxpr", p), "eqns")
+        ]
+        if not subs and any(
+            n in getattr(v.aval, "shape", ()) for v in eqn.invars
+        ):
+            others.append(eqn.primitive.name)
+        for sub in subs:
+            # a call's operands are its body's inputs one for one; any
+            # other nesting resolves to None and fails the guard
+            same = len(sub.invars) == len(src)
+            c, o = _touches_rows(
+                sub, n, dict(zip(sub.invars, src)) if same else {}
+            )
+            calls += c
+            others += o
+    return calls, others
+
+
+@pytest.mark.parametrize("layout", ["y_lanes", "older_layout"])
+def test_prepared_y_reaches_the_kernel_untouched(layout):
+    """The guard: in `vmap(value_and_grad)` of FusedLogistic's potential
+    over prepared data the kernel's `y` operand is an input of the jaxpr
+    itself, and no other equation reads an N-long array, so no reshape,
+    broadcast or cast of the outcomes can sit in a sampling loop again
+    without this failing on the CPU.  Data laid out by an older tree (no
+    leaf) takes the rank-1 path, whose re-layout the walk does see."""
+    from stark_tpu.models import FusedLogistic
+    from stark_tpu.models.logistic import Y_LANES
+
+    model = FusedLogistic(5)
+    raw, _ = synth_logistic_data(jax.random.PRNGKey(3), _N_RAGGED, 5)
+    data = stark_tpu.prepare_model_data(model, raw)
+    assert data["y"] is raw["y"]
+    assert data[Y_LANES].shape == (1, _N_RAGGED)
+    assert data[Y_LANES].dtype == jnp.float32
+    if layout == "older_layout":
+        data = {k: v for k, v in data.items() if k != Y_LANES}
+    fm = flatten_model(model)
+    closed = jax.make_jaxpr(
+        lambda z, dd: jax.vmap(lambda zz: fm.potential_and_grad(zz, dd))(z)
+    )(jnp.zeros((8, fm.ndim)), data)
+    top = closed.jaxpr
+    names = dict(zip(top.invars, ["z"] + sorted(data)))
+    calls, others = _touches_rows(top, _N_RAGGED, {v: v for v in top.invars})
+    assert len(calls) == 1
+    xt_src, y_src = calls[0][:2]
+    assert names.get(xt_src) == "xT"
+    if layout == "y_lanes":
+        assert names.get(y_src) == Y_LANES
+        assert others == []
+    else:
+        assert y_src is None  # made inside: the re-layout
+        assert others and set(others) <= {
+            "reshape", "broadcast_in_dim", "convert_element_type"}
