@@ -15,6 +15,7 @@ backend decision.  Provided backends:
 
 from __future__ import annotations
 
+import dataclasses
 from typing import Any, Dict, NamedTuple, Optional, Protocol, runtime_checkable
 
 
@@ -60,6 +61,103 @@ class AdaptiveParts(NamedTuple):
     samp_diag: Any = None
     seg_warmup: Any = None
     get_block: Any = None
+
+
+class KernelEnv(NamedTuple):
+    """What a `BlockKernel` takes from the call it serves, beside the
+    backend's parts, the sampler's configuration and the chain count."""
+
+    block_size: int  # warm-up runs in segments of this length too
+    stream_diag: bool  # asked for; the kernel's own attribute is what it can
+    sync_blocks: bool  # serial loop: the diagnostics carry may be donated
+    diag_lags: int
+    seed: int
+    init_params: Any
+    trace: Any
+    emit: Any  # the run's record sink (``adapt_*`` decisions)
+    model_name: str
+    checkpoint_path: Optional[str] = None  # mid-warm-up checkpoints
+    health_check: bool = False
+    adapt_path: Optional[str] = None
+    adapt_export_path: Optional[str] = None
+    adapt_touchup_frac: float = 0.2
+    adapt_fp: Optional[str] = None
+
+
+@dataclasses.dataclass
+class PendingBlock:
+    """One dispatched draw block until the host has processed it: every
+    device reference of the block lives here, and is freed with it."""
+
+    length: int
+    outs: Any  # the block's device outputs, in the kernel's own layout
+    diag: Any  # the streaming-diagnostics carry after the block, or None
+    # the state carried out of the block under the checkpoint's names (z,
+    # pe, grad, step_size, inv_mass): what the health check gates
+    carried: Dict[str, Any]
+    extras: Any = None  # what else the kernel's checkpoint needs
+    key: Any = None  # the host key as of this block's split (the loop's)
+    t_enq: float = 0.0  # seconds the dispatch took (the loop's)
+
+
+def carried_state(state, step_size, inv_mass) -> Dict[str, Any]:
+    """`PendingBlock.carried` of an `HMCState` and its step and mass."""
+    return {"z": state.z, "pe": state.potential_energy, "grad": state.grad,
+            "step_size": step_size, "inv_mass": inv_mass}
+
+
+def restored_key(arrays, name, reseed):
+    """A checkpoint's PRNG key.  A deterministic numerical failure would
+    replay identically from it on every retry, so the supervisor passes
+    the attempt number (``reseed``) to branch the stream."""
+    import jax
+
+    key = jax.numpy.asarray(arrays[name])
+    return key if reseed is None else jax.random.fold_in(key, reseed)
+
+
+class HostBlock(NamedTuple):
+    """A finished block on the host, as the block loop reads it."""
+
+    zs: Any  # (chains, block, d); may be a view of ``zs_dm``
+    zs_dm: Any  # the block draw-major (block, chains, d), or None
+    accept: Any  # (chains, block)
+    divergent: Any  # (chains, block)
+    mean_accept: float
+    grad_evals: int  # gradient evaluations of the block, all chains
+    energy: Any = None  # (chains, block), per-chain kernels, when asked for
+    ngrad: Any = None  # (chains, block), per-chain kernels
+    sched_fields: Any = {}  # lane occupancy of a ragged-NUTS block
+
+
+class BlockKernel(Protocol):
+    """The seam between the runner's block loop and its sampler.  The loop
+    owns blocks, diagnostics, records and when a checkpoint is written; the
+    kernel owns the sampler's carried state, its warm-up and what a
+    checkpoint of it holds: `chees.CheesBlockKernel` (the ensemble
+    sampler) and `sampler.ChainBlockKernel` (NUTS / HMC), each beside the
+    state it hides.  ``start`` and ``restore`` return the run's key, the
+    warm-up's divergences and the other fields of a ``warmup_done`` record
+    (None from a sampling-phase checkpoint: no warm-up ran)."""
+
+    chains: int  # the checkpoint's on a resume
+    stream_diag: bool  # whether its blocks carry the diagnostics accumulators
+    step_size: Any  # device value(s), for the ``warmup_done`` record
+    dtype: Any  # of the positions
+
+    def start(self): ...  # keys from the seed, positions, (MAP,) warm-up
+
+    def restore(self, arrays, meta, reseed): ...  # + the rest of a warm-up
+
+    def dispatch(self, key_block, length, diag, first_draw) -> PendingBlock:
+        """Enqueue a block without waiting; ``first_draw`` counts the
+        draws dispatched before it."""
+
+    def host_block(self, pending, energy=False) -> HostBlock: ...
+
+    def checkpoint_arrays(self, pending) -> Dict[str, Any]:
+        """The kernel's part of a sampling-phase checkpoint: host arrays
+        under the file's names, ``key`` among them."""
 
 
 def annotate_dispatch(sample_stats: Dict[str, Any], dispatch_steps) -> None:

@@ -36,9 +36,10 @@ the legacy kernel's — the transition keys come from the same
 (key_mom, key_loop) split, each doubling round the same 4-way split, each
 leaf the same `nuts._leaf_step` (shared code, not a copy) — so the draws,
 accept statistics, divergence flags, energies and grad-eval counts are
-BIT-IDENTICAL to `sampler.make_block_runner`'s nested scan, per lane,
-independent of batch composition (tests/test_ragged_nuts.py pins all of
-it).  Only the execution interleaving across lanes changes.
+EQUAL TO ROUNDING to `sampler.make_block_runner`'s nested scan (integers
+exactly, floats to 1e-5: two XLA programs), per lane, independent of batch
+composition (tests/test_ragged_nuts.py).  Only the execution interleaving
+across lanes changes.
 
 Occupancy accounting rides in the carry: ``iters`` counts the iterations
 a lane was still working (== its useful gradient evaluations — one leaf
@@ -86,7 +87,7 @@ from .nuts import (
 Array = jax.Array
 
 #: env knob: "1" routes NUTS block runners through the step-synchronized
-#: scheduler; default off — the legacy nested scan runs bit-identically
+#: scheduler; default off — the legacy nested scan runs as it always did
 RAGGED_NUTS_ENV = "STARK_RAGGED_NUTS"
 
 

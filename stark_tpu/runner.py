@@ -36,21 +36,24 @@ Auxiliary subsystems wired here (SURVEY.md §6):
 
 from __future__ import annotations
 
+import contextlib
+import dataclasses
 import json
 import os
 import time
 from typing import Any, Dict, Optional, Tuple
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 
 from . import diagnostics, faults, health as _health, lineage, telemetry
 from . import profile as _profile
-from .kernels.base import HMCState
+from .backends.base import KernelEnv
+from .chees import CheesBlockKernel, load_adapt_state  # noqa: F401 (re-export)
 from .ops import quantize as _quantize
 from .model import Model
-from .sampler import Posterior, SamplerConfig, _constrain_draws
+from .sampler import ChainBlockKernel, Posterior, SamplerConfig
+from .sampler import _constrain_draws
 
 
 class AdaptiveResult(Posterior):
@@ -68,9 +71,6 @@ class AdaptiveResult(Posterior):
         # statistical-health verdict (stark_tpu.health): sorted warning
         # names the observatory raised; None when STARK_HEALTH=0
         self.health_warnings = None
-
-
-_ADAPT_KEYS = ("z", "log_eps", "log_T", "inv_mass")
 
 
 def data_fingerprint(data) -> str:
@@ -101,48 +101,6 @@ def data_fingerprint(data) -> str:
     return h.hexdigest()[:16]
 
 
-def load_adapt_state(path, *, kernel, model_name, ndim, data_fp=None):
-    """Load + validate an adaptation-import artifact (``adapt_path``).
-
-    Returns ``(arrays, None)`` on success, ``(None, reason)`` on any
-    missing/corrupt/mismatched file — the ONE validation used both by
-    the runner's import and by callers deciding whether to skip MAP
-    descent (a skip decided on mere file existence would combine
-    "no MAP" with "no import" when the load is later rejected).
-    ``reason`` is None only when the file simply does not exist.
-    """
-    if not path or not os.path.exists(path):
-        return None, None
-    from .checkpoint import load_checkpoint
-
-    try:
-        arrays, meta = load_checkpoint(path)
-        missing = [k for k in _ADAPT_KEYS if k not in arrays]
-        if missing:
-            return None, f"missing arrays: {missing}"
-        if (
-            meta.get("kernel") != kernel
-            or meta.get("model") != model_name
-            or int(arrays["inv_mass"].shape[-1]) != ndim
-        ):
-            return None, (
-                f"mismatch: kernel={meta.get('kernel')} "
-                f"model={meta.get('model')} "
-                f"ndim={arrays['inv_mass'].shape[-1]} "
-                f"(want {kernel}/{model_name}/{ndim})"
-            )
-        if data_fp is not None and meta.get("data_fp") != data_fp:
-            # an artifact tuned on a DIFFERENT dataset (or one predating
-            # fingerprints) must not seed this run's positions/mass
-            return None, (
-                f"mismatch: data_fp={meta.get('data_fp')} (want {data_fp}; "
-                "artifact was adapted on a different dataset)"
-            )
-        return arrays, None
-    except Exception as e:  # noqa: BLE001 — corrupt import file
-        return None, repr(e)
-
-
 def _psum_counters(fm, chains: int, backend) -> Dict[str, int]:
     """What a gradient of a data-sharded run sends over the mesh, as the
     potential's trace wrote it down (`model.FlatModel.comm`): how many
@@ -166,33 +124,6 @@ def _mesh_shape(backend) -> Tuple[int, int]:
     return int(shape.get("data", 1)), int(shape.get("chains", 1))
 
 
-def _with_potential(arrays: Dict[str, Any]) -> Dict[str, Any]:
-    """Checkpoint arrays whose ``pe`` is the potential itself.  An ensemble
-    that carries its energies relative to a centre
-    (`chees.CheesRunCarry.pe_center`, collected beside them) has the two
-    added in float64, which holds both to the last bit of the float32 that
-    was carried; ``pe_center`` stays in the file for the resume."""
-    center = arrays.pop("pe_center", None)
-    if center is not None:
-        arrays["pe"] = np.asarray(arrays["pe"], np.float64) + np.float64(
-            center
-        )
-        arrays["pe_center"] = center
-    return arrays
-
-
-def _carried_potential(arrays, centred: bool):
-    """-> (pe, pe_center) as an ensemble's carry holds them, from a
-    checkpoint's arrays: `_with_potential` undone.  ``centred``: whether
-    the programs that resume carry a centre; a file without one (written
-    off the mesh) then resumes relative to 0."""
-    if not centred:
-        return arrays["pe"], None
-    center = np.float32(arrays.get("pe_center", 0.0))
-    pe = np.asarray(arrays["pe"], np.float64) - np.float64(center)
-    return pe.astype(np.float32), center
-
-
 @_profile.entrypoint
 def sample_until_converged(model: Model, data: Any = None, **kwargs):
     """Run chains until converged — see `_sample_until_converged` for the
@@ -208,21 +139,19 @@ def sample_until_converged(model: Model, data: Any = None, **kwargs):
         resumed=bool(kwargs.get("resume_from")),
         mesh_data=mesh_data, mesh_chains=mesh_chains,
     ):
+        job = contextlib.nullcontext()
         if lineage.enabled():
-            # single-run lineage parity: one ambient job for the whole
-            # run (the supervisor's outer job wins, so every restart
-            # attempt correlates to ONE id; otherwise mint
-            # deterministically from the model/seed — a resumed run
-            # re-mints the same id)
-            jid = lineage.current_job() or lineage.mint_job_id(
-                getattr(model, "tag", type(model).__name__),
-                int(kwargs.get("seed", 0)),
-            )
-            with lineage.use_job(jid):
-                return _sample_until_converged(
-                    model, data, trace=trace, **kwargs
-                )
-        return _sample_until_converged(model, data, trace=trace, **kwargs)
+            # single-run lineage parity: one ambient job for the whole run
+            # (the supervisor's outer job wins, so every restart attempt
+            # correlates to ONE id; otherwise mint deterministically from
+            # the model/seed — a resumed run re-mints the same id)
+            job = lineage.use_job(
+                lineage.current_job() or lineage.mint_job_id(
+                    getattr(model, "tag", type(model).__name__),
+                    int(kwargs.get("seed", 0)),
+                ))
+        with job:
+            return _sample_until_converged(model, data, trace=trace, **kwargs)
 
 
 def _sample_until_converged(
@@ -458,7 +387,6 @@ def _sample_until_converged(
         )
     with trace.phase("compile", stage="build"):
         ap = backend.adaptive_parts(model, cfg, data)
-    fm, data, extra = ap.fm, ap.data, ap.extra
 
     if sync_blocks is None:
         # multi-process meshes run serial: collect is a process_allgather
@@ -471,337 +399,175 @@ def _sample_until_converged(
             or jax.process_count() > 1
         )
 
-    is_chees = cfg.kernel == "chees"
-    ragged = False  # resolved on the per-chain branch below
-    if is_chees:
-        # ensemble kernel: blocks advance the whole ensemble through
-        # chees sample segments (frozen adaptation), checkpointed as a
-        # CheesRunCarry — same block/checkpoint/metrics protocol as the
-        # per-chain kernels below
-        from .chees import chees_init_positions
-        from .kernels.chees import halton
+    run = _Run(
+        cfg=cfg, ap=ap, backend=backend, trace=trace, monitor=monitor,
+        model_name=type(model).__name__, fused_tag=fused_tag,
+        block_size=block_size, max_blocks=max_blocks, min_blocks=min_blocks,
+        rhat_target=rhat_target, ess_target=ess_target,
+        diag_components=diag_components, diag_lags=diag_lags,
+        checkpoint_path=checkpoint_path, health_check=health_check,
+        progress_cb=progress_cb, time_budget_s=time_budget_s,
+        profile_dir=profile_dir, sync_blocks=sync_blocks,
+        adaptive_blocks=adaptive_blocks,
+    )
+    # the kernel seam (`backends.base.BlockKernel`): the ensemble sampler
+    # or the per-chain kernels, as the backend's parts say
+    kernel_cls = CheesBlockKernel if ap.chees is not None else ChainBlockKernel
+    run.kernel = kernel_cls(ap, cfg, chains, KernelEnv(
+        block_size=block_size, stream_diag=stream_diag,
+        sync_blocks=sync_blocks, diag_lags=diag_lags, seed=seed,
+        init_params=init_params, trace=trace, emit=run.emit,
+        model_name=run.model_name, checkpoint_path=checkpoint_path,
+        health_check=health_check, adapt_path=adapt_path,
+        adapt_export_path=adapt_export_path,
+        adapt_touchup_frac=adapt_touchup_frac, adapt_fp=adapt_fp,
+    ))
+    run.t_start = time.perf_counter()
+    run.metrics_f = open(metrics_path, "a") if metrics_path else None
+    try:
+        _setup(run, resume_from, reseed, draw_store_path)
+        _block_loop(run)
+    finally:
+        if run.metrics_f:
+            run.metrics_f.close()
+        if run.draw_store is not None:
+            run.draw_store.close()
+    result = _collect(run, t_run0)
+    # the kernel holds `run.emit`: cut the cycle, nothing waits for a gc
+    run.kernel = run.pending = None
+    return result
 
-        parts = ap.chees
-        chees_init_j, chees_warm_j, chees_samp_j = (
-            ap.init_j, ap.warm_j, ap.samp_j,
-        )
-        if stream_diag and ap.samp_diag is None:
-            stream_diag = False  # backend without the streaming segment
-        # donation of the diag carry is safe only when a block's
-        # accumulators are read back BEFORE the next block is dispatched
-        # — i.e. the serial loop; the pipeline reads block k's diag while
-        # block k+1 (which consumed it) is already in flight
-        chees_samp_diag_j = (
-            ap.samp_diag(donate=sync_blocks) if stream_diag else None
-        )
 
-        def save_warmup_checkpoint(path, carry, key, key_warm, done, nd, nl):
-            """Warmup-phase checkpoint: the full CheesWarmCarry, so a
-            fault mid-warmup resumes at the last finished segment instead
-            of burning the whole (dominant) warmup budget again."""
-            ckpt_span = telemetry.span("block.checkpoint", stage="warmup").open()
-            from .checkpoint import save_checkpoint
+@dataclasses.dataclass
+class _Run:
+    """One call of the runner: its configuration, its collaborators and the
+    block loop's state, in one place for `_setup`, `_block_loop` and
+    `_collect`.  The sampler's own carried state is the kernel's."""
 
-            # ap.collect (gather_draws on a mesh) materializes the
-            # chain-sharded leaves on every host — np.asarray alone
-            # cannot read non-addressable shards on multi-process meshes
-            arrays = ap.collect({
-                # standard names so checkpoint_is_healthy's finite check
-                # covers position/grad/step/mass exactly like sample-phase
-                "z": carry.states.z,
-                "pe": carry.states.potential_energy,
-                "grad": carry.states.grad,
-                "inv_mass": carry.inv_mass,
-                "da_log_step": carry.da.log_step,
-                "da_log_avg_step": carry.da.log_avg_step,
-                "da_h_avg": carry.da.h_avg,
-                "da_mu": carry.da.mu,
-                "da_count": carry.da.count,
-                "adam_m": carry.adam.m,
-                "adam_v": carry.adam.v,
-                "adam_t": carry.adam.t,
-                "log_T": carry.log_T,
-                "wf_count": carry.wf.count,
-                "wf_mean": carry.wf.mean,
-                "wf_m2": carry.wf.m2,
-                "pe_center": carry.pe_center,
-            })
-            arrays = _with_potential(arrays)
-            arrays["step_size"] = np.exp(arrays["da_log_step"])
-            # PRNG keys are host-side driver state, never mesh-sharded
-            arrays["key"] = np.asarray(key)
-            arrays["key_warm"] = np.asarray(key_warm)
-            if health_check:
-                # a poisoned adaptation carry must never land on disk
-                # (the load-side check in supervise covers old files)
-                from .supervise import check_finite_state
+    cfg: SamplerConfig
+    ap: Any  # the backend's `AdaptiveParts`
+    backend: Any
+    trace: Any
+    monitor: Any  # health observatory, or None
+    model_name: str
+    fused_tag: Optional[str]
+    block_size: int
+    max_blocks: int
+    min_blocks: int
+    rhat_target: float
+    ess_target: float
+    diag_components: int
+    diag_lags: int
+    checkpoint_path: Optional[str]
+    health_check: bool
+    progress_cb: Any
+    time_budget_s: Optional[float]
+    profile_dir: Optional[str]
+    sync_blocks: bool
+    adaptive_blocks: bool
+    kernel: Any = None  # `backends.base.BlockKernel`
+    t_start: float = 0.0
+    metrics_f: Any = None
+    draw_store: Any = None
+    suff: Any = None  # diagnostics.ChainSuffStats
+    draws_hist: Any = None  # diagnostics.DrawHistory
+    # -- the loop's state --------------------------------------------------
+    key: Any = None  # host PRNG key, split once a dispatch
+    diag: Any = None  # device-resident StreamDiagState, streaming mode
+    pending: Any = None  # the block dispatched ahead
+    blocks_done: int = 0
+    blocks_dispatched: int = 0
+    # the kernels' stream position: advanced at DISPATCH (the pipeline runs
+    # ahead of the host's count), anchored at the resumed draw count, so
+    # every mode walks the same sequence
+    draws_dispatched: int = 0
+    total_div: int = 0
+    converged: bool = False
+    budget_exhausted: bool = False
+    next_full_check: int = 0  # earliest block allowed to run full validation
+    profile_next: bool = False
+    history: list = dataclasses.field(default_factory=list)
+    # adaptive block scheduler: the (draws, min_ess) trail of processed
+    # blocks that the ESS-rate forecaster reads, and its reporting forecast
+    points: list = dataclasses.field(default_factory=list)
+    forecast_draws: Optional[int] = None
+    rate: Optional[float] = None
+    # overlap accounting: the previous cycle's host seconds and the running
+    # device-seconds-per-block estimate (exact whenever the host waited)
+    t_host_prev: float = 0.0
+    dev_est: Optional[float] = None
 
-                check_finite_state(arrays)
-            save_checkpoint(
-                path,
-                arrays,
-                {
-                    "kernel": cfg.kernel,
-                    "phase": "warmup",
-                    "warm_done": done,
-                    "warm_div": nd,
-                    "warm_leap": nl,
-                    "model": type(model).__name__,
-                },
-            )
-            ckpt_span.close()
-            if trace.enabled:
-                trace.emit(
-                    "checkpoint",
-                    stage="warmup",
-                    warm_done=done,
-                    path=path,
-                    dur_s=round(ckpt_span.seconds, 4),
-                )
+    @property
+    def chains(self) -> int:
+        return self.kernel.chains
 
-        def run_chees_touchup(carry, key_warm):
-            """Short re-equilibration warmup for an imported adaptation
-            state (``adapt_path``): ONLY the step size re-tunes (DA,
-            anchored at the imported value).  Mass windows are OFF (zero
-            flags) and the trajectory-length Adam is OFF (indices below
-            its t_start gate): both estimates come from a full previous
-            warmup, and a short window would only degrade them —
-            measured: a fresh Adam re-adapting the imported log_T walked
-            trajectories from ~100 to ~288 leapfrogs in 80 touch-up
-            transitions (N=20k fallback replica), tripling every later
-            block's cost."""
-            sched = parts.schedule
-            n = max(20, int(cfg.num_warmup * adapt_touchup_frac))
-            u = jnp.asarray(2.0 * halton(n), jnp.float32)
-            wkeys = jax.random.split(key_warm, n)
-            aoff = jnp.zeros((n,), np.asarray(sched.adapt_mass).dtype)
-            woff = jnp.zeros((n,), np.asarray(sched.window_end).dtype)
-            idxs = jnp.full((n,), -1, jnp.int32)  # < t_start: log_T frozen
-            n_div, n_leap = 0, 0
-            for s in range(0, n, block_size):
-                e = min(s + block_size, n)
-                with trace.phase(
-                    "warmup_block", start=s, end=e, stage="touchup"
-                ) as ph:
-                    carry, (nd, nl) = jax.block_until_ready(
-                        chees_warm_j(
-                            carry, wkeys[s:e], u[s:e], idxs[s:e],
-                            aoff[s:e], woff[s:e], *extra,
-                        )
-                    )
-                    if trace.enabled:
-                        ph.note(num_divergent=int(nd), leapfrogs=int(nl))
-                telemetry.notify_progress()  # watchdog liveness beat
-                n_div += int(nd)
-                n_leap += int(nl)
-            return carry, n_div, n_leap
+    @property
+    def stream_diag(self) -> bool:
+        return self.kernel.stream_diag
 
-        def load_adapt_import():
-            """Validated adaptation import, or None (missing/mismatched
-            file — a mismatch is logged, never fatal: the run falls back
-            to a full warmup)."""
-            arrays, reason = load_adapt_state(
-                adapt_path, kernel="chees",
-                model_name=type(model).__name__, ndim=fm.ndim,
-                data_fp=adapt_fp,
-            )
-            if arrays is None:
-                if reason is not None:
-                    emit({"event": "adapt_import_rejected", "reason": reason})
-                return None
-            z = np.asarray(arrays["z"])
-            if z.shape[0] >= chains:
-                z = z[:chains]
-            else:
-                # more chains than saved: tile the typical-set points
-                reps = -(-chains // z.shape[0])
-                z = np.tile(z, (reps, 1))[:chains]
-            # overdispersed warm starts: the saved z are one posterior
-            # point per chain; jitter by half the cross-chain spread so
-            # imported starts stay overdispersed relative to the target
-            # (and tiled duplicates separate — zero cross-chain variance
-            # would zero the ChEES criterion) instead of replaying the
-            # exporting run's exact typical-set points.  Zero-spread dims
-            # fall back to a 0.05 absolute scale.
-            sd = z.std(axis=0)
-            sd = np.where(sd > 0, sd, 0.05).astype(z.dtype)
-            z = z + 0.5 * sd * np.random.default_rng(
-                seed
-            ).standard_normal(z.shape).astype(z.dtype)
-            return {
-                "z": z,
-                "log_eps": np.asarray(arrays["log_eps"]),
-                "log_T": np.asarray(arrays["log_T"]),
-                "inv_mass": np.asarray(arrays["inv_mass"]),
-            }
+    @property
+    def max_draws(self) -> int:  # the fixed march as a DRAW budget
+        return self.max_blocks * self.block_size
 
-        def save_adapt(run_carry):
-            """Persist the tuned adaptation + end-of-warmup positions for
-            reuse by later runs (atomic, same npz machinery as
-            checkpoints).  A poisoned state is never exported — a NaN
-            import artifact would sabotage every later run."""
-            from .checkpoint import save_checkpoint
-
-            leaves = [
-                np.asarray(ap.collect(run_carry.states.z)),
-                np.asarray(run_carry.log_eps),
-                np.asarray(run_carry.log_T),
-                np.asarray(run_carry.inv_mass),
-            ]
-            if not all(np.all(np.isfinite(a)) for a in leaves):
-                emit({"event": "adapt_export_skipped",
-                      "reason": "non-finite warmup state"})
-                return
-            save_checkpoint(
-                adapt_export_path,
-                {
-                    "z": leaves[0],
-                    "log_eps": leaves[1],
-                    "log_T": leaves[2],
-                    "inv_mass": leaves[3],
-                },
-                {
-                    "kernel": cfg.kernel,
-                    "model": type(model).__name__,
-                    "num_warmup": cfg.num_warmup,
-                    "data_fp": adapt_fp,
-                },
-            )
-
-        def run_chees_warmup(carry, start, key, key_warm, nd0, nl0):
-            """Drive warmup segments from ``start``; checkpoint each."""
-            sched = parts.schedule
-            aflags = jnp.asarray(np.asarray(sched.adapt_mass))
-            wflags = jnp.asarray(np.asarray(sched.window_end))
-            u_warm = jnp.asarray(2.0 * halton(cfg.num_warmup), jnp.float32)
-            wkeys = jax.random.split(key_warm, max(cfg.num_warmup, 1))
-            idxs = jnp.arange(cfg.num_warmup)
-            n_div, n_leap = nd0, nl0
-            for s in range(start, cfg.num_warmup, block_size):
-                e = min(s + block_size, cfg.num_warmup)
-                with trace.phase("warmup_block", start=s, end=e) as ph:
-                    carry, (nd, nl) = jax.block_until_ready(
-                        chees_warm_j(
-                            carry, wkeys[s:e], u_warm[s:e], idxs[s:e],
-                            aflags[s:e], wflags[s:e], *extra,
-                        )
-                    )
-                    if trace.enabled:
-                        ph.note(num_divergent=int(nd), leapfrogs=int(nl))
-                telemetry.notify_progress()  # watchdog liveness beat
-                n_div += int(nd)
-                n_leap += int(nl)
-                if checkpoint_path and e < cfg.num_warmup:
-                    # the final segment's state is captured by the first
-                    # sample-phase checkpoint; persisting it here too
-                    # would only duplicate I/O
-                    save_warmup_checkpoint(
-                        checkpoint_path, carry, key, key_warm, e, n_div,
-                        n_leap,
-                    )
-            return carry, n_div, n_leap
-    else:
-        if stream_diag:
-            try:  # probe: older/third-party backends lack the diag carry
-                ap.get_block(
-                    block_size, diag_lags=diag_lags, donate_diag=sync_blocks
-                )
-            except TypeError:
-                stream_diag = False
-        # step-synchronized NUTS scheduling (STARK_RAGGED_NUTS): the block
-        # runners gain one trailing lane-iteration output (occupancy
-        # accounting).  Knob-gated per config, and probed like the diag
-        # carry — a backend without the ragged path (sharded meshes,
-        # whose data-sharded potentials carry collectives that must run
-        # in lockstep) falls back to the legacy scan.
-        from .kernels.nuts_ragged import ragged_nuts_enabled
-
-        ragged = ragged_nuts_enabled(cfg)
-        if ragged:
-            try:
-                ap.get_block(block_size, ragged=True)
-            except TypeError:
-                ragged = False
-
-        def get_v_block(length):
-            """Compiled block runner for ``length`` transitions — the
-            streaming-diagnostics variant when the feature is on (the
-            backend caches per (length, diag, donate, ragged))."""
-            kw = {"ragged": True} if ragged else {}
-            if stream_diag:
-                return ap.get_block(
-                    length, diag_lags=diag_lags, donate_diag=sync_blocks,
-                    **kw,
-                )
-            return ap.get_block(length, **kw)
-
-        # warmup runs as block_size-bounded dispatches too (same
-        # device-program length as the draw blocks, so warmup is
-        # checkpointable and beats the watchdog) — shared driver with
-        # the segmented backend paths
-        seg_warmup = ap.seg_warmup
-
-    t_start = time.perf_counter()
-    metrics_f = open(metrics_path, "a") if metrics_path else None
-
-    def emit(rec):
-        # every record is a progress beat (watchdog liveness) and is
-        # flushed AND fsynced line-by-line: the metrics trail documents
-        # crashes, so it must survive the crash it documents
+    def emit(self, rec):
+        # every record is a progress beat (watchdog liveness), flushed AND
+        # fsynced: the metrics trail must survive the crash it documents
         telemetry.notify_progress()
-        if metrics_f:
-            metrics_f.write(json.dumps(rec) + "\n")
-            metrics_f.flush()
-            os.fsync(metrics_f.fileno())
-        if progress_cb is not None:
+        if self.metrics_f:
+            self.metrics_f.write(json.dumps(rec) + "\n")
+            self.metrics_f.flush()
+            os.fsync(self.metrics_f.fileno())
+        if self.progress_cb is not None:
             try:
-                progress_cb(rec)
+                self.progress_cb(rec)
             except Exception:  # noqa: BLE001 — observability must not kill
-                # the run: e.g. a BrokenPipeError from a closed capture
-                # pipe would otherwise surface as a sampler fault and burn
-                # the supervisor's restart budget on healthy state
+                # the run: a BrokenPipeError from a closed capture pipe would
+                # burn the supervisor's restart budget on healthy state
                 pass
-        if trace.enabled and rec.get("event", "").startswith("adapt_"):
-            # adaptation decisions (import rejected / export skipped)
-            # mirror into the trace as auxiliary events
-            trace.emit(
-                "adapt",
-                kind=rec["event"],
-                **{k: v for k, v in rec.items() if k != "event"},
-            )
+        if self.trace.enabled and rec.get("event", "").startswith("adapt_"):
+            # adaptation decisions mirror into the trace
+            fields = {k: v for k, v in rec.items() if k != "event"}
+            self.trace.emit("adapt", kind=rec["event"], **fields)
 
-    def emit_warmup_done(n_div_total, step_size, warmup_grads=None,
-                         resumed_from=None, adapt_imported=None):
-        """One builder for the warmup_done record — fresh and
-        warmup-resumed paths must emit identical shapes."""
-        rec = {
-            "event": "warmup_done",
-            "wall_s": time.perf_counter() - t_start,
-            "num_divergent": int(n_div_total),
-            # per-chain kernels carry chain-sharded step sizes: collect
-            # (allgather on a multi-process mesh) before reading
-            "step_size": np.asarray(ap.collect(step_size)).tolist(),
-        }
-        if warmup_grads is not None:
-            rec["warmup_grad_evals"] = int(warmup_grads)
-        if resumed_from is not None:
-            rec["resumed_from_step"] = int(resumed_from)
-        if adapt_imported:
-            rec["adapt_imported"] = True
-        with telemetry.span("block.record", event="warmup_done"):
-            emit(rec)
-        if trace.enabled:
-            trace.emit(
-                "chain_health",
-                status="warmup_done",
-                num_divergent=rec["num_divergent"],
-                step_size=round(float(np.mean(rec["step_size"])), 6),
-            )
+    def wall_s(self) -> float:
+        return time.perf_counter() - self.t_start
 
-    blocks_done = 0
-    total_div = 0
-    budget_exhausted = False
-    history = []
+
+# ---------------------------------------------------------------------------
+# set-up: a fresh start or a resume, through the kernel seam
+# ---------------------------------------------------------------------------
+
+
+def _emit_warmup_done(run: _Run, num_divergent, fields):
+    """The ``warmup_done`` record: the loop's part and the kernel's
+    ``fields`` (``warmup_grad_evals``, ``resumed_from_step``,
+    ``adapt_imported``, where they apply)."""
+    rec = {
+        "event": "warmup_done",
+        "wall_s": run.wall_s(),
+        "num_divergent": int(np.sum(np.asarray(num_divergent))),
+        # per-chain kernels carry chain-sharded step sizes: collect
+        # (allgather on a multi-process mesh) before reading
+        "step_size": np.asarray(
+            run.ap.collect(run.kernel.step_size)).tolist(),
+        **fields,
+    }
+    with telemetry.span("block.record", event="warmup_done"):
+        run.emit(rec)
+    if run.trace.enabled:
+        run.trace.emit(
+            "chain_health", status="warmup_done",
+            num_divergent=rec["num_divergent"],
+            step_size=round(float(np.mean(rec["step_size"])), 6),
+        )
+
+
+def _setup(run: _Run, resume_from, reseed, draw_store_path):
+    """Bring the kernel to its first sampling dispatch (fresh: keys,
+    positions, MAP, warm-up; resumed: the checkpoint, and the rest of a
+    warm-up it was cut in), rebuild the streaming statistics from the
+    draws a resume finds stored, open the draw store."""
+    cfg, kernel = run.cfg, run.kernel
     draw_blocks = []
     # a resumed run's way to its first dispatch, as one span: checkpoint
     # load, state restore, the rebuild of the streaming statistics from the
@@ -811,116 +577,20 @@ def _sample_until_converged(
         from .checkpoint import load_checkpoint
 
         resume_span = telemetry.span(
-            "resume_load", bytes_read=os.path.getsize(resume_from)
-        ).open()
+            "resume_load", bytes_read=os.path.getsize(resume_from)).open()
         arrays, meta = load_checkpoint(resume_from)
         ckpt_kernel = meta.get("kernel")
-        if ckpt_kernel is None and is_chees:
-            # legacy checkpoints (pre-kernel field) were only ever written
-            # by the per-chain kernels; they lack the chees carry arrays
-            raise ValueError(
-                "checkpoint has no kernel record (pre-chees format); "
-                "cannot resume it with kernel='chees'"
-            )
         if ckpt_kernel is not None and ckpt_kernel != cfg.kernel:
             raise ValueError(
                 f"checkpoint was written by kernel={ckpt_kernel!r}, "
                 f"resuming run uses kernel={cfg.kernel!r}"
             )
-        # checkpoints are host numpy; re-place on the backend's layout
-        # (chains-sharded state, replicated ensemble adaptation on a mesh;
-        # identity/device_put on a single device)
-        pc, pr = ap.put_chains, ap.put_rep
-        pe, pe_center = _carried_potential(
-            arrays,
-            is_chees and ap.fm.centering is not None and ap.data is not None,
-        )
-        if pe_center is not None:
-            pe_center = pr(jnp.asarray(pe_center))
-        state = HMCState(
-            z=pc(jnp.asarray(arrays["z"])),
-            potential_energy=pc(jnp.asarray(pe)),
-            grad=pc(jnp.asarray(arrays["grad"])),
-        )
-        # chees adaptation is ensemble-shared; per-chain kernels carry
-        # per-chain step/mass
-        put_sm = pr if is_chees else pc
-        step_size = put_sm(jnp.asarray(arrays["step_size"]))
-        inv_mass = put_sm(jnp.asarray(arrays["inv_mass"]))
-        key = jnp.asarray(arrays["key"])
-        if reseed is not None:
-            # a deterministic numerical failure would otherwise replay
-            # identically from the checkpointed key on every retry — the
-            # supervisor passes the attempt number to branch the stream
-            key = jax.random.fold_in(key, reseed)
-        chains = state.z.shape[0]
-        if is_chees and meta.get("phase") == "warmup":
-            # mid-warmup checkpoint: rebuild the full adaptation carry and
-            # finish the remaining warmup segments before sampling
-            from .adaptation import DualAveragingState, WelfordState
-            from .chees import AdamState, CheesWarmCarry
-
-            rep = lambda name: pr(jnp.asarray(arrays[name]))  # noqa: E731
-            carry = CheesWarmCarry(
-                states=state,
-                da=DualAveragingState(
-                    log_step=rep("da_log_step"),
-                    log_avg_step=rep("da_log_avg_step"),
-                    h_avg=rep("da_h_avg"),
-                    mu=rep("da_mu"),
-                    count=rep("da_count"),
-                ),
-                adam=AdamState(
-                    m=rep("adam_m"),
-                    v=rep("adam_v"),
-                    t=rep("adam_t"),
-                ),
-                log_T=rep("log_T"),
-                wf=WelfordState(
-                    count=rep("wf_count"),
-                    mean=rep("wf_mean"),
-                    m2=rep("wf_m2"),
-                ),
-                inv_mass=inv_mass,
-                pe_center=pe_center,
-            )
-            key_warm = jnp.asarray(arrays["key_warm"])
-            if reseed is not None:
-                key_warm = jax.random.fold_in(key_warm, reseed)
-            with telemetry.span(
-                "warmup", steps=cfg.num_warmup - int(meta["warm_done"])
-            ) as warm_span:
-                carry, n_div, n_warm_leap = run_chees_warmup(
-                    carry,
-                    int(meta["warm_done"]),
-                    key,
-                    key_warm,
-                    int(meta.get("warm_div", 0)),
-                    int(meta.get("warm_leap", 0)),
-                )
-                warm_span.note(grad_evals=int(n_warm_leap) * chains)
-            run_carry = parts.finalize(carry)
-            state = run_carry.states
-            step_size = jnp.exp(run_carry.log_eps)
-            inv_mass = run_carry.inv_mass
-            emit_warmup_done(
-                n_div, step_size,
-                warmup_grads=(n_warm_leap + cfg.map_init_steps) * chains,
-                resumed_from=int(meta["warm_done"]),
-            )
-        elif is_chees:
-            from .chees import CheesRunCarry
-
-            run_carry = CheesRunCarry(
-                states=state,
-                log_eps=pr(jnp.asarray(arrays["log_eps"])),
-                log_T=pr(jnp.asarray(arrays["log_T"])),
-                inv_mass=inv_mass,
-                pe_center=pe_center,
-            )
-        blocks_done = int(meta.get("blocks_done", 0))
-        total_div = int(meta.get("num_divergent", 0))
-        history = list(meta.get("history", []))
+        run.key, n_div, fields = kernel.restore(arrays, meta, reseed)
+        if fields is not None:  # the file was cut in the warm-up
+            _emit_warmup_done(run, n_div, fields)
+        run.blocks_done = int(meta.get("blocks_done", 0))
+        run.total_div = int(meta.get("num_divergent", 0))
+        run.history = list(meta.get("history", []))
         if "draws" in arrays:
             draw_blocks = [arrays["draws"]]
         elif draw_store_path and os.path.exists(draw_store_path):
@@ -934,7 +604,7 @@ def _sample_until_converged(
             # checkpointed block_size, never the resuming call's).
             accounted = meta.get(
                 "draw_rows",
-                blocks_done * int(meta.get("block_size", block_size)),
+                run.blocks_done * int(meta.get("block_size", run.block_size)),
             )
             truncate_draws(draw_store_path, accounted)
             stored, _, _ = read_draws(draw_store_path, mmap=False)
@@ -942,141 +612,26 @@ def _sample_until_converged(
                 # (n, chains, d) on disk -> (chains, n, d) in memory
                 draw_blocks = [np.ascontiguousarray(stored.transpose(1, 0, 2))]
     else:
-        # a span, no phase event: the run's keys and the ensemble's start
-        # positions (the per-chain path has its `chain_init` phase below)
-        with telemetry.span("compile", stage="chain_init"):
-            key = jax.random.PRNGKey(seed)
-            key, key_init, key_warm = jax.random.split(key, 3)
-            warm_import = None
-            if is_chees:
-                warm_import = load_adapt_import()
-                if warm_import is not None:
-                    # imported adaptation: start AT the saved typical-set
-                    # positions; the short touch-up below replaces the
-                    # full warmup (docstring: adapt_path)
-                    z0 = ap.put_chains(jnp.asarray(warm_import["z"]))
-                else:
-                    z0 = ap.put_chains(
-                        chees_init_positions(
-                            fm, key_init, chains, init_params
-                        )
-                    )
-        if is_chees:
-            # init dispatch = first compile + MAP descent (map_init_steps)
-            with trace.phase("compile", stage="init+map",
-                             map_init_steps=cfg.map_init_steps):
-                # the MAP descent itself; its compile counters say how
-                # much of it was compilation
-                with telemetry.span(
-                    "map_init", steps=cfg.map_init_steps,
-                    grad_evals=cfg.map_init_steps * chains,
-                ):
-                    carry = jax.block_until_ready(
-                        chees_init_j(key_init, z0, *extra)
-                    )
-            warm_span = telemetry.span("warmup", steps=cfg.num_warmup).open()
-            if warm_import is not None:
-                from .adaptation import da_init
-
-                pr = ap.put_rep
-                ls = jnp.asarray(warm_import["log_eps"])
-                # DA anchored AT the imported step (mu = log_eps, not
-                # Stan's log(10*eps) exploration prior — that prior is
-                # for cold starts and measurably pulled a tuned eps 2.7x
-                # up during an 80-transition touch-up)
-                carry = carry._replace(
-                    da=jax.tree.map(pr, da_init(jnp.exp(ls), mu=ls)),
-                    log_T=pr(jnp.asarray(warm_import["log_T"])),
-                    inv_mass=pr(jnp.asarray(warm_import["inv_mass"])),
-                )
-                carry, n_div, n_warm_leap = run_chees_touchup(carry, key_warm)
-            else:
-                # warmup dispatches bounded by block_size, like the draw
-                # blocks, each segment checkpointed for mid-warmup resume
-                carry, n_div, n_warm_leap = run_chees_warmup(
-                    carry, 0, key, key_warm, 0, 0
-                )
-            run_carry = parts.finalize(carry)
-            state = run_carry.states
-            step_size = jnp.exp(run_carry.log_eps)
-            inv_mass = run_carry.inv_mass
-            warm_span.close(grad_evals=int(n_warm_leap) * chains)
-            if adapt_export_path and warm_import is None:
-                # populate the reuse cache from a FULL warmup only.  A
-                # successful import leaves the artifact byte-identical: a
-                # judged capture must not dirty committed artifacts
-                # (VERDICT r4 weak #2), and overwriting a full-warmup
-                # state with the touch-up's slightly re-tuned eps would
-                # trade provenance for noise.
-                save_adapt(run_carry)
-            elif adapt_export_path:
-                emit({"event": "adapt_export_skipped", "reason": "imported"})
-        else:
-            # chain-position init is the first real dispatch of the
-            # per-chain path (vmapped init_flat compiles here): a
-            # compile-stage phase covers it so the span timeline
-            # (profiling.spans_from_events) attributes it instead of
-            # reporting pre-warmup slack
-            with trace.phase("compile", stage="chain_init"):
-                if init_params is not None:
-                    z0 = jnp.broadcast_to(
-                        fm.unconstrain(init_params), (chains, fm.ndim)
-                    )
-                else:
-                    z0 = jax.vmap(fm.init_flat)(
-                        jax.random.split(key_init, chains)
-                    )
-                z0 = ap.put_chains(z0)
-                warm_keys = ap.put_chains(jax.random.split(key_warm, chains))
-                jax.block_until_ready(z0)
-            # the segmented warmup driver reads the ambient trace, which
-            # the public wrapper pinned to THIS run's trace
-            with telemetry.span("warmup", steps=cfg.num_warmup):
-                state, step_size, inv_mass, n_div = seg_warmup(
-                    warm_keys, z0, data, block_size
-                )
-                # per-chain counts are chain-sharded
-                n_div = ap.collect(n_div)
-        # chees: ensemble gradient evals spent before sampling — MAP
-        # descent (one fused gradient per Adam step per chain) + warm
-        # leapfrogs; per-chain kernels have no shared-budget equivalent
-        emit_warmup_done(
-            np.sum(np.asarray(n_div)),
-            step_size,
-            warmup_grads=(
-                (n_warm_leap + cfg.map_init_steps) * chains
-                if is_chees
-                else None
-            ),
-            adapt_imported=(is_chees and warm_import is not None) or None,
-        )
+        run.key, n_div, fields = kernel.start()
+        _emit_warmup_done(run, n_div, fields)
 
     # what stands between here and the first dispatch is `resume_load`'s on
     # a resumed run and this span's on a fresh one (no phase event): the
     # streaming statistics and the diagnostics carry are built and placed
     loop_span = resume_span or telemetry.span(
-        "compile", stage="loop_init"
-    ).open()
-    suff = diagnostics.ChainSuffStats(chains, fm.ndim)
-    # full draw history in ONE growing preallocated host buffer: each block
-    # is written exactly once, the per-block worst-k ESS subset is a single
-    # fancy index, and full-history passes (stop validation, no-store
-    # checkpoints, final collection) read a zero-copy view — the old
-    # per-block ``np.concatenate`` over the block list was O(blocks²)
-    # copy traffic in the hot loop
-    draws_hist = diagnostics.DrawHistory(chains, fm.ndim)
+        "compile", stage="loop_init").open()
+    chains, ndim = run.chains, run.ap.fm.ndim
+    run.suff = diagnostics.ChainSuffStats(chains, ndim)
+    # the draw history in ONE growing preallocated host buffer: a block is
+    # written once, and full-history passes read a zero-copy view
+    run.draws_hist = diagnostics.DrawHistory(chains, ndim)
     for blk in draw_blocks:
-        suff.update(blk)  # resume: rebuild streaming stats from stored draws
-        draws_hist.append(blk)
+        run.suff.update(blk)  # a resume's streaming stats, from stored draws
+        run.draws_hist.append(blk)
     del draw_blocks
-    next_full_check = 0  # earliest block allowed to run full validation
-    # chees Halton stream position: advanced at DISPATCH time (the
-    # pipeline enqueues ahead of the host-side suff.count), anchored at
-    # the resumed draw count so every mode walks the same sequence
-    halton_start = int(suff.count[0])
+    run.draws_dispatched = int(run.suff.count[0])
 
-    diag = None
-    if stream_diag:
+    if run.stream_diag:
         # device-resident streaming-diagnostics carry, (chains,)-batched.
         # A resume rebuilds it from the stored draws (host reference
         # implementation of the same accumulator), so the gate's summary
@@ -1084,785 +639,546 @@ def _sample_until_converged(
         from .kernels.base import StreamDiagState
 
         host_diag = diagnostics.stream_diag_from_draws(
-            draws_hist.view()
-            if draws_hist.rows
-            else np.zeros((chains, 0, fm.ndim), np.float32),
-            diag_lags,
-            chains=chains,
-            ndim=fm.ndim,
-            dtype=np.dtype(state.z.dtype),
+            run.draws_hist.view()
+            if run.draws_hist.rows
+            else np.zeros((chains, 0, ndim), np.float32),
+            run.diag_lags, chains=chains, ndim=ndim, dtype=kernel.dtype,
         )
-        diag = StreamDiagState(
-            **{k: ap.put_chains(v) for k, v in host_diag.items()}
+        run.diag = StreamDiagState(
+            **{k: run.ap.put_chains(v) for k, v in host_diag.items()})
+
+    # the scheduler's trail is seeded from the resumed metrics history so a
+    # resumed run reconstructs the SAME schedule decisions the original made
+    for r in run.history:
+        e = r.get("min_ess")
+        run.points.append((int(r.get("draws_per_chain", 0)),
+                           float(e) if e is not None else None))
+    if draw_store_path:
+        from .drawstore import DrawStore
+
+        run.draw_store = DrawStore(draw_store_path, chains, ndim)
+    run.blocks_dispatched = run.blocks_done
+    run.profile_next = bool(run.profile_dir) and run.blocks_done == 0
+    loop_span.close(draws_rebuilt=run.draws_hist.rows * chains)
+
+
+# ---------------------------------------------------------------------------
+# the block loop: dispatch / wait / gate / record / checkpoint
+# ---------------------------------------------------------------------------
+
+
+def _rate_and_deficit(points, ess_target):
+    """(rate, deficit) from a (draws, min_ess) trail — window rate over the
+    last two finite points when it is positive, else the cumulative rate;
+    deficit is vs the LAST finite point."""
+    usable = [p for p in points if p[1] is not None]
+    if not usable:
+        return None, None
+    draws_u, ess_u = usable[-1]
+    rate = None
+    if len(usable) >= 2:
+        dd = draws_u - usable[-2][0]
+        de = ess_u - usable[-2][1]
+        if dd > 0 and de > 0:
+            rate = de / dd
+    if rate is None and draws_u > 0 and ess_u > 0:
+        rate = ess_u / draws_u
+    return rate, ess_target - ess_u
+
+
+def _next_block_len(run: _Run) -> int:
+    """Length of the next dispatch.  Fixed mode: always block_size (the
+    historical loop).  Adaptive mode: geometric growth from block_size/2 capped
+    at 4x (ramp ordinal = GLOBAL block ordinal, so a resumed run continues the
+    ramp), shrunk to the ESS-forecast deficit (quantized to multiples of the
+    base quantum so at most cap/quantum compiled block variants exist), and
+    truncated to the remaining draw budget.
+
+    REPLAY DETERMINISM: the forecast reads the stats trail only up to block
+    ``m-2`` when sizing block ``m`` — exactly what the pipelined loop (which
+    dispatches m before processing m-1) can know.  The serial loop deliberately
+    ignores its one-block-fresher stats, and a resumed run re-reads the same
+    window from the checkpointed history, so serial, pipelined, and
+    crash-resumed runs all size every block identically — which is what keeps
+    the supervised replay bit-identical (chaos: inflight_block_replay).
+    """
+    if not run.adaptive_blocks:
+        return run.block_size
+    remaining = run.max_draws - run.draws_dispatched
+    if remaining <= 0:
+        return 0
+    quantum = max(1, run.block_size // 2)
+    cap = max(run.block_size, 4 * run.block_size)
+    m = run.blocks_dispatched  # 0-based ordinal of the next dispatch
+    n = min(cap, quantum * (2 ** min(m, 8)))
+    rate, deficit = _rate_and_deficit(
+        run.points[: max(0, m - 1)], run.ess_target)
+    if rate and deficit is not None and deficit > 0:
+        # 1.1 safety: the rate estimate is noisy, and undershooting
+        # repeatedly costs a host round-trip per correction
+        need = int(np.ceil(1.1 * deficit / rate))
+        need = -(-max(need, 1) // quantum) * quantum
+        n = min(n, max(need, quantum))
+    return min(n, remaining)
+
+
+def _note_block_ess(run: _Run, min_ess, draws_now):
+    """Record one processed block's ESS; refresh the REPORTING forecast
+    (trace/metrics fields) from the full trail — the scheduler itself reads the
+    delayed window above."""
+    run.points.append(
+        (int(draws_now), float(min_ess) if np.isfinite(min_ess) else None)
+    )
+    rate, deficit = _rate_and_deficit(run.points, run.ess_target)
+    run.rate = rate
+    run.forecast_draws = (
+        int(draws_now + max(0.0, deficit) / rate) if rate else None)
+
+
+def _dispatch_next(run: _Run):
+    """Split the next block's key on the HOST (identical stream in serial and
+    pipelined order), size the block (fixed or ESS-forecast adaptive), and
+    ENQUEUE it without waiting.  -> the pending record `_process_block`
+    materializes later, or None when the draw budget is spent.  Its ``carried``
+    refs are what block k's health check gates and block k's checkpoint
+    persists, and ``key`` is the host key as of THIS split — stored in the
+    checkpoint regardless of how far ahead the pipeline has already split for
+    later blocks."""
+    length = _next_block_len(run)
+    if length <= 0:
+        return None
+    enq_span = telemetry.span(
+        "block.dispatch", block=run.blocks_dispatched + 1, length=length
+    ).open()
+    run.key, key_block = jax.random.split(run.key)
+    # the profiler wants one block's device timeline by itself: run the first
+    # block synchronously under the trace, then pipeline from the next block on
+    profiled, run.profile_next = run.profile_next, False
+    with (jax.profiler.trace(run.profile_dir) if profiled
+          else contextlib.nullcontext()):
+        pend = run.kernel.dispatch(
+            key_block, length, run.diag, run.draws_dispatched)
+        if profiled:
+            jax.block_until_ready(pend.outs)
+    run.diag, pend.key = pend.diag, run.key
+    enq_span.close()
+    pend.t_enq = enq_span.seconds
+    run.blocks_dispatched += 1
+    run.draws_dispatched += length
+    return pend
+
+
+@dataclasses.dataclass
+class _Block:
+    """One finished block's way through the host cycle."""
+
+    pend: Any  # `backends.base.PendingBlock`
+    blk: int
+    host: Any = None  # `backends.base.HostBlock`
+    wait_span: Any = None
+    rec: Any = None
+    n_stuck: int = 0
+    max_rhat: float = float("inf")
+    min_ess: float = float("nan")
+    draws_per_chain: int = 0
+    diag_bytes: int = 0
+    t_ckpt: float = 0.0
+
+    def host_since_wait(self) -> float:
+        """The host cycle so far: since the device's outputs arrived."""
+        return (time.perf_counter_ns() - self.wait_span.end_ns) / 1e9
+
+
+def _gate_block(run: _Run, b: _Block):
+    """`block.gate`: the host's work on the block up to its record —
+    health gate, draw persistence, streaming R-hat / ESS, stop
+    validation."""
+    pend, hb = b.pend, b.host
+    gate_span = telemetry.span(
+        "block.gate", block=b.blk, block_grad_evals=hb.grad_evals,
+        **_psum_counters(run.ap.fm, run.chains, run.backend),
+    ).open()
+    if run.health_check:
+        # poisoned state must never reach the checkpoint (the supervisor
+        # restarts from the last healthy one); ``pend`` holds block k's
+        # carried state, so k's health gates k's checkpoint with k+1 in flight
+        from .supervise import check_finite_state
+
+        carried = run.ap.collect(dict(pend.carried))
+        if run.monitor is not None:
+            # the statistical trail records the stuck chain BEFORE the
+            # finite check below raises into the supervisor
+            run.monitor.observe_state(carried, block=b.blk)
+        check_finite_state(carried)
+    run.blocks_done += 1
+    run.draws_hist.append(hb.zs)
+    if run.draw_store is not None:
+        # async writer; a draw-major block goes in as it is, without a copy
+        if hb.zs_dm is not None:
+            run.draw_store.append(hb.zs_dm, draw_major=True)
+        else:
+            run.draw_store.append(hb.zs)
+    run.total_div += int(np.sum(hb.divergent))
+
+    run.suff.update(hb.zs)
+    srhat = run.suff.rhat()
+    # NaN streaming R-hat = frozen component: counted, never hidden by a
+    # nanmax, and it blocks the stop gate below
+    b.n_stuck = int(np.count_nonzero(np.isnan(srhat)))
+    finite_rhat = srhat[~np.isnan(srhat)]
+    b.max_rhat = (
+        float(np.max(finite_rhat)) if finite_rhat.size else float("inf"))
+    if run.stream_diag:
+        # streaming gate: the convergence signal's ONLY device->host traffic
+        # is the O(chains*d*L) accumulator summary, constant per block
+        diag_host = run.ap.collect(pend.diag)
+        b.diag_bytes = int(sum(np.asarray(a).nbytes for a in diag_host))
+        ess_vals = diagnostics.ess_from_suffstats(*diag_host)
+    else:
+        # host gate: ESS on the worst-mixing components alone (by streaming
+        # R-hat, NaN counting as worst): O(draws * k) host work per block
+        k = min(run.diag_components, run.ap.fm.ndim)
+        worst = np.argsort(np.where(np.isnan(srhat), -np.inf, -srhat))[:k]
+        subset = run.draws_hist.take(worst)
+        b.diag_bytes = int(subset.nbytes)
+        ess_vals = diagnostics.ess(subset)
+    finite_ess = ess_vals[np.isfinite(ess_vals)]
+    # NaN ESS (stuck components) stays out of the reported minimum, which
+    # num_stuck_components covers; all-NaN gives NaN and fails the gate
+    b.min_ess = float(np.min(finite_ess)) if finite_ess.size else float("nan")
+    b.draws_per_chain = int(run.suff.count[0])
+    _note_block_ess(run, b.min_ess, b.draws_per_chain)
+    b.rec = rec = {
+        "event": "block",
+        "block": run.blocks_done,
+        "draws_per_chain": b.draws_per_chain,
+        # metrics must stay strict JSON: non-finite values -> null
+        "max_rhat": b.max_rhat if np.isfinite(b.max_rhat) else None,
+        "min_ess": b.min_ess if np.isfinite(b.min_ess) else None,
+        "num_stuck_components": b.n_stuck,
+        "num_divergent": run.total_div,
+        "mean_accept": hb.mean_accept,
+        # device-attributed time (enqueue + the host's wait for the device,
+        # near zero when the pipeline hides host work)
+        "t_dispatch_s": round(pend.t_enq + b.wait_span.seconds, 3),
+        # the length of the `block.gate` span, stamped where it closes (below)
+        "t_diag_s": None,
+        # GRADIENT EVALUATIONS on all paths: leapfrog steps (ChEES / HMC) or
+        # tree leaves (NUTS), one each; the basis is named beside the count
+        "block_grad_evals": hb.grad_evals,
+        "grad_eval_basis":
+            "tree_leaves" if run.cfg.kernel == "nuts" else "leapfrog",
+        # only on fused-model runs / ragged-NUTS runs: the plain trail stays
+        # byte-identical
+        **({"fused": run.fused_tag} if run.fused_tag else {}),
+        **hb.sched_fields,
+        "wall_s": run.wall_s(),
+    }
+    if run.stream_diag:  # these fields ride the streaming mode alone
+        rec["diag_bytes_to_host"] = b.diag_bytes
+        if run.forecast_draws is not None:
+            rec["ess_forecast"] = run.forecast_draws
+    # failpoint: force the streaming gate optimistic (arm with the ``nan``
+    # data directive) — the candidate stop then reaches the full validation
+    # pass early, which must reject it (the tier-1 guard test's invariant)
+    forced_opt = faults.fail_point("runner.gate.optimistic") is not None
+    # min_blocks counts BLOCKS in both modes (the adaptive scheduler's early
+    # blocks are smaller); the full pass gates every stop on all draws
+    gate_pass = (b.n_stuck == 0 and b.max_rhat < run.rhat_target
+                 and b.min_ess > run.ess_target)
+    if (
+        run.blocks_done >= run.min_blocks
+        and (gate_pass or forced_opt)
+        and run.blocks_done >= run.next_full_check
+    ):
+        # candidate stop: validate with the full split-form pass
+        full_draws = run.draws_hist.view()
+        full_rhat = float(np.max(diagnostics.split_rhat(full_draws)))
+        full_ess = float(np.min(diagnostics.ess(full_draws)))
+        rec["full_max_rhat"] = full_rhat
+        rec["full_min_ess"] = full_ess
+        # recorded, not gated: the rank form flags heavy-tail / scale
+        # disagreement the classic gate can miss
+        rec["full_max_rank_rhat"] = float(
+            np.max(diagnostics.rank_rhat(full_draws)))
+        # re-stamp the wall: it covers the expensive validation too
+        rec["wall_s"] = run.wall_s()
+        if full_rhat < run.rhat_target and full_ess > run.ess_target:
+            run.converged = True
+        else:
+            run.next_full_check = run.blocks_done + max(
+                1, run.blocks_done // 4)
+    gate_span.close(diag_bytes_to_host=b.diag_bytes)
+    rec["t_diag_s"] = round(gate_span.seconds, 3)
+    run.history.append(rec)
+
+
+def _record_block(run: _Run, b: _Block):
+    """`block.record`: the block's metrics record, then the health
+    observatory's sweep."""
+    with telemetry.span("block.record", block=b.blk):
+        run.emit(b.rec)
+    if run.monitor is not None:
+        # host-side only and AFTER the block record: the metrics trail is
+        # the same with the observatory on
+        hb = b.host
+        run.monitor.observe_block(
+            block=run.blocks_done, zs=hb.zs, accept=hb.accept,
+            divergent=hb.divergent, energy=hb.energy, ngrad=hb.ngrad,
+            max_rhat=b.max_rhat, min_ess=b.min_ess, n_stuck=b.n_stuck,
+            draws_per_chain=b.draws_per_chain,
         )
 
-    # adaptive block scheduler (STARK_ADAPTIVE_BLOCKS): the fixed march is
-    # re-expressed as a DRAW budget so both modes draw the same total —
-    # only the block boundaries differ.  ``sched["points"]`` is the
-    # per-processed-block (draws, min_ess) trail the ESS-rate forecaster
-    # reads; it is seeded from the resumed metrics history so a resumed
-    # run reconstructs the SAME schedule decisions the original made.
-    max_draws = max_blocks * block_size
-    blk_quantum = max(1, block_size // 2)
-    blk_cap = max(block_size, 4 * block_size)
-    sched = {"points": [], "forecast_draws": None, "rate": None}
-    for _r in history:
-        _e = _r.get("min_ess")
-        sched["points"].append(
-            (int(_r.get("draws_per_chain", 0)),
-             float(_e) if _e is not None else None)
-        )
-    draws_dispatched = halton_start
 
-    def _rate_and_deficit(points):
-        """(rate, deficit) from a (draws, min_ess) trail — window rate over
-        the last two finite points when it is positive, else the
-        cumulative rate; deficit is vs the LAST finite point."""
-        usable = [p for p in points if p[1] is not None]
-        if not usable:
-            return None, None
-        draws_u, ess_u = usable[-1]
-        rate = None
-        if len(usable) >= 2:
-            dd = draws_u - usable[-2][0]
-            de = ess_u - usable[-2][1]
-            if dd > 0 and de > 0:
-                rate = de / dd
-        if rate is None and draws_u > 0 and ess_u > 0:
-            rate = ess_u / draws_u
-        return rate, ess_target - ess_u
+def _checkpoint_block(run: _Run, b: _Block):
+    """`block.checkpoint`: the kernel's arrays, the draws where no store
+    holds them, the loop's counters."""
+    if not run.checkpoint_path:
+        return
+    ckpt_span = telemetry.span("block.checkpoint", block=b.blk).open()
+    from .checkpoint import save_checkpoint
 
-    def next_block_len():
-        """Length of the next dispatch.  Fixed mode: always block_size
-        (the historical loop).  Adaptive mode: geometric growth from
-        block_size/2 capped at 4x (ramp ordinal = GLOBAL block ordinal,
-        so a resumed run continues the ramp), shrunk to the ESS-forecast
-        deficit (quantized to multiples of the base quantum so at most
-        cap/quantum compiled block variants exist), and truncated to the
-        remaining draw budget.
-
-        REPLAY DETERMINISM: the forecast reads the stats trail only up to
-        block ``m-2`` when sizing block ``m`` — exactly what the
-        pipelined loop (which dispatches m before processing m-1) can
-        know.  The serial loop deliberately ignores its one-block-fresher
-        stats, and a resumed run re-reads the same window from the
-        checkpointed history, so serial, pipelined, and crash-resumed
-        runs all size every block identically — which is what keeps the
-        supervised replay bit-identical (chaos: inflight_block_replay).
-        """
-        if not adaptive_blocks:
-            return block_size
-        remaining = max_draws - draws_dispatched
-        if remaining <= 0:
-            return 0
-        m = blocks_dispatched  # 0-based ordinal of the next dispatch
-        n = min(blk_cap, blk_quantum * (2 ** min(m, 8)))
-        rate, deficit = _rate_and_deficit(sched["points"][: max(0, m - 1)])
-        if rate and deficit is not None and deficit > 0:
-            # 1.1 safety: the rate estimate is noisy, and undershooting
-            # repeatedly costs a host round-trip per correction
-            need = int(np.ceil(1.1 * deficit / rate))
-            need = -(-max(need, 1) // blk_quantum) * blk_quantum
-            n = min(n, max(need, blk_quantum))
-        return min(n, remaining)
-
-    def note_block_ess(min_ess, draws_now):
-        """Record one processed block's ESS; refresh the REPORTING
-        forecast (trace/metrics fields) from the full trail — the
-        scheduler itself reads the delayed window above."""
-        sched["points"].append(
-            (int(draws_now),
-             float(min_ess) if np.isfinite(min_ess) else None)
-        )
-        rate, deficit = _rate_and_deficit(sched["points"])
-        sched["rate"] = rate
-        sched["forecast_draws"] = (
-            int(draws_now + max(0.0, deficit) / rate) if rate else None
+    arrays = run.kernel.checkpoint_arrays(b.pend)
+    if run.draw_store is None:
+        # no draw store -> draws ride in the checkpoint; a store has them
+        # already (avoids O(blocks^2) checkpoint I/O)
+        arrays["draws"] = run.draws_hist.view()
+    else:
+        run.draw_store.flush()  # store on disk before state advances
+    save_checkpoint(run.checkpoint_path, arrays, {
+        "blocks_done": run.blocks_done,
+        "block_size": run.block_size,
+        "draw_rows": b.draws_per_chain,
+        "num_divergent": run.total_div,
+        "history": run.history,
+        "model": run.model_name,
+        "kernel": run.cfg.kernel,
+    })
+    ckpt_span.close()
+    b.t_ckpt = ckpt_span.seconds
+    if run.trace.enabled:
+        run.trace.emit(
+            "checkpoint", block=run.blocks_done, path=run.checkpoint_path,
+            dur_s=round(b.t_ckpt, 4),
         )
 
-    # overlap accounting across blocks: host-side seconds of the previous
-    # cycle (diagnostics + persistence + checkpoint) and the running
-    # device-seconds-per-block estimate (exact whenever the host waited)
-    pipe = {"t_host_prev": 0.0, "dev_est": None}
 
-    draw_store = None
-    converged = False
-    try:
-        if draw_store_path:
-            from .drawstore import DrawStore
+def _trace_block(run: _Run, b: _Block, next_in_flight: bool):
+    """One phase event (timing) + one health event (diagnostics) per block,
+    emitted once the block's ENTIRE host cycle (diagnostics + persistence +
+    checkpoint) is done. ``dur_s`` excludes the checkpoint time — the
+    checkpoint phase has its own event and the per-run phase durations must
+    still tile the wall without double counting. Overlap accounting:
+    ``t_host_hidden_s`` is this block's host-cycle time that ran while the next
+    block computed on device; ``device_idle_s`` is the device idle the host
+    caused before this block ran — exact in sync mode (the whole previous host
+    cycle), estimated in pipelined mode from the latest
+    device-seconds-per-block observation (0 whenever the host had to wait, i.e.
+    the device never starved).  Both are bounded by the host-cycle totals, so
+    the summarized idle fraction (idle over sample_block + checkpoint phase
+    time) stays in [0, 1]."""
+    pend, rec, t_wait = b.pend, b.rec, b.wait_span.seconds
+    host_cycle = b.host_since_wait()
+    if run.sync_blocks:
+        hidden, idle = 0.0, run.t_host_prev
+    else:
+        hidden = host_cycle if next_in_flight else 0.0
+        idle = (0.0 if t_wait > 1e-4 or run.dev_est is None
+                else max(0.0, run.t_host_prev - run.dev_est))
+    forecast = {} if run.forecast_draws is None else {
+        "ess_forecast": run.forecast_draws}
+    run.trace.emit(
+        "sample_block",
+        block=run.blocks_done,
+        # this block's own host timeline: enqueue (a first call's compile
+        # lands there) + wait + host diagnostics, less the checkpoint
+        dur_s=round(pend.t_enq + t_wait + host_cycle - b.t_ckpt, 4),
+        t_dispatch_s=rec["t_dispatch_s"],
+        t_diag_s=rec["t_diag_s"],
+        t_wait_s=round(t_wait, 4),
+        t_host_hidden_s=round(hidden, 4),
+        device_idle_s=round(idle, 4),
+        pipelined=not run.sync_blocks,
+        draws_per_chain=b.draws_per_chain,
+        block_len=pend.length,
+        block_grad_evals=b.host.grad_evals,
+        **({"fused": run.fused_tag} if run.fused_tag else {}),
+        # O(chains*d*L) with streaming diagnostics, O(draws*k) without
+        stream_diag=run.stream_diag,
+        diag_bytes_to_host=b.diag_bytes,
+        **b.host.sched_fields,
+        **forecast,
+    )
+    run.trace.emit(
+        "chain_health",
+        block=run.blocks_done,
+        max_rhat=rec["max_rhat"],
+        min_ess=rec["min_ess"],
+        num_stuck_components=b.n_stuck,
+        num_divergent=run.total_div,
+        mean_accept=rec["mean_accept"],
+        step_size=round(float(np.mean(np.asarray(
+            run.ap.collect(pend.carried["step_size"])))), 6),
+        draws_per_chain=b.draws_per_chain,
+    )
 
-            draw_store = DrawStore(draw_store_path, chains, fm.ndim)
 
-        def dispatch_block(key_block, key_snap, length):
-            """ENQUEUE one draw block of ``length`` transitions on the
-            device without waiting, and refresh the carried device state
-            so the next dispatch chains off it.  Returns the
-            pending-block record `process_block` materializes later: the
-            ``state``/``step_size``/``inv_mass`` (and chees adaptation)
-            refs inside it are what block k's health check gates and
-            block k's checkpoint persists, and ``key`` is the host RNG
-            key as of THIS split — stored in the checkpoint regardless of
-            how far ahead the pipeline has already split for later
-            blocks.  With streaming diagnostics on, the block also
-            carries the StreamDiagState accumulators; ``pend["diag"]`` is
-            the post-block summary the convergence gate collects."""
-            nonlocal state, step_size, inv_mass, halton_start, diag
-            if is_chees:
-                nonlocal run_carry
-                # Halton jitter continues the global sampling sequence
-                # (draws already dispatched = halton_start), so a resumed,
-                # blocked, or pipelined run walks the SAME stream
-                us = jnp.asarray(
-                    2.0 * halton(length, start=halton_start), jnp.float32
-                )
-                halton_start += length
-                bkeys = jax.random.split(key_block, length)
-                if stream_diag:
-                    run_carry, diag, (zs, accept, divergent, n_leap) = (
-                        chees_samp_diag_j(run_carry, diag, bkeys, us, *extra)
-                    )
-                else:
-                    run_carry, (zs, accept, divergent, n_leap) = chees_samp_j(
-                        run_carry, bkeys, us, *extra
-                    )
-                # failpoint: NaN-poison the carried state — injected where
-                # a real numerical fault would surface (health_check=True
-                # catches it before block k's checkpoint; with the check
-                # off it lands on disk and exercises the quarantine path)
-                st = faults.poison("runner.carried_nan", run_carry.states)
-                state = st
-                step_size = jnp.exp(run_carry.log_eps)
-                inv_mass = run_carry.inv_mass
-                return {
-                    "key": key_snap,
-                    "state": st,
-                    "step_size": step_size,
-                    "inv_mass": inv_mass,
-                    "log_eps": run_carry.log_eps,
-                    "log_T": run_carry.log_T,
-                    "pe_center": run_carry.pe_center,
-                    "diag": diag,
-                    "len": length,
-                    "outs": {"zs": zs, "accept": accept,
-                             "divergent": divergent, "n_leap": n_leap},
-                }
-            block_keys = ap.put_chains(jax.random.split(key_block, chains))
-            lane_iters = None
-            if stream_diag:
-                out = get_v_block(length)(
-                    block_keys, state, diag, step_size, inv_mass, data
-                )
-                if ragged:
-                    (new_state, diag, zs, accept, divergent, energy,
-                     ngrad, lane_iters) = out
-                else:
-                    new_state, diag, zs, accept, divergent, energy, ngrad = out
-            else:
-                out = get_v_block(length)(
-                    block_keys, state, step_size, inv_mass, data
-                )
-                if ragged:
-                    (new_state, zs, accept, divergent, energy, ngrad,
-                     lane_iters) = out
-                else:
-                    new_state, zs, accept, divergent, energy, ngrad = out
-            # per-chain kernels CARRY the (possibly poisoned) state into
-            # the next dispatch — same rebinding as the serial loop
-            new_state = faults.poison("runner.carried_nan", new_state)
-            state = new_state
-            return {
-                "key": key_snap,
-                "state": new_state,
-                "step_size": step_size,
-                "inv_mass": inv_mass,
-                "diag": diag,
-                "len": length,
-                "outs": {"zs": zs, "accept": accept,
-                         "divergent": divergent, "ngrad": ngrad,
-                         # per-block Hamiltonian series: kernels always
-                         # computed it; the health observatory is its
-                         # first host-side consumer (E-BFMI)
-                         "energy": energy,
-                         **({"lane_iters": lane_iters} if ragged else {})},
-            }
+def _over_budget(run: _Run) -> bool:
+    """Whether the run is past its ``time_budget_s``.  The stop must be agreed
+    ACROSS RANKS on a multi-process mesh: convergence decisions derive from
+    identical collected draws, but wall clocks skew per host — an unilateral
+    break would leave the other ranks hanging on the next block's unmatched
+    collectives.  Rule: stop when ANY rank is over budget (one tiny allgather
+    per block, only when a budget is actually set)."""
+    if run.time_budget_s is None:
+        return False
+    over = run.wall_s() > run.time_budget_s
+    if jax.process_count() > 1:
+        from .parallel.primitives import gather_tree
 
-        def process_block(pend, next_in_flight):
-            """Host side of ONE finished block: materialize its outputs
-            (blocks only until the DEVICE finishes block k — block k+1 may
-            already be running), health-gate, update diagnostics, emit
-            metrics/trace, checkpoint.  Returns True when the run stops
-            (converged or over budget); an in-flight speculative block is
-            then discarded by the caller."""
-            nonlocal blocks_done, total_div, converged, next_full_check
-            nonlocal budget_exhausted
-            # failpoint: crash/preempt/sleep/stall before the host consumes
-            # a completed block — @skip counts hits, so ``stall(600)*1@1``
-            # stalls exactly once, at block 2 of the first attempt.  With
-            # the pipeline on, block k+1 may already be in flight here; a
-            # crash discards it and the supervisor replays from block
-            # k-1's checkpoint.
-            faults.fail_point("runner.block.pre")
-            blk = blocks_done + 1
-            wait_span = telemetry.span("block.wait", block=blk).open()
-            outs = pend["outs"]
-            if is_chees:
-                # chain-sharded outputs cross to host via collect (an
-                # allgather on multi-process meshes); n_leap is the SHARED
-                # per-transition trajectory length (replicated), and the
-                # ensemble total is chains x that (chees.py convention)
-                zs_dm, accept, divergent = ap.collect(
-                    (outs["zs"], outs["accept"], outs["divergent"])
-                )
-                # the device block is draw-major (block, chains, d): keep
-                # it for the draw store and give host diagnostics a free
-                # transposed VIEW — no transpose copies on this path
-                zs_dm = np.asarray(zs_dm)
-                zs = zs_dm.transpose(1, 0, 2)
-                blk_grads = int(np.sum(np.asarray(outs["n_leap"]))) * chains
-            else:
-                zs, accept, divergent, ngrad = ap.collect(
-                    (outs["zs"], outs["accept"], outs["divergent"],
-                     outs["ngrad"])
-                )
-                zs, zs_dm = np.asarray(zs), None
-                blk_grads = int(np.sum(np.asarray(ngrad)))
-            # the per-block energy series crosses to host ONLY for the
-            # health observatory (STARK_HEALTH=0 restores the historical
-            # drop-on-device behavior); chees blocks carry no energies
-            blk_energy = (
-                np.asarray(ap.collect(outs["energy"]))
-                if monitor is not None and "energy" in outs
-                else None
+        over = bool(np.any(
+            gather_tree(np.array([over], np.bool_), tiled=False)))
+    return over
+
+
+def _process_block(run: _Run, pend, next_in_flight: bool) -> bool:
+    """Host side of ONE finished block, along its spans: wait, gate, record,
+    checkpoint.  Returns True when the run stops (converged or over budget); an
+    in-flight speculative block is then discarded by the caller."""
+    # failpoint: crash/preempt/sleep/stall before the host consumes a
+    # completed block (@skip counts hits: ``stall(600)*1@1`` stalls once, at
+    # block 2 of the first attempt).  A crash discards a block k+1 in flight
+    # and the supervisor replays from block k-1's checkpoint.
+    faults.fail_point("runner.block.pre")
+    b = _Block(pend, run.blocks_done + 1)
+    # waits only until the DEVICE finishes block k: k+1 may be running
+    b.wait_span = telemetry.span("block.wait", block=b.blk).open()
+    b.host = run.kernel.host_block(pend, energy=run.monitor is not None)
+    b.wait_span.close()
+    _gate_block(run, b)
+    _record_block(run, b)
+    _checkpoint_block(run, b)
+    if run.trace.enabled:
+        _trace_block(run, b, next_in_flight)
+    # failpoint: crash/preempt after the block is fully accounted (metrics +
+    # checkpoint durable), the next block in flight: the orphaned-block drill
+    faults.fail_point("runner.block.post")
+
+    # overlap bookkeeping: the device-seconds estimate is exact when the host
+    # waited; the host cycle feeds the next block's idle attribution
+    t_wait = b.wait_span.seconds
+    if t_wait > 1e-4 or run.dev_est is None:
+        run.dev_est = t_wait if run.sync_blocks else run.t_host_prev + t_wait
+    run.t_host_prev = b.host_since_wait()
+
+    if run.converged:
+        return True
+    if _over_budget(run):
+        # stop AFTER the block is emitted and checkpointed, so the
+        # returned (and persisted) result accounts for every draw
+        run.budget_exhausted = True
+        with telemetry.span("block.record", block=b.blk,
+                            event="budget_exhausted"):
+            run.emit({
+                "event": "budget_exhausted",
+                "time_budget_s": float(run.time_budget_s),
+                "wall_s": run.wall_s(),
+            })
+        if run.trace.enabled:
+            run.trace.emit(
+                "budget", time_budget_s=float(run.time_budget_s),
+                blocks=run.blocks_done,
             )
-            # ragged-NUTS occupancy accounting: the batch executed
-            # max(lane_iters) iterations x chains lane-gradients; the
-            # useful fraction is what the step-synchronized scheduler
-            # exists to raise (fields ride ONLY ragged runs, so the
-            # knob-off metrics/trace trails stay byte-identical)
-            sched_fields = {}
-            if ragged and outs.get("lane_iters") is not None:
-                from .kernels.nuts_ragged import lane_occupancy_fields
+        return True
+    return False
 
-                sched_fields = lane_occupancy_fields(
-                    ap.collect(outs["lane_iters"])
-                )
-            wait_span.close()
-            t_wait = wait_span.seconds
 
-            def host_since_wait():
-                # the host cycle so far: everything since the device's
-                # outputs arrived
-                return (time.perf_counter_ns() - wait_span.end_ns) / 1e9
+def _within_budget(run: _Run, draws: int, blocks: int) -> bool:
+    # the fixed march counts BLOCKS (bit-exact legacy loop); the
+    # adaptive scheduler budgets DRAWS — same total either way
+    if run.adaptive_blocks:
+        return draws < run.max_draws
+    return blocks < run.max_blocks
 
-            # the host's work on the block up to its record: health gate,
-            # draw persistence, streaming R-hat / ESS, stop validation
-            gate_span = telemetry.span(
-                "block.gate", block=blk, block_grad_evals=blk_grads,
-                **_psum_counters(fm, chains, backend),
-            ).open()
-            if health_check:
-                # poisoned state must never reach the checkpoint; the
-                # supervisor (supervise.supervised_sample) restarts from
-                # the last healthy one.  The refs in ``pend`` are block
-                # k's carried state, so block k's health still gates
-                # block k's checkpoint even with k+1 in flight.
-                from .supervise import check_finite_state
 
-                carried = ap.collect({
-                    "z": pend["state"].z,
-                    "pe": pend["state"].potential_energy,
-                    "grad": pend["state"].grad,
-                    "step_size": pend["step_size"],
-                    "inv_mass": pend["inv_mass"],
-                })
-                if monitor is not None:
-                    # the statistical trail records the stuck chain
-                    # BEFORE the fault taxonomy fires (the finite check
-                    # below raises into the supervisor)
-                    monitor.observe_state(carried, block=blocks_done + 1)
-                check_finite_state(carried)
-            blocks_done += 1
-            draws_hist.append(zs)
-            if draw_store is not None:
-                # async writer; doesn't stall the loop.  The chees block
-                # is already draw-major — append it without the
-                # transpose-back + ascontiguousarray copy
-                if zs_dm is not None:
-                    draw_store.append(zs_dm, draw_major=True)
-                else:
-                    draw_store.append(zs)
-            total_div += int(np.sum(np.asarray(divergent)))
-
-            suff.update(zs)
-            srhat = suff.rhat()
-            # NaN streaming R-hat = frozen component; surface it explicitly
-            # (nanmax would report a healthy-looking max while never
-            # converging) and hard-block the stop gate below
-            n_stuck = int(np.count_nonzero(np.isnan(srhat)))
-            finite_rhat = srhat[~np.isnan(srhat)]
-            max_rhat = (
-                float(np.max(finite_rhat)) if finite_rhat.size else float("inf")
-            )
-            if stream_diag:
-                # streaming gate: the ONLY device->host traffic the
-                # convergence signal needs is the O(chains*d*L)
-                # accumulator summary — constant per block, independent
-                # of the accumulated draw count (the draws themselves
-                # still stream to the DrawStore/history for persistence
-                # and the stop-time validation pass)
-                diag_host = ap.collect(pend["diag"])
-                diag_bytes = int(
-                    sum(np.asarray(a).nbytes for a in diag_host)
-                )
-                ess_vals = diagnostics.ess_from_suffstats(*diag_host)
-            else:
-                # legacy gate: ESS only on the worst-mixing components (by
-                # streaming R-hat); NaN R-hat counts as worst — it flags a
-                # suspicious component.  One fancy index off the
-                # preallocated history buffer — still O(draws * k) host
-                # work and memory traffic per block
-                k = min(diag_components, fm.ndim)
-                worst = np.argsort(
-                    np.where(np.isnan(srhat), -np.inf, -srhat)
-                )[:k]
-                subset = draws_hist.take(worst)
-                diag_bytes = int(subset.nbytes)
-                ess_vals = diagnostics.ess(subset)
-            finite_ess = ess_vals[np.isfinite(ess_vals)]
-            # NaN ESS values (stuck components) are excluded from the
-            # reported minimum — num_stuck_components carries that signal;
-            # the all-NaN edge gives NaN, which fails the stop gate below
-            min_ess = (
-                float(np.min(finite_ess)) if finite_ess.size else float("nan")
-            )
-            draws_per_chain = int(suff.count[0])
-            note_block_ess(min_ess, draws_per_chain)
-            rec = {
-                "event": "block",
-                "block": blocks_done,
-                "draws_per_chain": draws_per_chain,
-                # metrics must stay strict JSON: non-finite values -> null
-                "max_rhat": max_rhat if np.isfinite(max_rhat) else None,
-                "min_ess": min_ess if np.isfinite(min_ess) else None,
-                "num_stuck_components": n_stuck,
-                "num_divergent": total_div,
-                "mean_accept": float(np.mean(np.asarray(accept))),
-                # wall attribution (VERDICT r2 weak #6): device-attributed
-                # time (enqueue + host wait for the device — near-zero wait
-                # when the pipeline hides host work) vs host diagnostics;
-                # grad_evals divides out to device cost per gradient
-                "t_dispatch_s": round(pend["t_enq"] + t_wait, 3),
-                # the length of the `block.gate` span, stamped where it
-                # closes (below)
-                "t_diag_s": None,
-                # Normalized to GRADIENT EVALUATIONS on all paths: the
-                # ChEES/HMC count is leapfrog steps (1 grad eval each),
-                # the NUTS count is tree leaves (1 grad eval each).
-                # grad_eval_basis names the counting basis so the paths
-                # are never silently conflated (ADVICE r3).
-                "block_grad_evals": blk_grads,
-                "grad_eval_basis": (
-                    "tree_leaves" if cfg.kernel == "nuts" else "leapfrog"
-                ),
-                # fused-path tag rides ONLY fused-model runs, so the
-                # plain-model metrics trail stays byte-identical
-                **({"fused": fused_tag} if fused_tag else {}),
-                # ragged-NUTS scheduling fields ride ONLY knob-on runs
-                **sched_fields,
-                "wall_s": time.perf_counter() - t_start,
-            }
-            if stream_diag:
-                # new fields ride ONLY the streaming mode, so the
-                # flags-off metrics trail stays byte-identical to the
-                # historical runner
-                rec["diag_bytes_to_host"] = diag_bytes
-                if sched["forecast_draws"] is not None:
-                    rec["ess_forecast"] = sched["forecast_draws"]
-            # failpoint: force the streaming gate optimistic (arm with the
-            # ``nan`` data directive) — the candidate stop then reaches
-            # the full validation pass early, which must reject it; the
-            # tier-1 guard test drills exactly this never-stop-on-a-
-            # rejected-validation invariant
-            forced_opt = (
-                faults.fail_point("runner.gate.optimistic") is not None
-            )
-            # min_blocks counts BLOCKS in both modes: under the adaptive
-            # scheduler the early blocks are smaller, so the earliest
-            # possible stop moves from min_blocks*block_size draws to
-            # min_blocks small blocks — the full validation pass still
-            # gates every stop on the complete history
-            min_gate = blocks_done >= min_blocks
-            gate_pass = (
-                n_stuck == 0
-                and max_rhat < rhat_target
-                and min_ess > ess_target
-            )
-            if (
-                min_gate
-                and (gate_pass or forced_opt)
-                and blocks_done >= next_full_check
-            ):
-                # candidate stop: validate with the full split-form pass
-                # (zero-copy view of the history buffer)
-                full_draws = draws_hist.view()
-                full_rhat = float(np.max(diagnostics.split_rhat(full_draws)))
-                full_ess = float(np.min(diagnostics.ess(full_draws)))
-                rec["full_max_rhat"] = full_rhat
-                rec["full_min_ess"] = full_ess
-                # recorded for the metrics trail, not gated: the robust
-                # rank form flags heavy-tail/scale disagreement the
-                # classic gate can miss
-                rec["full_max_rank_rhat"] = float(
-                    np.max(diagnostics.rank_rhat(full_draws))
-                )
-                # the full pass is host diagnostics too (inside the gate
-                # span) — re-stamp the wall so it covers the expensive
-                # validation blocks
-                rec["wall_s"] = time.perf_counter() - t_start
-                if full_rhat < rhat_target and full_ess > ess_target:
-                    converged = True
-                else:
-                    next_full_check = blocks_done + max(1, blocks_done // 4)
-            gate_span.close(diag_bytes_to_host=diag_bytes)
-            rec["t_diag_s"] = round(gate_span.seconds, 3)
-            history.append(rec)
-            with telemetry.span("block.record", block=blk):
-                emit(rec)
-            if monitor is not None:
-                # per-block warning sweep — host-side only, AFTER the
-                # block record so the metrics trail stays byte-identical
-                # to the pre-observatory runner.  The chees block is
-                # draw-major (block, chains): transpose to the monitor's
-                # (chains, block) layout (``zs`` is already transposed)
-                acc_cm = np.asarray(accept)
-                div_cm = np.asarray(divergent)
-                if is_chees:
-                    acc_cm, div_cm = acc_cm.T, div_cm.T
-                monitor.observe_block(
-                    block=blocks_done,
-                    zs=zs,
-                    accept=acc_cm,
-                    divergent=div_cm,
-                    energy=blk_energy,
-                    ngrad=(
-                        np.asarray(ngrad) if not is_chees else None
-                    ),
-                    max_rhat=max_rhat,
-                    min_ess=min_ess,
-                    n_stuck=n_stuck,
-                    draws_per_chain=draws_per_chain,
-                )
-
-            t_ckpt_dur = 0.0
-            if checkpoint_path:
-                ckpt_span = telemetry.span("block.checkpoint", block=blk).open()
-                from .checkpoint import save_checkpoint
-
-                arrays = ap.collect({
-                    "z": pend["state"].z,
-                    "pe": pend["state"].potential_energy,
-                    "grad": pend["state"].grad,
-                    "step_size": pend["step_size"],
-                    "inv_mass": pend["inv_mass"],
-                    "pe_center": pend.get("pe_center"),
-                })
-                arrays = _with_potential(arrays)
-                # host driver state AS OF this block's dispatch: the
-                # pipeline may have split further keys for in-flight
-                # blocks, but a resume from THIS checkpoint must replay
-                # block k+1 from the serial stream position
-                arrays["key"] = np.asarray(pend["key"])
-                if is_chees:
-                    arrays["log_eps"] = np.asarray(pend["log_eps"])
-                    arrays["log_T"] = np.asarray(pend["log_T"])
-                if draw_store is None:
-                    # no draw store -> draws ride in the checkpoint; with a
-                    # store the draws are already persisted incrementally
-                    # (avoids O(blocks^2) checkpoint I/O)
-                    arrays["draws"] = draws_hist.view()
-                else:
-                    draw_store.flush()  # store on disk before state advances
-                save_checkpoint(
-                    checkpoint_path,
-                    arrays,
-                    {
-                        "blocks_done": blocks_done,
-                        "block_size": block_size,
-                        "draw_rows": draws_per_chain,
-                        "num_divergent": total_div,
-                        "history": history,
-                        "model": type(model).__name__,
-                        "kernel": cfg.kernel,
-                    },
-                )
-                ckpt_span.close()
-                t_ckpt_dur = ckpt_span.seconds
-                if trace.enabled:
-                    trace.emit(
-                        "checkpoint",
-                        block=blocks_done,
-                        path=checkpoint_path,
-                        dur_s=round(t_ckpt_dur, 4),
-                    )
-            if trace.enabled:
-                # one phase event (timing) + one health event (diagnostics)
-                # per block, emitted once the block's ENTIRE host cycle
-                # (diagnostics + persistence + checkpoint) is done.
-                # ``dur_s`` excludes the checkpoint time — the checkpoint
-                # phase has its own event and the per-run phase durations
-                # must still tile the wall without double counting.
-                # Overlap accounting: ``t_host_hidden_s`` is this block's
-                # host-cycle time that ran while the next block computed
-                # on device; ``device_idle_s`` is the device idle the host
-                # caused before this block ran — exact in sync mode (the
-                # whole previous host cycle), estimated in pipelined mode
-                # from the latest device-seconds-per-block observation
-                # (0 whenever the host had to wait, i.e. the device never
-                # starved).  Both are bounded by the host-cycle totals, so
-                # the summarized idle fraction (idle over sample_block +
-                # checkpoint phase time) stays in [0, 1].
-                host_cycle = host_since_wait()
-                if sync_blocks:
-                    hidden, idle = 0.0, pipe["t_host_prev"]
-                else:
-                    hidden = host_cycle if next_in_flight else 0.0
-                    idle = (
-                        0.0
-                        if t_wait > 1e-4 or pipe["dev_est"] is None
-                        else max(0.0, pipe["t_host_prev"] - pipe["dev_est"])
-                    )
-                trace.emit(
-                    "sample_block",
-                    block=blocks_done,
-                    # dur covers this block's own host timeline: enqueue
-                    # (jit tracing/compile on the first call lands there)
-                    # + wait + host diagnostics — checkpoint excluded
-                    # (own phase event), so per-run phases still tile the
-                    # wall
-                    dur_s=round(
-                        pend["t_enq"] + t_wait + host_cycle - t_ckpt_dur,
-                        4,
-                    ),
-                    t_dispatch_s=rec["t_dispatch_s"],
-                    t_diag_s=rec["t_diag_s"],
-                    t_wait_s=round(t_wait, 4),
-                    t_host_hidden_s=round(hidden, 4),
-                    device_idle_s=round(idle, 4),
-                    pipelined=not sync_blocks,
-                    draws_per_chain=draws_per_chain,
-                    block_len=pend["len"],
-                    block_grad_evals=blk_grads,
-                    **({"fused": fused_tag} if fused_tag else {}),
-                    # convergence-gate transfer accounting: constant
-                    # O(chains*d*L) with streaming diagnostics, O(draws*k)
-                    # under the legacy full-history gate — the contrast
-                    # trace_report's diagnostics table renders
-                    stream_diag=stream_diag,
-                    diag_bytes_to_host=diag_bytes,
-                    **sched_fields,
-                    **(
-                        {"ess_forecast": sched["forecast_draws"]}
-                        if sched["forecast_draws"] is not None
-                        else {}
-                    ),
-                )
-                trace.emit(
-                    "chain_health",
-                    block=blocks_done,
-                    max_rhat=rec["max_rhat"],
-                    min_ess=rec["min_ess"],
-                    num_stuck_components=n_stuck,
-                    num_divergent=total_div,
-                    mean_accept=rec["mean_accept"],
-                    step_size=round(
-                        float(
-                            np.mean(np.asarray(ap.collect(pend["step_size"])))
-                        ),
-                        6,
-                    ),
-                    draws_per_chain=draws_per_chain,
-                )
-            # failpoint: crash/preempt after the block is fully accounted
-            # (metrics + checkpoint durable) — with the pipeline on, the
-            # next block is in flight HERE, so this site drills the
-            # orphaned-in-flight-block recovery story
-            faults.fail_point("runner.block.post")
-
-            # overlap bookkeeping: device-seconds estimate is exact when
-            # the host waited (device busy for the whole previous host
-            # cycle plus the wait); host cycle time feeds the next
-            # block's idle attribution
-            if t_wait > 1e-4 or pipe["dev_est"] is None:
-                pipe["dev_est"] = (
-                    t_wait if sync_blocks else pipe["t_host_prev"] + t_wait
-                )
-            pipe["t_host_prev"] = host_since_wait()
-
-            if converged:
-                return True
-            # budget stop must be agreed ACROSS RANKS on a multi-process
-            # mesh: convergence decisions derive from identical collected
-            # draws, but wall clocks skew per host — an unilateral break
-            # would leave the other ranks hanging on the next block's
-            # unmatched collectives.  Rule: stop when ANY rank is over
-            # budget (one tiny allgather per block, only when a budget is
-            # actually set).
-            over_budget = (
-                time_budget_s is not None
-                and time.perf_counter() - t_start > time_budget_s
-            )
-            if time_budget_s is not None and jax.process_count() > 1:
-                from .parallel.primitives import gather_tree
-
-                over_budget = bool(
-                    np.any(
-                        gather_tree(
-                            np.array([over_budget], np.bool_), tiled=False
-                        )
-                    )
-                )
-            if over_budget:
-                # stop AFTER the block is emitted and checkpointed, so the
-                # returned (and persisted) result accounts for every draw
-                budget_exhausted = True
-                with telemetry.span(
-                    "block.record", block=blk, event="budget_exhausted"
-                ):
-                    emit(
-                        {
-                            "event": "budget_exhausted",
-                            "time_budget_s": float(time_budget_s),
-                            "wall_s": time.perf_counter() - t_start,
-                        }
-                    )
-                if trace.enabled:
-                    trace.emit(
-                        "budget", time_budget_s=float(time_budget_s),
-                        blocks=blocks_done,
-                    )
-                return True
-            return False
-
-        pending = None
-        blocks_dispatched = blocks_done
-        profile_next = bool(profile_dir) and blocks_done == 0
-        loop_span.close(draws_rebuilt=draws_hist.rows * chains)
-
-        def dispatch_next():
-            """Split the next block's key on the HOST (identical stream in
-            serial and pipelined order), size the block (fixed or
-            ESS-forecast adaptive), and enqueue it."""
-            nonlocal key, blocks_dispatched, profile_next, draws_dispatched
-            length = next_block_len()
-            if length <= 0:
-                return None
-            enq_span = telemetry.span(
-                "block.dispatch", block=blocks_dispatched + 1, length=length
-            ).open()
-            key, key_block = jax.random.split(key)
-            if profile_next:
-                # the profiler wants one block's device timeline by
-                # itself: run the first block synchronously under the
-                # trace, then pipeline from the next block on
-                profile_next = False
-                with jax.profiler.trace(profile_dir):
-                    pend = dispatch_block(key_block, key, length)
-                    jax.block_until_ready(pend["outs"])
-            else:
-                pend = dispatch_block(key_block, key, length)
-            enq_span.close()
-            pend["t_enq"] = enq_span.seconds
-            blocks_dispatched += 1
-            draws_dispatched += length
-            return pend
-
-        def can_dispatch():
-            # the fixed march counts BLOCKS (bit-exact legacy loop); the
-            # adaptive scheduler budgets DRAWS — same total either way
-            if adaptive_blocks:
-                return draws_dispatched < max_draws
-            return blocks_dispatched < max_blocks
-
-        def keep_running():
-            if adaptive_blocks:
-                return draws_hist.rows < max_draws
-            return blocks_done < max_blocks
-
-        while keep_running():
-            if pending is None:
-                pending = dispatch_next()
-                if pending is None:
-                    break
-            current, pending = pending, None
-            if not sync_blocks and can_dispatch():
-                # the overlap: block k+1 starts on the device while the
-                # host processes block k below
-                pending = dispatch_next()
-            if process_block(current, next_in_flight=pending is not None):
-                # converged or budget stop: a speculative in-flight block
-                # is simply discarded — the serial path never ran it, and
-                # neither its draws nor its key split are observable in
-                # any persisted artifact
+def _block_loop(run: _Run):
+    """Draw blocks until converged, out of budget or out of blocks: a
+    software pipeline unless ``sync_blocks`` (module docstring)."""
+    while _within_budget(run, run.draws_hist.rows, run.blocks_done):
+        if run.pending is None:
+            run.pending = _dispatch_next(run)
+            if run.pending is None:
                 break
-    finally:
-        if metrics_f:
-            metrics_f.close()
-        if draw_store is not None:
-            draw_store.close()
+        current, run.pending = run.pending, None
+        if not run.sync_blocks and _within_budget(
+            run, run.draws_dispatched, run.blocks_dispatched
+        ):
+            # the overlap: block k+1 starts on the device while the
+            # host processes block k below
+            run.pending = _dispatch_next(run)
+        if _process_block(run, current, run.pending is not None):
+            # the block in flight is discarded: the serial path never ran
+            # it, and no persisted artifact shows its draws or key split
+            break
 
+
+# ---------------------------------------------------------------------------
+# collect: drain, lay out, constrain, the result
+# ---------------------------------------------------------------------------
+
+
+def _collect(run: _Run, t_run0: float) -> AdaptiveResult:
+    fm, trace = run.ap.fm, run.trace
     with trace.phase("collect"):
         with telemetry.span("collect.layout") as sp:
-            # one final contiguous copy out of the history buffer (the
-            # buffer over-allocates by up to 2x; the result should not pin
-            # that)
-            all_draws = np.ascontiguousarray(draws_hist.view())
-            sp.note(draws=draws_hist.rows * chains, bytes=all_draws.nbytes)
-        if pending is not None:
+            # one final contiguous copy out of the history buffer (the buffer
+            # over-allocates by up to 2x; the result should not pin that)
+            all_draws = np.ascontiguousarray(run.draws_hist.view())
+            sp.note(draws=run.draws_hist.rows * run.chains,
+                    bytes=all_draws.nbytes)
+        if run.pending is not None:
             # the block dispatched ahead of the stop is dropped, but the
             # device runs it to its end before anything queued behind it:
             # wait here, so that `collect.constrain` is the layout alone
-            with telemetry.span("collect.drain", block=blocks_dispatched):
-                jax.block_until_ready(pending["outs"])
+            with telemetry.span("collect.drain", block=run.blocks_dispatched):
+                jax.block_until_ready(run.pending.outs)
         with telemetry.span("collect.constrain", bytes=all_draws.nbytes):
             draws = _constrain_draws(fm, all_draws)
-    stats = {"num_divergent": np.asarray(total_div)}
     result = AdaptiveResult(
         draws,
-        stats,
+        {"num_divergent": np.asarray(run.total_div)},
         flat_model=fm,
         draws_flat=all_draws,
-        history=history,
-        converged=converged,
-        wall_s=time.perf_counter() - t_start,
+        history=run.history,
+        converged=run.converged,
+        wall_s=run.wall_s(),
     )
-    result.budget_exhausted = budget_exhausted
+    result.budget_exhausted = run.budget_exhausted
     # statistical-health verdict: every warning the observatory raised
     # (None when STARK_HEALTH=0 — null, never an empty claim of health)
     result.health_warnings = (
-        monitor.finalize(converged=converged) if monitor is not None
-        else None
+        run.monitor.finalize(converged=run.converged)
+        if run.monitor is not None else None
     )
-    # overshoot accounting: estimated draws spent beyond what the ESS
-    # target needed (at the measured rate) — the number the adaptive
-    # scheduler exists to drive toward ~one small block; surfaced in the
-    # trace so BENCH artifacts can show the win
+    # overshoot: estimated draws spent beyond what the ESS target needed at
+    # the measured rate, which the adaptive scheduler drives toward one
+    # small block
     overshoot = None
-    final_pts = [p for p in sched["points"] if p[1] is not None]
-    if converged and sched["rate"] and final_pts:
+    final_pts = [p for p in run.points if p[1] is not None]
+    if run.converged and run.rate and final_pts:
         overshoot = int(
-            max(0.0, (final_pts[-1][1] - ess_target) / sched["rate"])
-        )
+            max(0.0, (final_pts[-1][1] - run.ess_target) / run.rate))
     result.overshoot_draws = overshoot
     if trace.enabled:
         trace.emit(
             "run_end",
             dur_s=round(time.perf_counter() - t_run0, 4),
-            converged=converged,
-            blocks=blocks_done,
-            num_divergent=total_div,
-            budget_exhausted=budget_exhausted,
-            stream_diag=stream_diag,
-            adaptive_blocks=adaptive_blocks,
+            converged=run.converged,
+            blocks=run.blocks_done,
+            num_divergent=run.total_div,
+            budget_exhausted=run.budget_exhausted,
+            stream_diag=run.stream_diag,
+            adaptive_blocks=run.adaptive_blocks,
             **({"overshoot_draws": overshoot} if overshoot is not None
                else {}),
         )

@@ -416,7 +416,7 @@ def make_block_runner(fm: FlatModel, cfg: SamplerConfig, block_size: int,
     step-synchronized scheduler (`kernels.nuts_ragged`) — one batched
     gradient evaluation per lane per loop iteration, with each vmapped
     lane advancing its own tree/transition independently.  Draws and all
-    per-transition stats are BIT-IDENTICAL to this scan (shared per-leaf
+    per-transition stats are EQUAL TO ROUNDING to this scan's (shared per-leaf
     code and key discipline); both signatures gain ONE trailing output,
     the per-lane live-iteration count (lane-occupancy accounting).
     """
@@ -491,6 +491,141 @@ def make_block_runner(fm: FlatModel, cfg: SamplerConfig, block_size: int,
         return state, diag, zs, accept, divergent, energy, ngrad
 
     return block_run_diag
+
+
+class ChainBlockKernel:
+    """`backends.base.BlockKernel` for the per-chain kernels (NUTS, HMC;
+    both NUTS schedulers; with and without the streaming-diagnostics
+    carry): every chain carries its own state, step size and mass through
+    the backend's compiled blocks (`make_block_runner`), and warm-up is the
+    segmented driver's (`drive_segmented_warmup`)."""
+
+    def __init__(self, ap, cfg: SamplerConfig, chains: int, env):
+        self.ap, self.cfg, self.chains, self.env = ap, cfg, chains, env
+        self.stream_diag = env.stream_diag
+        if self.stream_diag:
+            try:  # probe: older/third-party backends lack the diag carry
+                ap.get_block(env.block_size, diag_lags=env.diag_lags,
+                             donate_diag=env.sync_blocks)
+            except TypeError:
+                self.stream_diag = False
+        # step-synchronized NUTS scheduling (STARK_RAGGED_NUTS): blocks gain
+        # one trailing lane-iteration output.  Knob-gated per config and
+        # probed like the diag carry: a backend without it (sharded meshes,
+        # whose collectives must run in lockstep) keeps the legacy scan
+        from .kernels.nuts_ragged import ragged_nuts_enabled
+
+        self._ragged = ragged_nuts_enabled(cfg)
+        if self._ragged:
+            try:
+                ap.get_block(env.block_size, ragged=True)
+            except TypeError:
+                self._ragged = False
+        self.state = self.step_size = self.inv_mass = None
+
+    @property
+    def dtype(self):
+        return np.dtype(self.state.z.dtype)
+
+    def _block(self, length):
+        """Compiled block runner for ``length`` transitions (the backend
+        caches per (length, diag, donate, ragged))."""
+        kw = {"ragged": True} if self._ragged else {}
+        if self.stream_diag:
+            kw.update(diag_lags=self.env.diag_lags,
+                      donate_diag=self.env.sync_blocks)
+        return self.ap.get_block(length, **kw)
+
+    def start(self):
+        ap, env, chains, fm = self.ap, self.env, self.chains, self.ap.fm
+        with telemetry.span("compile", stage="chain_init"):
+            key = jax.random.PRNGKey(env.seed)
+            key, key_init, key_warm = jax.random.split(key, 3)
+        # chain-position init is the per-chain path's first real dispatch
+        # (vmapped init_flat compiles here): a compile-stage phase, so the
+        # span timeline attributes it instead of reporting pre-warmup slack
+        with env.trace.phase("compile", stage="chain_init"):
+            if env.init_params is not None:
+                z0 = jnp.broadcast_to(
+                    fm.unconstrain(env.init_params), (chains, fm.ndim))
+            else:
+                z0 = jax.vmap(fm.init_flat)(jax.random.split(key_init, chains))
+            z0 = ap.put_chains(z0)
+            warm_keys = ap.put_chains(jax.random.split(key_warm, chains))
+            jax.block_until_ready(z0)
+        # warm-up runs as block_size-bounded dispatches too (checkpointable,
+        # beats the watchdog); the segmented driver reads the ambient trace,
+        # which the public wrapper pinned to THIS run's
+        with telemetry.span("warmup", steps=self.cfg.num_warmup):
+            self.state, self.step_size, self.inv_mass, n_div = ap.seg_warmup(
+                warm_keys, z0, ap.data, env.block_size)
+            # per-chain counts are chain-sharded
+            n_div = ap.collect(n_div)
+        return key, n_div, {}
+
+    def restore(self, arrays, meta, reseed):
+        from .backends.base import restored_key
+
+        # checkpoints are host numpy; per-chain kernels carry per-chain
+        # step/mass: everything goes on the chains layout
+        put = lambda name: self.ap.put_chains(  # noqa: E731
+            jnp.asarray(arrays[name]))
+        self.state = HMCState(put("z"), put("pe"), put("grad"))
+        self.step_size, self.inv_mass = put("step_size"), put("inv_mass")
+        self.chains = arrays["z"].shape[0]
+        return restored_key(arrays, "key", reseed), None, None
+
+    def dispatch(self, key_block, length, diag, first_draw):
+        from . import faults
+        from .backends.base import PendingBlock, carried_state
+
+        block_keys = self.ap.put_chains(
+            jax.random.split(key_block, self.chains))
+        carried = (self.state, diag) if self.stream_diag else (self.state,)
+        out = list(self._block(length)(
+            block_keys, *carried, self.step_size, self.inv_mass, self.ap.data
+        ))
+        # per-chain kernels CARRY the (possibly poisoned) state into the
+        # next dispatch — same rebinding as the serial loop
+        st = self.state = faults.poison("runner.carried_nan", out.pop(0))
+        if self.stream_diag:
+            diag = out.pop(0)
+        # what is left: zs, accept, divergent, energy, ngrad[, lane_iters]
+        return PendingBlock(
+            length, tuple(out), diag,
+            carried_state(st, self.step_size, self.inv_mass),
+        )
+
+    def host_block(self, pending, energy=False):
+        from .backends.base import HostBlock
+
+        collect = self.ap.collect
+        zs, accept, divergent, energy_d, ngrad, *lane = pending.outs
+        zs, accept, divergent, ngrad = collect((zs, accept, divergent, ngrad))
+        # the per-block Hamiltonian series crosses to host only for the
+        # health observatory, its first host-side consumer (E-BFMI)
+        energy_h = np.asarray(collect(energy_d)) if energy else None
+        sched_fields = {}
+        if lane:
+            # ragged-NUTS occupancy: the batch executed max(lane_iters)
+            # iterations x chains lane-gradients; the useful fraction is
+            # what the scheduler exists to raise (knob-on runs only)
+            from .kernels.nuts_ragged import lane_occupancy_fields
+
+            sched_fields = lane_occupancy_fields(collect(lane[0]))
+        accept, ngrad = np.asarray(accept), np.asarray(ngrad)
+        return HostBlock(
+            zs=np.asarray(zs), zs_dm=None, accept=accept,
+            divergent=np.asarray(divergent),
+            mean_accept=float(np.mean(accept)),
+            grad_evals=int(np.sum(ngrad)), energy=energy_h, ngrad=ngrad,
+            sched_fields=sched_fields,
+        )
+
+    def checkpoint_arrays(self, pending):
+        arrays = self.ap.collect(dict(pending.carried))
+        arrays["key"] = np.asarray(pending.key)  # as of this block's split
+        return arrays
 
 
 def drive_segmented_sampling(fm: FlatModel, cfg: SamplerConfig, seg_warmup,
