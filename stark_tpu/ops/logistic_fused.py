@@ -42,6 +42,14 @@ pattern-matches dot_general+add into a matmul-with-accumulator it cannot
 compile for a non-constant accumulator (the per-row offset; adding the
 offset AFTER a complete dot, as the batched kernel does, lowers fine).
 
+The link (`_link_parts`, shared with `ops/hier_fused.py`'s grouped
+kernel) is where the tile's vector and transcendental slots go once the
+dots are issued, so it is written for the units, not from the library:
+the value term is ``y·z − max(z, 0) − log1p(exp(−|z|))`` (the identity
+``logσ(z) − logσ(−z) = z`` folds the two log-sigmoids into one) and the
+residual ``y − 1/(1 + exp(−z))``: two ``exp``, one ``log1p`` and one
+reciprocal an element, not three, two and one.
+
 On the CPU backend, which has no Mosaic, the kernels run under the Pallas
 interpreter (`_resolve_interpret`): that keeps the tests and the
 virtual-device mesh runnable without a TPU; the numerics match autodiff to
@@ -111,12 +119,33 @@ def _link_parts(link, y, logits, mask):
       gaussian:        val = (y - mu)^2 (SSR), resid = y - mu
     (the gaussian value/gradient are SCALE-FREE: the caller applies
     1/sigma^2 outside, so sigma never enters the kernel)
+
+    The Bernoulli link spends two ``exp``, one ``log1p`` and one
+    reciprocal an element where ``y·log_sigmoid(z) + (1−y)·log_sigmoid(−z)``
+    and ``y − sigmoid(z)`` from the library spent three, two and one, with
+    two ``logaddexp`` expansions' selects and NaN guards in the vector
+    slots (PERF.md §5/§6, PR 31):
+      - value: logsig(z) − logsig(−z) = z, so y·logsig(z) +
+        (1−y)·logsig(−z) = y·z + logsig(−z) = y·z − max(z, 0) −
+        log1p(exp(−|z|)) for every real y.  ``log1p(e)``, not
+        ``log(1 + e)``: it keeps the tail (e < 6e-8 past |z| = 16.6).
+      - residual: y − 1/(1 + exp(−z)), the sigmoid with an ``exp`` of
+        its own.  Sharing the value's e = exp(−|z|) (σ(z) = e·σ(|z|) for
+        z < 0) saves it and was measured (PERF.md §6, PR 31): the chip's
+        ``exp`` is a microrelative off, one way; through exp(−z) that
+        moves σ the same way on both sides of 0, as a shifted intercept
+        would, but through exp(−|z|) it moves σ away from ½ on both
+        sides, as stretched logits would, and that adds up along Xβ over
+        the rows: the gradient's gap to the reference rose thirtyfold at
+        20M rows, for no time gained.
+    A non-finite logit gives a non-finite value term; exp(−z) overflowing
+    to inf gives σ = 0, the right limit.
     """
     if link == "bernoulli_logit":
-        ll = y * jax.nn.log_sigmoid(logits) + (1.0 - y) * jax.nn.log_sigmoid(
-            -logits
-        )
-        resid = jnp.where(mask, y - jax.nn.sigmoid(logits), 0.0)
+        e = jnp.exp(-jnp.abs(logits))
+        ll = y * logits - jnp.maximum(logits, 0.0) - jnp.log1p(e)
+        sig = 1.0 / (1.0 + jnp.exp(-logits))
+        resid = jnp.where(mask, y - sig, 0.0)
         return jnp.where(mask, ll, 0.0), resid
     if link == "gaussian":
         resid = jnp.where(mask, y - logits, 0.0)

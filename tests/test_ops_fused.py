@@ -311,6 +311,14 @@ def test_rank2_y_must_be_the_operand_itself():
                              interpret=True)
 
 
+def _sub_jaxprs(eqn):
+    """The jaxprs an equation carries in its parameters (closed or open)."""
+    return [
+        getattr(p, "jaxpr", p) for p in eqn.params.values()
+        if hasattr(getattr(p, "jaxpr", p), "eqns")
+    ]
+
+
 def _touches_rows(jaxpr, n, env):
     """(each pallas_call's operands traced back to the outermost jaxpr's
     inputs, None where an equation made them; every other equation, at any
@@ -325,10 +333,7 @@ def _touches_rows(jaxpr, n, env):
         if eqn.primitive.name == "pallas_call":
             calls.append(src)
             continue
-        subs = [
-            getattr(p, "jaxpr", p) for p in eqn.params.values()
-            if hasattr(getattr(p, "jaxpr", p), "eqns")
-        ]
+        subs = _sub_jaxprs(eqn)
         if not subs and any(
             n in getattr(v.aval, "shape", ()) for v in eqn.invars
         ):
@@ -381,3 +386,209 @@ def test_prepared_y_reaches_the_kernel_untouched(layout):
         assert y_src is None  # made inside: the re-layout
         assert others and set(others) <= {
             "reshape", "broadcast_in_dim", "convert_element_type"}
+
+
+# --- the Bernoulli link: two exp, one log1p, one reciprocal (PR 31) ------
+# The logits every case is held on: where the link is symmetric (0), where
+# exp(-|z|) is 1 to the last bit (1e-6), ordinary (1), saturated (20),
+# subnormal (88) and flushed to 0 (90), then a dense sweep between.
+
+_LINK_POINTS = [0.0, 1e-6, -1e-6, 1.0, -1.0, 20.0, -20.0, 88.0, -88.0, 90.0, -90.0]
+
+
+def _link_grid():
+    return np.concatenate([
+        np.asarray(_LINK_POINTS, np.float32),
+        np.linspace(-95.0, 95.0, 4001).astype(np.float32),
+    ])
+
+
+def _link(y, logits, mask=None):
+    """`_link_parts`' Bernoulli branch; every lane valid unless masked."""
+    from stark_tpu.ops.logistic_fused import _link_parts
+
+    y, logits = jnp.asarray(y), jnp.asarray(logits)
+    if mask is None:
+        mask = jnp.ones(logits.shape, bool)
+    return _link_parts("bernoulli_logit", y, logits, mask)
+
+
+def _old_link(y, logits):
+    """The expression `_link_parts` held until PR 31 (two log-sigmoids and
+    a sigmoid: three exp, two log, a reciprocal): kept here as what the
+    lean form is compared with."""
+    ll = y * jax.nn.log_sigmoid(logits) + (1.0 - y) * jax.nn.log_sigmoid(-logits)
+    return ll, y - jax.nn.sigmoid(logits)
+
+
+def _link_float64(y, z):
+    y, z = y.astype(np.float64), z.astype(np.float64)
+    ll = -y * np.logaddexp(0.0, -z) - (1.0 - y) * np.logaddexp(0.0, z)
+    e = np.exp(-np.abs(z))
+    return ll, y - np.where(z >= 0, 1.0, e) / (1.0 + e)
+
+
+@pytest.mark.parametrize("y_value", [0.0, 1.0, 0.3], ids=["y0", "y1", "y_frac"])
+def test_link_against_float64(y_value):
+    """Value terms and residuals within 2 ulp of the term or 5e-7, and no
+    further from float64 than the old expression was, for y in {0, 1} and
+    for a fractional y (the identity holds for every real y)."""
+    z = _link_grid()
+    y = np.full_like(z, y_value)
+    new, old = _link(y, z), _old_link(jnp.asarray(y), jnp.asarray(z))
+    for name, got, was, want in zip(("value", "resid"), new, old, _link_float64(y, z)):
+        ulp = np.spacing(np.abs(want).astype(np.float32)).astype(np.float64)
+        tol = np.maximum(2.0 * ulp, 5e-7)
+        err = np.abs(np.asarray(got, np.float64) - want) / tol
+        err_old = np.abs(np.asarray(was, np.float64) - want) / tol
+        assert err.max() <= 1.0, (name, z[err.argmax()], err.max())
+        # in units of the tolerance, with a tenth of it for slack
+        assert err.max() <= err_old.max() + 0.1, (name, err.max(), err_old.max())
+        assert err.sum() <= 1.25 * err_old.sum(), (name, err.sum(), err_old.sum())
+
+
+@pytest.mark.parametrize("y_value", [0.0, 1.0, 0.3], ids=["y0", "y1", "y_frac"])
+def test_link_keeps_the_old_expression_s_bits(y_value):
+    """Nothing was traded for the transcendentals saved: the residual is the
+    old expression's bit for bit for every y, and so is the value term for
+    an outcome in {0, 1} (for a fractional y the one rounding of y·z
+    replaces two, and `test_link_against_float64` holds it).  On the CPU,
+    eager and jitted; the chip's own units are PERF.md §6's to read."""
+    z = jnp.asarray(np.concatenate([
+        _link_grid(),
+        np.random.default_rng(31).normal(0.0, 4.0, 50_000).astype(np.float32),
+    ]))
+    y = jnp.full(z.shape, y_value, jnp.float32)
+    for wrap in (lambda f: f, jax.jit):
+        (v, r), (v_old, r_old) = wrap(_link)(y, z), wrap(_old_link)(y, z)
+        np.testing.assert_array_equal(np.asarray(r), np.asarray(r_old))
+        if y_value in (0.0, 1.0):
+            np.testing.assert_array_equal(np.asarray(v), np.asarray(v_old))
+
+
+@pytest.mark.parametrize("y_value", [0.0, 1.0])
+@pytest.mark.parametrize("bad", [np.inf, -np.inf, np.nan], ids=["inf", "-inf", "nan"])
+def test_link_non_finite_logit_gives_non_finite_value(bad, y_value):
+    """A divergent chain's logit: the sampler's reject depends on the value
+    coming out non-finite, never a finite number that could be accepted."""
+    val, _ = _link(
+        np.full((3,), y_value, np.float32),
+        np.asarray([0.5, bad, -0.5], np.float32),
+    )
+    val = np.asarray(val)
+    assert np.isfinite(val[[0, 2]]).all()
+    assert not np.isfinite(val[1])
+    assert not np.isfinite(val.sum())
+
+
+def test_link_masked_lanes_are_exact_zeros():
+    """A ragged tile's overhang reads unspecified values: whatever they
+    are, the lane contributes exactly 0 to value and residual (selects,
+    not multiplies)."""
+    z = np.asarray([np.nan, np.inf, -np.inf, 1e30, -1e30, 0.0, 3.0], np.float32)
+    y = np.asarray([np.nan, 1.0, 0.0, np.inf, 1.0, 0.0, 1.0], np.float32)
+    val, resid = _link(y, z, jnp.asarray([False] * 6 + [True]))
+    for out in (np.asarray(val), np.asarray(resid)):
+        np.testing.assert_array_equal(out[:6], np.zeros(6, np.float32))
+        assert not np.signbit(out[:6]).any()
+        assert np.isfinite(out[6]) and out[6] != 0.0
+
+
+def _link_op_case(op):
+    """(fused log-lik of per-chain parameters, the plain log-lik of the
+    same, a (chains, ...) parameter pytree): 8192 + 37 rows (a ragged last
+    tile at the default lane tile) with logits of scale 4, so both tails of
+    the link carry weight."""
+    from stark_tpu.models.logistic import _bernoulli_logit_loglik
+    from stark_tpu.ops import hier_fused, logistic_fused
+
+    n, d, groups, chains = 8192 + 37, 5, 12, 3
+    k = jax.random.split(jax.random.PRNGKey(31), 5)
+    x = jax.random.normal(k[0], (n, d))
+    y = (jax.random.uniform(k[1], (n,)) < 0.4).astype(jnp.float32)
+    params = {"beta": 1.8 * jax.random.normal(k[2], (chains, d))}
+    if op == "logistic_loglik":
+        fused = lambda p: logistic_fused.logistic_loglik(p["beta"], x.T, y)
+        plain = lambda p: _bernoulli_logit_loglik(x @ p["beta"], y)
+    elif op == "logistic_offset_loglik":
+        params["off"] = jax.random.normal(k[3], (chains, n))
+        fused = lambda p: logistic_fused.logistic_offset_loglik(
+            p["beta"], p["off"], x.T, y
+        )
+        plain = lambda p: _bernoulli_logit_loglik(x @ p["beta"] + p["off"], y)
+    else:
+        g = np.sort(np.random.RandomState(0).randint(0, groups, size=n))
+        lane_tile, k_loc, first_gid, gl = hier_fused.grouped_layout(g, d)
+        params["alpha"] = jax.random.normal(k[4], (chains, groups))
+        layout = (
+            jnp.asarray(gl), jnp.asarray(first_gid), jnp.zeros((k_loc,)),
+            jnp.zeros((lane_tile // 128,)),
+        )
+        fused = lambda p: hier_fused.hier_logistic_loglik(
+            p["beta"], p["alpha"], x.T, y, *layout
+        )
+        plain = lambda p: _bernoulli_logit_loglik(
+            x @ p["beta"] + p["alpha"][g], y
+        )
+    return fused, plain, params
+
+
+@pytest.mark.parametrize("batched", [False, True], ids=["one_chain", "vmapped"])
+@pytest.mark.parametrize(
+    "op", ["logistic_loglik", "logistic_offset_loglik", "hier_logistic_loglik"]
+)
+def test_link_through_each_kernel_matches_plain_autodiff(op, batched):
+    """All three kernels that share the link (`stark_logistic_ll_1chain`
+    and `stark_logistic_ll` by the vmap rule, with and without offsets;
+    `stark_hier_ll_grouped`), through their public ops under the
+    interpreter: value and every gradient against autodiff of
+    models/logistic.py's plain log-likelihood."""
+    fused, plain, params = _link_op_case(op)
+    if batched:
+        run = lambda f: jax.vmap(jax.value_and_grad(f))(params)
+    else:
+        one = jax.tree.map(lambda a: a[1], params)
+        run = lambda f: jax.value_and_grad(f)(one)
+    (v_f, g_f), (v_p, g_p) = run(fused), run(plain)
+    np.testing.assert_allclose(np.asarray(v_f), np.asarray(v_p), rtol=2e-5)
+    for name in params:
+        np.testing.assert_allclose(
+            np.asarray(g_f[name]), np.asarray(g_p[name]), rtol=2e-4,
+            atol=2e-4, err_msg=name,
+        )
+
+
+@pytest.mark.parametrize(
+    "op", ["logistic_loglik", "logistic_offset_loglik", "hier_logistic_loglik"]
+)
+def test_non_finite_position_gives_non_finite_value_through_each_kernel(op):
+    """One chain of the ensemble diverged (an infinite coefficient): its
+    value is non-finite, its neighbours' values are untouched."""
+    fused, _, params = _link_op_case(op)
+    sound = np.asarray(jax.vmap(fused)(params))
+    params["beta"] = params["beta"].at[1, 0].set(jnp.inf)
+    val = np.asarray(jax.vmap(fused)(params))
+    assert not np.isfinite(val[1])
+    np.testing.assert_array_equal(val[[0, 2]], sound[[0, 2]])
+
+
+def test_link_spends_two_exp_one_log_one_reciprocal():
+    """What the kernels' transcendental unit is asked for, an element: the
+    jaxpr of the Bernoulli link holds two `exp` (one of -|z| for the value,
+    one of -z for the sigmoid), one `log1p` and one `div`, and none of the
+    library's `logistic` / `log` / `logaddexp` expansions."""
+    def primitives(jaxpr):
+        for eqn in jaxpr.eqns:
+            subs = _sub_jaxprs(eqn)
+            if not subs:
+                yield eqn.primitive.name
+            for sub in subs:
+                yield from primitives(sub)
+
+    z = jnp.zeros((8, 128), jnp.float32)
+    names = list(primitives(
+        jax.make_jaxpr(_link)(z[:1], z, jnp.ones((1, 128), bool)).jaxpr))
+    costly = {"exp", "exp2", "log", "log1p", "logistic", "div", "tanh",
+              "expm1", "pow", "rsqrt", "sqrt", "integer_pow"}
+    assert sorted(n for n in names if n in costly) == [
+        "div", "exp", "exp", "log1p"], names
