@@ -244,10 +244,11 @@ class ShardedBackend:
 
         S, R = P("chains"), P()
         state_spec = HMCState(z=S, potential_energy=S, grad=S)
-        # the carries' centre, where the flat model has one (`chees.py`)
-        center_spec = (
-            R if fm.centering is not None and data is not None else None
-        )
+        # the carries' centre, where the flat model has one (`chees.py`):
+        # a row a chain goes with the chains
+        center_spec = None
+        if fm.centering is not None and data is not None:
+            center_spec = S if fm.centering.per_chain else R
         warm_spec = CheesWarmCarry(
             states=state_spec,
             da=DualAveragingState(R, R, R, R, R),

@@ -130,9 +130,10 @@ class CheesWarmCarry(NamedTuple):
     log_T: jax.Array
     wf: WelfordState
     inv_mass: jax.Array
-    # what ``states.potential_energy`` is summed relative to (a replicated
-    # scalar; potential = carried + centre), None where the potential is
-    # the plain sum: see ``recentre`` in `make_chees_parts`
+    # what ``states.potential_energy`` is summed relative to
+    # (`model.Centering`: a replicated scalar, or a row a chain, laid out
+    # like the states; potential = carried + its constant), None where the
+    # potential is the plain sum: see ``recentre`` in `make_chees_parts`
     pe_center: Optional[jax.Array] = None
 
 
@@ -196,28 +197,41 @@ def make_chees_parts(
         L = jnp.ceil(u * jnp.exp(log_T - log_eps)).astype(jnp.int32)
         return jnp.clip(L, 1, cap)
 
+    def centred(data, pe_center):
+        """The ensemble kernels' ``(potential_fn, chain_args)`` relative to
+        a carry's ``pe_center``: one potential for all chains, or (a centre
+        a chain) what makes a chain's potential of its row."""
+        if pe_center is None or not fm.centering.per_chain:
+            return fm.bind(data, pe_center), ()
+        return (lambda row: fm.bind(data, row)), (pe_center,)
+
     def recentre(carry: CheesWarmCarry, data):
-        """(potential, carry) of a warm-up program.  A flat model that can
-        centre (a data-sharded potential over a model with `center_data`)
-        sums its potential relative to a constant held in the carry, so
-        that the carried energies are small numbers and their differences
-        keep float32's resolution at any number of rows.  Warm-up moves
-        far (from where MAP stopped to the typical set), so each of its
-        programs first moves the constant to the potential where the first
-        chain now stands and evaluates the ensemble again relative to it:
-        one gradient a program.  Sampling stays within tens of nats and
-        keeps the constant warm-up's last program left (`_sample_scan`)."""
+        """(potential, chain_args, carry) of a warm-up program.  A flat
+        model that can centre (`model.Centering`) sums its potential
+        relative to a constant held in the carry, so that the carried
+        energies are small numbers and their differences keep float32's
+        resolution at any number of rows.  Warm-up moves far (from where
+        MAP stopped to the typical set), so each of its programs first
+        moves the constant to the potential where the first chain now
+        stands (where each chain stands, for a centre a chain) and
+        evaluates the ensemble again relative to it: one gradient a
+        program.  Sampling stays within tens of nats and keeps the constant
+        warm-up's last program left (`_sample_scan`)."""
         if carry.pe_center is None:
-            return fm.bind(data), carry
-        st = carry.states
-        # one constant for the whole ensemble: the mean of the chain
-        # shards' first chains
-        pe_center = carry.pe_center + _cmean(
-            fm.centering.at(st.z[:1], st.potential_energy[:1]), chains_axis
-        )
-        potential_fn = fm.bind(data, pe_center)
-        return potential_fn, carry._replace(
-            states=init_ensemble(potential_fn, st.z), pe_center=pe_center
+            return fm.bind(data), (), carry
+        st, cen = carry.states, fm.centering
+        if cen.per_chain:
+            pe_center = cen.at(st.z, st.potential_energy, carry.pe_center)
+        else:
+            # one constant for the whole ensemble: the mean of the chain
+            # shards' first chains
+            pe_center = carry.pe_center + _cmean(
+                cen.at(st.z[:1], st.potential_energy[:1]), chains_axis
+            )
+        potential_fn, chain_args = centred(data, pe_center)
+        return potential_fn, chain_args, carry._replace(
+            states=init_ensemble(potential_fn, st.z, chain_args),
+            pe_center=pe_center,
         )
 
     def init_carry(key, z0, data=None) -> CheesWarmCarry:
@@ -263,11 +277,11 @@ def make_chees_parts(
             log_T=jnp.log(jnp.asarray(T0)),
             wf=welford_init(d),
             inv_mass=jnp.ones((d,)),
-            pe_center=jnp.zeros(()) if centres else None,
+            pe_center=fm.centering.zero(z0.shape[0]) if centres else None,
         )
-        return recentre(carry, data)[1]
+        return recentre(carry, data)[2]
 
-    def warm_body(potential_fn):
+    def warm_body(potential_fn, chain_args):
         def body(carry: CheesWarmCarry, x):
             states, da, adam, log_T, wf, inv_mass, pe_center = carry
             key, u, idx, accum, at_window = x
@@ -275,7 +289,7 @@ def make_chees_parts(
             states, info = chees_transition(
                 key, states, potential_fn, jnp.exp(log_eps), inv_mass,
                 num_steps(u, log_T, log_eps, warm_cap),
-                chains_axis=chains_axis,
+                chains_axis=chains_axis, chain_args=chain_args,
             )
             da = da_update(
                 da, _cmean(info.accept_prob, chains_axis), cfg.target_accept
@@ -323,9 +337,10 @@ def make_chees_parts(
         return body
 
     def warm_segment(carry, keys, us, idxs, aflags, wflags, data=None):
-        potential_fn, carry = recentre(carry, data)
+        potential_fn, chain_args, carry = recentre(carry, data)
         carry, (div, nleap) = jax.lax.scan(
-            warm_body(potential_fn), carry, (keys, us, idxs, aflags, wflags)
+            warm_body(potential_fn, chain_args), carry,
+            (keys, us, idxs, aflags, wflags),
         )
         n_div = jnp.sum(div.astype(jnp.int32))
         if chains_axis is not None:
@@ -365,7 +380,7 @@ def make_chees_parts(
         only CONSUMES states.z, so draws match bit-for-bit either way."""
         from .kernels.base import stream_diag_update
 
-        potential_fn = fm.bind(data, carry.pe_center)
+        potential_fn, chain_args = centred(data, carry.pe_center)
         # built at trace time so the interval clamps to THIS segment's
         # length (keys.shape is static per compiled variant): an interval
         # longer than one dispatch still heartbeats once per segment
@@ -386,7 +401,7 @@ def make_chees_parts(
             states, info = chees_transition(
                 key, c.states, potential_fn, jnp.exp(c.log_eps), c.inv_mass,
                 num_steps(u, c.log_T, c.log_eps, warm_cap),
-                chains_axis=chains_axis,
+                chains_axis=chains_axis, chain_args=chain_args,
             )
             if tick is not None:
                 tick(i, jnp.mean(info.accept_prob))
@@ -744,29 +759,39 @@ def load_adapt_state(path, *, kernel, model_name, ndim, data_fp=None):
         return None, repr(e)
 
 
-def _with_potential(arrays: Dict[str, Any]) -> Dict[str, Any]:
+def _with_potential(arrays: Dict[str, Any], centering) -> Dict[str, Any]:
     """Checkpoint arrays whose ``pe`` is the potential itself.  An ensemble
     that carries its energies relative to a centre (``pe_center``,
-    collected beside them) has the two added in float64, which holds both
-    to the last bit of the float32 that was carried; ``pe_center`` stays in
-    the file for the resume."""
+    collected beside them; ``centering``: its `model.Centering`) has the
+    centre's constant added in float64, which holds both to the last bit of
+    the float32 that was carried; ``pe_center`` stays in the file for the
+    resume."""
     center = arrays.pop("pe_center", None)
     if center is not None:
         arrays["pe"] = (np.asarray(arrays["pe"], np.float64)
-                        + np.float64(center))
+                        + np.float64(centering.constant(center)))
         arrays["pe_center"] = center
     return arrays
 
 
-def _carried_potential(arrays, centred: bool):
+def _carried_potential(arrays, centering):
     """-> (pe, pe_center) as an ensemble's carry holds them, from a
-    checkpoint's arrays: `_with_potential` undone.  ``centred``: whether
-    the programs that resume carry a centre; a file without one (written
-    off the mesh) then resumes relative to 0."""
-    if not centred:
+    checkpoint's arrays: `_with_potential` undone.  ``centering``: the
+    `model.Centering` of the programs that resume, None where they carry no
+    centre; a file without one (written off the mesh) then resumes relative
+    to 0, which a centre that holds more than its constant cannot."""
+    if centering is None:
         return arrays["pe"], None
-    center = np.float32(arrays.get("pe_center", 0.0))
-    pe = np.asarray(arrays["pe"], np.float64) - np.float64(center)
+    if "pe_center" in arrays:
+        center = np.asarray(arrays["pe_center"], np.float32)
+    elif centering.per_chain:
+        raise ValueError(
+            "this checkpoint holds no pe_center, and the model's centre "
+            "keeps more than a constant: it cannot be made up on resume")
+    else:
+        center = np.float32(0.0)
+    pe = (np.asarray(arrays["pe"], np.float64)
+          - np.float64(centering.constant(center)))
     return pe.astype(np.float32), center
 
 
@@ -785,8 +810,8 @@ class CheesBlockKernel:
         # read back BEFORE the next block is dispatched: the serial loop
         self._samp_diag_j = (
             ap.samp_diag(donate=env.sync_blocks) if self.stream_diag else None)
-        # whether the programs carry the potential's centre
-        self._centred = ap.fm.centering is not None and ap.data is not None
+        # the carries' `model.Centering`, None where they hold no centre
+        self._centering = ap.fm.centering if ap.data is not None else None
         self.carry: Optional[CheesRunCarry] = None
         self.step_size = None
 
@@ -930,7 +955,7 @@ class CheesBlockKernel:
                 named[f"{part}_{f}"] = getattr(getattr(carry, part), f)
         # ap.collect (gather_draws on a mesh): np.asarray alone cannot read
         # non-addressable shards on multi-process meshes
-        arrays = _with_potential(self.ap.collect(named))
+        arrays = _with_potential(self.ap.collect(named), self._centering)
         arrays["step_size"] = np.exp(arrays["da_log_step"])
         # PRNG keys are host-side driver state, never mesh-sharded
         arrays["key"] = np.asarray(key)
@@ -1022,9 +1047,11 @@ class CheesBlockKernel:
         re-placed on the backend's layout: the ensemble's state over the
         chains, ``rep(name)`` for its shared adaptation, replicated."""
         pc, pr = self.ap.put_chains, self.ap.put_rep
-        pe, pe_center = _carried_potential(arrays, self._centred)
+        pe, pe_center = _carried_potential(arrays, self._centering)
         if pe_center is not None:
-            pe_center = pr(jnp.asarray(pe_center))
+            # a row a chain lies where the chains' states lie
+            put = pc if self._centering.per_chain else pr
+            pe_center = put(jnp.asarray(pe_center))
         states = HMCState(*(
             pc(jnp.asarray(a)) for a in (arrays["z"], pe, arrays["grad"])
         ))
@@ -1124,7 +1151,7 @@ class CheesBlockKernel:
         extras = pending.extras  # the run carry's replicated scalars
         arrays = _with_potential(self.ap.collect(
             {**pending.carried, "pe_center": extras.pe_center}
-        ))
+        ), self._centering)
         # the host key AS OF this block's dispatch: the pipeline may have split
         # further, but a resume from THIS file replays block k+1 from here
         arrays["key"] = np.asarray(pending.key)

@@ -110,6 +110,8 @@ def chees_transition(
     inv_mass_diag: Array,  # (d,)
     num_leapfrog: Array,  # traced scalar int — shared by all chains
     chains_axis=None,  # mesh axis name when the ensemble is sharded
+    chain_args=(),  # per-chain operands: ``potential_fn(*one chain's)`` is
+    # then that chain's potential (a centre a chain, `model.Centering`)
 ):
     """One ensemble transition; returns (states, CheesInfo).
 
@@ -136,12 +138,15 @@ def chees_transition(
     ke0 = jax.vmap(kinetic_energy, in_axes=(0, None))(r0, inv_mass_diag)
     energy0 = states.potential_energy + ke0
 
-    def integrate(z, r, grad):
+    def integrate(z, r, grad, *own):
         return dynamic_leapfrog(
-            potential_fn, z, r, grad, step_size, inv_mass_diag, num_leapfrog
+            potential_fn(*own) if own else potential_fn,
+            z, r, grad, step_size, inv_mass_diag, num_leapfrog,
         )
 
-    z1, r1, grad1, pe1 = jax.vmap(integrate)(states.z, r0, states.grad)
+    z1, r1, grad1, pe1 = jax.vmap(integrate)(
+        states.z, r0, states.grad, *chain_args
+    )
     ke1 = jax.vmap(kinetic_energy, in_axes=(0, None))(r1, inv_mass_diag)
     energy1 = pe1 + ke1
 
@@ -205,9 +210,15 @@ def chees_transition(
     return new_states, info
 
 
-def init_ensemble(potential_fn: PotentialFn, z: Array) -> HMCState:
-    """Init the (C, d) ensemble state with one vmapped potential+grad."""
-    pe, grad = jax.vmap(value_and_grad_of(potential_fn))(z)
+def init_ensemble(
+    potential_fn: PotentialFn, z: Array, chain_args=()
+) -> HMCState:
+    """Init the (C, d) ensemble state with one vmapped potential+grad
+    (``chain_args``: as `chees_transition`'s)."""
+    def one(zc, *own):
+        return value_and_grad_of(potential_fn(*own) if own else potential_fn)(zc)
+
+    pe, grad = jax.vmap(one)(z, *chain_args)
     return HMCState(z=z, potential_energy=pe, grad=grad)
 
 

@@ -110,15 +110,35 @@ class Model:
         the scalar ``center``, with the subtraction done INSIDE the sum
         over rows (partial sums less their share of it), so that the
         difference keeps float32's resolution.  None (default): the model
-        has no such sum, and the potential stays as it is.
+        has no such sum, and the potential stays as it is.  A model with
+        ``center_per_chain`` gets one chain's centre: a vector, the scalar
+        first and what `center_keep` returned behind it.
 
         Why: over tens of millions of rows the log-likelihood is a
         float32 near 1e7-1e8 whose last bit is 1 to 4 nats, and the
         accept step of every sampler lives on energy differences of a
-        tenth of a nat.  The data-sharded ChEES programs ask for the
-        potential relative to its value where the chains are (`Centering`,
+        tenth of a nat.  The ChEES programs ask for the potential relative
+        to its value where the chains are (`Centering`,
         `chees.make_chees_parts`)."""
         return None
+
+    #: how a model with `center_data` is centred.  False: one constant for
+    #: the ensemble (where the first chain stands), and only over a data
+    #: mesh: `FusedLogistic`'s, whose one-chip programs stay the plain
+    #: ones.  True: every chain relative to where it stands itself, on one
+    #: chip as on a mesh: an accept step compares a chain with itself
+    #: alone, so chains may stand any distance apart
+    center_per_chain = False
+
+    def center_keep(self, params: Dict[str, Array]) -> Array:
+        """With ``center_per_chain``: what of the position a chain's centre
+        is taken at (``params``, constrained) its centred likelihood is
+        evaluated relative to, as a vector; carried behind the constant
+        and handed to `center_data` with it.  For a likelihood whose large
+        sums are multiplied by scalars of the position (a noise scale):
+        taking those to a fixed reference makes their rounding the same at
+        every position.  Default: nothing."""
+        return jnp.zeros((0,))
 
     def data_shard_row_axes(self, data: PyTree) -> PyTree:
         """Row axes for CONTIGUOUS, ORDER-PRESERVING data-axis sharding
@@ -220,13 +240,14 @@ class FlatModel:
     comm: Dict[str, int] = dataclasses.field(
         default_factory=dict, compare=False
     )
-    # optional (data-sharded potentials of models with `center_data`): the
-    # potential summed relative to a constant, see `Centering`
+    # optional (models with `center_data`): the potential summed relative
+    # to a constant, see `Centering`
     centering: Optional["Centering"] = None
 
     def bind(self, data=None, pe_center=None) -> Potential:
         """Close over a dataset -> a Potential for the kernels.  With
-        ``pe_center`` (`Centering`) the potential comes back less it."""
+        ``pe_center`` (`Centering`: the ensemble's constant, or ONE chain's
+        centre) the potential comes back less it."""
         if pe_center is not None:
             data = self.centering.data(data, pe_center)
         if self.potential_factory is not None:
@@ -238,20 +259,42 @@ class FlatModel:
 
 
 class Centering(NamedTuple):
-    """A potential summed relative to a constant ``pe_center``, so that
-    near the positions the constant was taken at it is a small number whose
-    differences keep float32's resolution at any number of rows
-    (`Model.center_data`; held and moved by `chees.make_chees_parts`).
+    """A potential summed relative to a constant, so that near the positions
+    the constant was taken at it is a small number whose differences keep
+    float32's resolution at any number of rows (`Model.center_data`; held
+    as the carries' ``pe_center`` and moved by `chees.make_chees_parts`).
+    What is carried is one array, and only this class and the model know
+    what is in it: a scalar for the ensemble (``width`` 0), or for a model
+    with `Model.center_per_chain` a row a chain, (C, width): the chain's
+    constant, then what the model keeps of the position it was taken at.
 
       at(z (C, d), pe (C,)) -> (C,)   the constant at each position ``z``
                                       with plain potential ``pe``: the
                                       likelihood's part of it
-      data(data, pe_center) -> data'  bound over data' the potential is the
-                                      plain one less the scalar
+      at(z, pe, centre) -> centre'    a row a chain: ``centre`` moved to
+                                      ``z``, where the potential relative
+                                      to it is ``pe``
+      data(data, centre) -> data'     bound over data' the potential is the
+                                      plain one less the constant; ``centre``
+                                      the scalar, or ONE chain's row
     """
 
-    at: Callable[[Array, Array], Array]
+    at: Callable[..., Array]
     data: Callable[[PyTree, Array], PyTree]
+    width: int = 0
+
+    @property
+    def per_chain(self) -> bool:
+        return self.width > 0
+
+    def zero(self, chains: int) -> Array:
+        """The centre of an ensemble whose energies are the plain ones."""
+        return jnp.zeros((chains, self.width) if self.per_chain else ())
+
+    def constant(self, centre: Array) -> Array:
+        """What the potential is the carried energy plus: a scalar, or a
+        number a chain."""
+        return centre[..., 0] if self.per_chain else centre
 
 
 def flatten_model(
@@ -350,25 +393,37 @@ def flatten_model(
         grad = -(pp_grad + lik_scale * ll_grad_tot)
         return pe, grad
 
-    def center_at(z: Array, pe: Array):
-        # pe = -(prior + lik): what is left when the prior's part goes is
-        # the whole mesh's log-likelihood term (0 where it is not finite)
-        c = pe + jax.vmap(prior_part)(z)
-        return jax.lax.stop_gradient(jnp.where(jnp.isfinite(c), c, 0.0))
-
-    def centered_data(data: PyTree, pe_center: Array):
-        # each shard's sums take an even share of it off: rows dealt to
-        # shards at random differ by a few thousand nats, still small
-        from .parallel.primitives import mapped_axis_size
-
-        shards = lik_scale * mapped_axis_size(axis_name)
-        return model.center_data(data, -pe_center / shards)
-
+    per_chain = bool(getattr(model, "center_per_chain", False))
     centers = (
-        axis_name is not None
+        (axis_name is not None or per_chain)
         and getattr(type(model), "center_data", Model.center_data)
         is not Model.center_data
     )
+
+    def center_keep(z: Array):
+        return model.center_keep(constrain(z))
+
+    def center_at(z: Array, pe: Array, centre=None):
+        # pe = -(prior + lik): what is left when the prior's part goes is
+        # the whole mesh's log-likelihood term (0 where it is not finite)
+        c = pe + jax.vmap(prior_part)(z)
+        c = jax.lax.stop_gradient(jnp.where(jnp.isfinite(c), c, 0.0))
+        if not per_chain:
+            return c
+        return jax.lax.stop_gradient(jnp.concatenate(
+            [(centre[:, 0] + c)[:, None], jax.vmap(center_keep)(z)], axis=1))
+
+    def centered_data(data: PyTree, centre: Array):
+        # each shard's sums take an even share of it off: rows dealt to
+        # shards at random differ by a few thousand nats, still small
+        # (one shard off the mesh)
+        from .parallel.primitives import mapped_axis_size
+
+        shards = lik_scale * mapped_axis_size(axis_name)
+        if per_chain:
+            return model.center_data(
+                data, jnp.concatenate([-centre[:1] / shards, centre[1:]]))
+        return model.center_data(data, -centre / shards)
 
     def init_flat(key: Array) -> Array:
         init = model.init_params(key)
@@ -384,5 +439,10 @@ def flatten_model(
         unconstrain=unconstrain,
         init_flat=init_flat,
         comm=comm,
-        centering=Centering(center_at, centered_data) if centers else None,
+        centering=Centering(
+            center_at, centered_data,
+            # a row: the constant and what the model keeps beside it
+            1 + jax.eval_shape(center_keep, jnp.zeros((ndim,))).shape[0]
+            if per_chain else 0,
+        ) if centers else None,
     )

@@ -143,13 +143,19 @@ class FusedLinearMixedModelGrouped(LinearMixedModel):
     """LMM with the fully-fused grouped kernel (ops/hier_fused.py): rows
     pre-sorted by group; the random-effect offsets AND the (G, Q)
     u-gradient live inside the Pallas pass — no (C, N) gather/scatter
-    per evaluation.  At 10k groups over 100k rows the layout shrinks the
-    lane tile until each tile's group window is static and small.
+    per evaluation.  What `grouped_layout` does with it: at the on-chip
+    benchmark's shape (10k groups over 81.9M rows, 8 192 rows a
+    group: ``onchip/configs/lmm_d8_q2_g10k_n49m.json``) it keeps the full
+    lane tile of 8 192 and a window of ``k_loc`` 8 (a tile spans at most
+    three groups); at ``configs/lmm.yaml``'s 100k rows, ten a group, it
+    halves the tile to 1 024, where a window of 104 groups fits.
 
     Same posterior as LinearMixedModel/FusedLinearMixedModel (row sums
     are permutation-invariant).  Falls back to the offset-path layout
     when no tile size keeps the window bounded.  Rows are NOT shardable
     (global tile layout) — use FusedLinearMixedModel on data meshes.
+    Tens of millions of rows on one chip: the potential is centred chain
+    by chain, off a mesh too (``center_per_chain``, ``center_data``).
     """
 
     def fused_tag(self):
@@ -175,9 +181,11 @@ class FusedLinearMixedModelGrouped(LinearMixedModel):
 
             return _row_axes_xt(data)
         raise NotImplementedError(
-            "FusedLinearMixedModelGrouped's tile layout is global: rows "
-            "cannot be re-sharded. Use FusedLinearMixedModel for "
-            "data-sharded meshes; chain parallelism still applies."
+            "FusedLinearMixedModelGrouped's tile layout is global "
+            "(first_gid indexes absolute lane tiles; 8192 lanes and a "
+            "window of k_loc 8 at the benchmark's shape): rows cannot be "
+            "re-sharded. Use FusedLinearMixedModel for data-sharded "
+            "meshes; chain parallelism still applies."
         )
 
     def log_lik(self, p, data):
@@ -189,20 +197,43 @@ class FusedLinearMixedModelGrouped(LinearMixedModel):
             offsets = p["intercept"] + jnp.sum(
                 data["z"] * u[data["g"]], axis=-1
             )
-            return gaussian_offset_loglik(
+            ll = gaussian_offset_loglik(
                 beta, offsets, data["xT"], data["y"], p["sigma"]
             )
+            # no tile sums to centre on this path: the constant comes off
+            # the total
+            return ll - data["ll_center"][0] if "ll_center" in data else ll
         from ..ops.hier_fused import lmm_grouped_loglik
 
         # the z slab's quant scales fold into u the same way xT's fold
-        # into beta: mu's j-th term is (u_q-window @ onehot) * z_j, so
-        # (s_z[j] * u[:, j]) against packed z equals u against s_z * z
-        u = _fold_scale(u, data, key="zT_scale")
+        # into beta: one rescale of the tiny (G, Q) array per evaluation
+        if "zT_scale" in data:
+            u = u * data["zT_scale"]
         return lmm_grouped_loglik(
             beta, u, p["intercept"], p["sigma"], data["xT"],
             data["zT"], data["y"], data["gl"], data["first_gid"],
-            data["k_loc"], data["lt128"],
+            data["k_loc"], data["lt128"], data.get("ll_center"),
         )
+
+    #: ten thousand groups of thousands of rows are tens of millions of
+    #: rows on ONE chip, and chains that MAP leaves 1e7 nats and tens of
+    #: percent of the noise scale apart (my chip runs, PR 32): every chain
+    #: is centred where it stands itself, off a mesh too
+    center_per_chain = True
+
+    def center_keep(self, p):
+        """`Model.center_keep`: the noise scale where the chain's centre is
+        taken, with its logarithm and inverse square
+        (`ops.hier_fused.ref_scale`)."""
+        from ..ops.hier_fused import ref_scale
+
+        return ref_scale(p["sigma"])
+
+    def center_data(self, data, center):
+        """`Model.center_data`: the tiles' log-densities take the chain's
+        constant ``center[0]`` off before they are added, at the fixed
+        scale ``center[1:]`` (`ops.hier_fused._gauss_loglik`)."""
+        return {**data, "ll_center": center}
 
 
 def synth_lmm_data(

@@ -300,7 +300,9 @@ class FusedHierLogisticGrouped(HierLogistic):
     row sum — sorting is a permutation).  When the data defeats the
     dense-window layout (some lane tile spans > _K_LOC_MAX groups),
     prepare_data falls back to the offset-path layout and log_lik routes
-    accordingly.  Rows are NOT shardable across a data mesh axis: the
+    accordingly.  At the on-chip benchmark's shape (1000 groups over 16M
+    rows) `grouped_layout` keeps the full lane tile of 8 192 and a window
+    of ``k_loc`` 8.  Rows are NOT shardable across a data mesh axis: the
     tile layout is global (first_gid indexes absolute tiles) — use
     FusedHierLogistic for sharded runs.
     """
@@ -326,7 +328,8 @@ class FusedHierLogisticGrouped(HierLogistic):
             return _row_axes_xt(data)
         raise NotImplementedError(
             "FusedHierLogisticGrouped's tile layout is global (first_gid "
-            "indexes absolute lane tiles): rows cannot be re-sharded. "
+            "indexes absolute lane tiles; 8192 lanes and a window of "
+            "k_loc 8 at the benchmark's shape): rows cannot be re-sharded. "
             "Use FusedHierLogistic for data-sharded meshes; chain "
             "parallelism still applies."
         )

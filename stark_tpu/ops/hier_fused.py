@@ -57,6 +57,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from .. import telemetry
 from .logistic_fused import (
@@ -64,6 +65,7 @@ from .logistic_fused import (
     _default_lane_tile,
     _link_parts,
     _resolve_interpret,
+    _sum_tiles,
 )
 from .precision import (
     dot_precision as _dot_precision,
@@ -87,9 +89,11 @@ def grouped_layout(g_sorted: np.ndarray, d: int):
 
     Returns (lane_tile, k_loc, first_gid (grid,) int32, gl (N,) int32)
     or None when no tile size keeps the group window within _K_LOC_MAX.
-    Dense groupings (few rows per group, e.g. the LMM's 10k groups over
-    100k rows) get a SMALLER lane tile so each tile still spans few
-    groups — the one-hot stays cheap and the window static.  The chosen
+    Dense groupings (few rows per group, e.g. ``configs/lmm.yaml``'s 10k
+    groups over 100k rows: tile 1024, window 104) get a SMALLER lane tile
+    so each tile still spans few groups — the one-hot stays cheap and the
+    window static; the on-chip benchmark's shapes (thousands of rows a
+    group) keep the full tile of 8192 and a window of 8.  The chosen
     lane_tile rides back to the kernel call in the data layout (shape-
     encoded), so prepare and call cannot disagree.
     """
@@ -168,6 +172,8 @@ def prepare_grouped(data, d_eff, transpose_keys=("x",)):
             k: v[order].T if k in transpose_keys else v[order]
             for k, v in host.items()
         }
+    # on the caller's `prepare_data` span: what `grouped_layout` chose
+    telemetry.note(lane_tile=lane_tile, k_loc=k_loc, tiles=len(first_gid))
     xdt = _x_stream_dtype()
     from .quantize import is_packed_dtype, pack_slab
 
@@ -198,7 +204,8 @@ def prepare_grouped(data, d_eff, transpose_keys=("x",)):
     return out
 
 
-def _check_chain_vmem(cpad, lane_tile, interpret, k_loc=0, q=1, slab_rows=0):
+def _check_chain_vmem(cpad, lane_tile, interpret, k_loc=0, q=1, slab_rows=0,
+                      chain_slabs=3, transposed=0, budget=10 * 1024 * 1024):
     """The kernel holds ~3 (C, TILE) f32 intermediates (logits, resid,
     value terms) in scoped VMEM; past ~16 MB Mosaic refuses to compile
     (measured: C=128 at TILE=8192 asked for 20 MB).  The grouped kernels
@@ -206,22 +213,28 @@ def _check_chain_vmem(cpad, lane_tile, interpret, k_loc=0, q=1, slab_rows=0):
     per-tile (C, Q*K_LOC) group window (ADVICE r3: a small-C /
     large-K_LOC config could OOM past the C-only estimate), and the
     hierarchical kernel a stacked copy of the design slab and the one-hot
-    (``slab_rows`` = D + K_LOC).  Fail with an actionable message instead
-    of the compiler OOM."""
+    (``slab_rows`` = D + K_LOC).  ``chain_slabs`` is the number of live
+    (C, TILE)s where it is not three; ``transposed`` counts the (TILE, k)
+    operands a kernel transposes for a dot over the lanes: each is laid
+    out in tiles of 128 lanes whatever k is (4 MB at TILE 8192).  The
+    default ``budget`` is conservative (the OOM had >3 live (C, TILE)s);
+    a call that raises Mosaic's limit passes its own.  Fail with an
+    actionable message instead of the compiler OOM."""
     if interpret:
         return
-    budget = 10 * 1024 * 1024  # conservative: the OOM had >3 live (C,TILE)s
     need = (
-        3 * cpad * lane_tile * 4        # (C, TILE) logits/resid/val terms
+        chain_slabs * cpad * lane_tile * 4  # (C, TILE) logits/resid/val terms
         + 2 * k_loc * lane_tile * 4     # (K_LOC, TILE) one-hot + iota
         + slab_rows * lane_tile * 4     # (D + K_LOC, TILE) stacked slab
         + cpad * q * k_loc * 4          # (C, Q*K_LOC) group window block
+        + transposed * lane_tile * 128 * 4  # (TILE, k) in 128-lane tiles
     )
     if need > budget:
         raise ValueError(
             f"chain batch C={cpad} at lane_tile={lane_tile} "
             f"(k_loc={k_loc}, q={q}) needs ~{need / 2**20:.1f} MB scoped "
-            f"VMEM, more than the TPU core's ~16MB allows with headroom; "
+            f"VMEM, more than the {budget / 2**20:.0f} MB the kernel may ask "
+            f"of the TPU core with headroom; "
             f"reduce chains per device program, halve the tile with "
             f"STARK_GROUPED_LANE_TILE, or use the offset-layout Fused "
             f"model, whose lane tile shrinks with the chain count"
@@ -417,12 +430,21 @@ hier_logistic_loglik.defvjp(_hier_fwd, _hier_bwd)
 
 # --- grouped LMM: gaussian link, Q random effects per group -------------
 # Same dense-window trick for benchmark config 3 (random intercept +
-# slopes, 10k groups over 100k rows — ~10 rows/group, so grouped_layout
-# shrinks the lane tile until each tile's window fits).  The kernel
+# slopes, 10k groups; over configs/lmm.yaml's 100k rows, ~10 rows/group,
+# grouped_layout shrinks the lane tile to 1024 until each tile's window
+# fits; over the on-chip cell's 81.9M rows it keeps 8192).  The kernel
 # computes mu = intercept + X·beta + Σ_q z_q ⊙ (u_q-window @ onehot)
 # entirely in-register and emits SSR, Σresid, X·resid and the per-tile
 # windowed u-gradient partials; sigma stays outside (scale-free kernel,
 # like ops/logistic_fused.py's gaussian link).
+
+# What the kernel may ask of the core's VMEM (128 MiB on a v5e).  Mosaic's
+# default scoped limit is 16 MB, and at the lane tile the layout picks for
+# D + Q = 10 (8192) the chip's compiler asks 20.2 MB for C = 16, D = 8,
+# Q = 2, K_LOC = 8 (a compile for a described v5e, PR 32): three dots
+# contract over the lanes, and their transposed operands ``xt.T`` and
+# ``onehot.T`` take 4 MB each (`_check_chain_vmem`).
+_LMM_VMEM_LIMIT = 48 * 1024 * 1024
 
 
 def _make_grouped_lmm_kernel(n, lane_tile, k_loc, q):
@@ -475,14 +497,19 @@ def _make_grouped_lmm_kernel(n, lane_tile, k_loc, q):
 def _grouped_lmm_call(beta, u, intercept, xt, zt, y, gl, first_gid, *,
                       k_loc, lane_tile, interpret):
     """beta (C, D), u (C, G, Q), intercept (C,) ->
-    (ssr (C,), sum_resid (C,), gbeta (C, D), gu (C, G, Q))."""
+    (ssr (C, grid): the tiles' sums of squares, left apart for
+    `_gauss_loglik`; sum_resid (C,), gbeta (C, D), gu (C, G, Q))."""
     interpret = _resolve_interpret(interpret)
     c, d = beta.shape
     g_total, q = u.shape[1], u.shape[2]
     n = xt.shape[1]
     grid = -(-n // lane_tile)
     cpad = -(-c // 8) * 8
-    _check_chain_vmem(cpad, lane_tile, interpret, k_loc=k_loc, q=q)
+    # (C, TILE)s: mu, resid, a dot's result and a weighted resid a random
+    # effect; transposed: xt.T and an onehot.T a random effect
+    _check_chain_vmem(cpad, lane_tile, interpret, k_loc=k_loc, q=q,
+                      slab_rows=d + q, chain_slabs=3 + q, transposed=1 + q,
+                      budget=_LMM_VMEM_LIMIT // 2)
     if cpad != c:
         beta = jnp.pad(beta, ((0, cpad - c), (0, 0)))
         u = jnp.pad(u, ((0, cpad - c), (0, 0), (0, 0)))
@@ -532,9 +559,12 @@ def _grouped_lmm_call(beta, u, intercept, xt, zt, y, gl, first_gid, *,
         out_shape=out_shape,
         interpret=interpret,
         name="stark_lmm_ll_grouped",
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=_LMM_VMEM_LIMIT
+        ),
     )(*args)
-    acc = jnp.sum(out[0], axis=0)  # (cpad, 2)
-    ssr, sresid = acc[:c, 0], acc[:c, 1]
+    ssr = out[0][:, :c, 0].T  # (C, grid)
+    sresid = jnp.sum(out[0][:, :c, 1], axis=0)
     gbeta = jnp.sum(out[1], axis=0)[:c]
     parts = out[2].reshape(grid, cpad, q, k_loc)
     gu = jnp.stack(
@@ -590,42 +620,132 @@ def _vg_lmm_vmap(axis_size, in_batched, beta, u, intercept, xt, zt, y, gl,
     )
 
 
+def _tile_rows(n, lane_tile):
+    """(grid,) float32: the rows each tile holds (the last may be short)."""
+    first = lane_tile * np.arange(-(-n // lane_tile))
+    return np.minimum(lane_tile, n - first).astype(np.float32)
+
+
+def _log1p_near0(t):
+    """log1p(t) = 2 atanh(t / (2 + t)) from its series where |t| < 0.4, in
+    multiplications, additions and one quotient: it holds float32's 1e-7
+    of the result, where the chip's own ``log1p`` is up to 1.6e-4 of it
+    off for |t| in 1e-7..0.1 (and ``expm1`` 1.1e-4), which `_gauss_loglik`
+    multiplies by 8e7 rows: with the library's two the centred density of
+    the on-chip cell's shape read 0.07 to 175 nats off over sweeps of
+    sigma 1e-3 to 1e-1 wide, with this series 0.014 to 1.6 (my chip run,
+    PR 32: PERF.md section 6)."""
+    s = t / (2.0 + t)
+    s2, acc = s * s, 0.0
+    for k in range(15, 0, -2):  # 1 + s2/3 + s2^2/5 + ...
+        acc = 1.0 / k + s2 * acc
+    return jnp.where(jnp.abs(t) < 0.4, 2.0 * s * acc, jnp.log1p(t))
+
+
+def _gauss_loglik(ssr, sigma, rows, center=None):
+    """The normal log-density of all rows and its derivative in sigma, from
+    the tiles' sums of squares ``ssr`` (grid,) and row counts ``rows``.
+
+    Over tens of millions of rows -ssr/(2 sigma^2) and -n log sigma are
+    float32s near 1e7-1e8 whose last bit is whole nats, and the
+    sigma-gradient ssr/sigma^3 - n/sigma is the difference of two such.  So
+    every large sum is formed tile by tile, where the terms are thousands,
+    and ``center[0]`` (a scalar close to the total) comes off tile by tile
+    before they are added (`logistic_fused._sum_tiles`).
+
+    That alone does not make the value one an accept step can live on: the
+    two scalars 1/sigma^2 and log sigma multiply totals of 1e7-1e8, and a
+    float32 holds them to 6e-8 at best (the chip's ``exp`` and ``log`` to
+    1e-6: PERF.md section 6, PR 31 and PR 32), which is nats to a hundred
+    nats that change with sigma's last bits.  ``center[1:]`` = (sigma0,
+    log sigma0, 1 / sigma0^2) at the position the constant was taken at
+    (`ref_scale`) takes the large terms to that fixed scale, where the
+    scalars' errors are the same at every position and come off with the
+    centre, and leaves ``delta = log(sigma / sigma0)``, formed as a
+    ``log1p`` of the exact difference, to carry what moves:
+
+        ll = sum_t [-ssr_t / (2 sigma0^2) - n_t log sigma0 - ...]
+             - (S / 2) expm1(-2 delta) - n delta,     S = ssr / sigma0^2
+
+    whose last two terms are some n * delta each, held to float32's 1e-7
+    (`_log1p_near0`; ``expm1(-2 delta)`` is (sigma0 / sigma)^2 - 1, a
+    quotient of the exact difference): within a thousandth of sigma0 their
+    errors are hundredths of a nat, within a hundredth tenths.  The three numbers are DATA here, computed once
+    where the centre is taken and carried beside it: two programs that
+    each took ``log sigma0`` for themselves read it a microrelative
+    apart on the chip (one folds ``log(exp(x))``), 140 nats over 7e7 rows
+    between the energies one program left and the next one proposes
+    against, and no proposal was ever accepted (my chip runs, PR 32).
+    A chain has its own ``center`` (`Model.center_per_chain`): the three
+    numbers of one chain agree with one another to a float32's rounding
+    and no better, so the potentials of two chains may stand whole nats
+    apart from the truth, each the same number of nats at every position
+    of its chain.  Without ``center`` the scale is ``sigma`` itself, the
+    sum is the plain one and ``delta`` is 0.
+    """
+    if center is None:
+        s0, log_s0, inv0 = sigma, jnp.log(sigma), 1.0 / (sigma * sigma)
+    else:
+        s0, log_s0, inv0 = center[1], center[2], center[3]
+    val = _sum_tiles(
+        -0.5 * ssr * inv0 - rows * (log_s0 + 0.5 * _LOG_2PI),
+        None if center is None else center[0],
+    )
+    excess = jnp.sum(ssr * inv0 - rows)  # S - n, tile by tile
+    if center is None:
+        return val, excess / sigma
+    delta = _log1p_near0((sigma - s0) / s0)
+    swing = jnp.sum(ssr) * inv0 * (
+        (s0 - sigma) * (s0 + sigma) / (sigma * sigma)
+    )
+    val = val - 0.5 * swing - float(np.sum(rows, dtype=np.float64)) * delta
+    return val, (excess + swing) / sigma
+
+
+def ref_scale(sigma):
+    """(3,): what `_gauss_loglik` keeps of the noise scale at the position
+    its centre is taken at: sigma, log sigma, 1 / sigma^2."""
+    return jnp.stack([sigma, jnp.log(sigma), 1.0 / (sigma * sigma)])
+
+
 @jax.custom_vjp
 def lmm_grouped_loglik(beta, u, intercept, sigma, xt, zt, y, gl, first_gid,
-                       k_loc_arr, lt_arr):
+                       k_loc_arr, lt_arr, center=None):
     """Differentiable fused LMM normal log-lik over group-sorted rows.
 
     mu = intercept + X·beta + Σ_q z_q ⊙ u[g, q]; one Pallas pass yields
-    the SSR, Σresid, ∂/∂beta and the windowed ∂/∂u — no (C, N)
-    intermediate.  sigma applies outside (scale-free kernel).  Layout
-    args (gl, first_gid, k_loc_arr, lt_arr) come from `grouped_layout`.
+    the tiles' SSR, Σresid, ∂/∂beta and the windowed ∂/∂u — no (C, N)
+    intermediate.  sigma applies outside (scale-free kernel), tile by
+    tile (`_gauss_loglik`).  Layout args (gl, first_gid, k_loc_arr,
+    lt_arr) come from `grouped_layout`.  ``center`` (4,): a scalar close
+    to the log-lik where the chain is, then `ref_scale` of the sigma at the
+    position it was taken at; the value comes back less ``center[0]``.  A
+    constant of the program: it gets no cotangent.
     """
-    ssr, _, _, _ = _vg_lmm(
-        beta, u, intercept, xt, zt, y, gl, first_gid, k_loc_arr, lt_arr
-    )
-    n = y.shape[-1]
-    return -0.5 * ssr / sigma**2 - n * jnp.log(sigma) - 0.5 * n * _LOG_2PI
+    return _lmm_fwd(beta, u, intercept, sigma, xt, zt, y, gl, first_gid,
+                    k_loc_arr, lt_arr, center)[0]
 
 
 def _lmm_fwd(beta, u, intercept, sigma, xt, zt, y, gl, first_gid,
-             k_loc_arr, lt_arr):
+             k_loc_arr, lt_arr, center):
     ssr, sresid, gbeta, gu = _vg_lmm(
         beta, u, intercept, xt, zt, y, gl, first_gid, k_loc_arr, lt_arr
     )
-    n = y.shape[-1]
-    val = -0.5 * ssr / sigma**2 - n * jnp.log(sigma) - 0.5 * n * _LOG_2PI
-    return val, (ssr, sresid, gbeta, gu, sigma, y.shape[-1])
+    val, dsigma = _gauss_loglik(
+        ssr, sigma, _tile_rows(y.shape[-1], 128 * lt_arr.shape[0]), center
+    )
+    return val, (sresid, gbeta, gu, sigma, dsigma)
 
 
 def _lmm_bwd(res, ct):
-    ssr, sresid, gbeta, gu, sigma, n = res
+    sresid, gbeta, gu, sigma, dsigma = res
     inv2 = 1.0 / (sigma * sigma)
     return (
         ct * inv2 * gbeta,
         ct * inv2 * gu,
         ct * inv2 * sresid,
-        ct * (ssr * inv2 / sigma - n / sigma),
-        None, None, None, None, None, None, None,
+        ct * dsigma,
+        None, None, None, None, None, None, None, None,
     )
 
 

@@ -387,6 +387,10 @@ def _sample_until_converged(
         )
     with trace.phase("compile", stage="build"):
         ap = backend.adaptive_parts(model, cfg, data)
+        # the posterior's width, and whether the programs carry the
+        # potential's centre (`model.Centering`), on the call's `run` span
+        telemetry.note(root=True, ndim=ap.fm.ndim,
+                       centred=ap.fm.centering is not None and data is not None)
 
     if sync_blocks is None:
         # multi-process meshes run serial: collect is a process_allgather
@@ -954,7 +958,8 @@ def _checkpoint_block(run: _Run, b: _Block):
         "model": run.model_name,
         "kernel": run.cfg.kernel,
     })
-    ckpt_span.close()
+    ckpt_span.close(
+        bytes_written=sum(int(np.asarray(a).nbytes) for a in arrays.values()))
     b.t_ckpt = ckpt_span.seconds
     if run.trace.enabled:
         run.trace.emit(

@@ -416,8 +416,10 @@ class SpanRecord(NamedTuple):
     fields: Dict[str, Any]
 
 
-#: set-up plus a 30 s window of the benchmark is under 500 spans
-SPAN_LOG_SIZE = 4096
+#: set-up plus a 30 s window of the benchmark is under 500 spans; a test
+#: process that drives a dozen runs and slices the log by its length
+#: (`onchip/tests`) must not fill it
+SPAN_LOG_SIZE = 16384
 
 _SPAN_LOG: "collections.deque[SpanRecord]" = collections.deque(
     maxlen=SPAN_LOG_SIZE)
@@ -555,6 +557,17 @@ def run_span(**fields: Any) -> Span:
     """The root span ``run`` of one entry call: spans opened inside it
     carry its run ordinal."""
     return Span("run", fields, root=True)
+
+
+def note(root: bool = False, **fields: Any) -> None:
+    """Attach fields to the innermost open span, or with ``root`` to the
+    outermost (an entry call's ``run``), from code that did not open it;
+    nothing where no span is open."""
+    sp = _OPEN_SPAN.get()
+    while root and sp is not None and sp._outer is not None:
+        sp = sp._outer
+    if sp is not None:
+        sp.fields.update(fields)
 
 
 def span_log() -> List[SpanRecord]:

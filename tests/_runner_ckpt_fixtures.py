@@ -7,11 +7,20 @@ next.  The committed files were written by PR 29's tree (commit 903e46a),
 before PR 30 moved the carry and its file behind the kernel seam;
 `tests/test_runner_seam.py` resumes each under the tree it runs on and holds
 the draws to those, bit for bit.  Write them again only when the format is
-meant to change.
+meant to change.  `chees_centred` is PR 32's: a model that centres its
+potential on one chip, chain by chain (`Model.center_per_chain`), whose file
+holds ``pe_center`` (a row a chain) and the potential in float64; no tree
+before it could write it
+(`python tests/_runner_ckpt_fixtures.py chees_centred` wrote that one alone,
+from the repo's root with `PYTHONPATH=.` and, as `conftest.py` sets them for
+the tests, `JAX_PLATFORMS=cpu STARK_PROFILE=0
+XLA_FLAGS=--xla_force_host_platform_device_count=8`: with one host device the
+draws differ in their last digits).
 """
 
 import os
 import shutil
+import sys
 
 import jax.numpy as jnp
 import numpy as np
@@ -48,6 +57,17 @@ def toy_rows():
     return {"x": jnp.asarray(x), "y": jnp.asarray(y)}
 
 
+def toy_grouped():
+    """A linear mixed model over 8 groups of 512 rows in all, through the
+    grouped kernel (interpreted here): the model that centres chain by chain."""
+    import jax
+
+    from stark_tpu.models import FusedLinearMixedModelGrouped, synth_lmm_data
+
+    data, _ = synth_lmm_data(jax.random.PRNGKey(4), 512, 3, 8)
+    return FusedLinearMixedModelGrouped(3, 8), data
+
+
 _CHEES = dict(
     kernel="chees", chains=8, block_size=4, num_warmup=12, map_init_steps=3,
     init_step_size=0.1, max_leapfrog=8, seed=3, rhat_target=0.0,
@@ -65,7 +85,15 @@ CASES = {
     "chees_sample": (_CHEES, 2, 3, None),
     "chees_warmup": (_CHEES, 1, 1, 8),
     "nuts_sample": (_NUTS, 2, 3, None),
+    "chees_centred": (_CHEES, 2, 3, None),
 }
+
+
+def _job(name):
+    """(model, rows) of a case."""
+    if name == "chees_centred":
+        return toy_grouped()
+    return ToyRegression(), toy_rows()
 
 
 def paths(name):
@@ -79,8 +107,7 @@ def write_checkpoint(name, path):
     kw, blocks, _, warm_at = CASES[name]
     if warm_at is None:
         stark_tpu.sample_until_converged(
-            ToyRegression(), toy_rows(), max_blocks=blocks,
-            checkpoint_path=path, **kw,
+            *_job(name), max_blocks=blocks, checkpoint_path=path, **kw,
         )
         return
     from stark_tpu import checkpoint as ck
@@ -96,7 +123,7 @@ def write_checkpoint(name, path):
     ck.save_checkpoint = keep
     try:
         stark_tpu.sample_until_converged(
-            ToyRegression(), toy_rows(), max_blocks=blocks,
+            *_job(name), max_blocks=blocks,
             checkpoint_path=live, **kw,
         )
     finally:
@@ -110,15 +137,14 @@ def resume_next_block(name, path, **more):
     kw, _, blocks, _ = CASES[name]
     kw = {k: v for k, v in kw.items() if k not in ("chains", "seed")}
     post = stark_tpu.sample_until_converged(
-        ToyRegression(), toy_rows(), max_blocks=blocks, resume_from=path,
-        **kw, **more,
+        *_job(name), max_blocks=blocks, resume_from=path, **kw, **more,
     )
     return np.asarray(post.draws_flat)[:, -kw["block_size"]:]
 
 
 if __name__ == "__main__":
     os.makedirs(FIXTURES, exist_ok=True)
-    for case in CASES:
+    for case in sys.argv[1:] or CASES:
         ckpt, nxt = paths(case)
         write_checkpoint(case, ckpt)
         np.save(nxt, resume_next_block(case, ckpt))

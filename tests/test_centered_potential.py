@@ -241,3 +241,60 @@ def test_checkpoint_holds_the_potential_and_resumes_the_carry(
     assert float(a["pe_center"]) == float(b["pe_center"])
     np.testing.assert_array_equal(a["z"], b["z"])
     np.testing.assert_allclose(a["pe"], b["pe"], rtol=0, atol=1e-7)
+
+
+class _OwnCentres(FusedLogistic):
+    """`FusedLogistic` centred chain by chain (`Model.center_per_chain`): a
+    chain's row is its constant and, to have something behind it, its first
+    coefficient where the constant was taken."""
+
+    center_per_chain = True
+
+    def center_keep(self, params):
+        return params["beta"][:1]
+
+    def center_data(self, data, center):
+        return super().center_data(data, center[0])
+
+
+def test_a_centre_a_chain_is_split_with_the_chains_over_the_mesh(rows):
+    """Rows over two devices and chains over two more: every device holds
+    the rows of its own chains' centres, each chain is centred where it
+    stands itself, and the ensemble goes where the plain one goes."""
+    data, beta = rows
+    mesh = make_mesh({"data": 2, "chains": 2}, devices=jax.devices()[:4])
+    cfg = SamplerConfig(kernel="chees", num_warmup=10, map_init_steps=0)
+    # chains far apart: thousands of nats, where one constant cannot serve
+    z0 = beta + jax.random.normal(jax.random.PRNGKey(3), (C, D))
+    key = jax.random.PRNGKey(0)
+    keys, us = jax.random.split(key, 3), jnp.ones((3,), jnp.float32)
+    ends = {}
+    for model in (_OwnCentres(D), Logistic(D)):
+        ap = ShardedBackend(mesh).adaptive_parts(
+            model, cfg, data if model.center_per_chain
+            else prepare_model_data(model, {
+                "x": np.asarray(data["xT"]).T, "y": np.asarray(data["y"])}))
+        warm = ap.init_j(key, ap.put_chains(z0), ap.data)
+        carry = ap.chees.finalize(warm)._replace(
+            log_eps=ap.put_rep(jnp.log(jnp.float32(0.01))),
+            log_T=ap.put_rep(jnp.log(jnp.float32(0.05))))
+        ends[type(model)] = warm, ap.samp_j(carry, keys, us, ap.data)[0]
+    warm, run = ends[_OwnCentres]
+    centre = np.asarray(warm.pe_center)
+    assert centre.shape == (C, 2)
+    assert len(warm.pe_center.sharding.device_set) == 4
+    assert not warm.pe_center.sharding.is_fully_replicated
+    np.testing.assert_allclose(
+        centre[:, 0], [-_ll64(b, data) for b in np.asarray(z0)], rtol=1e-6)
+    np.testing.assert_array_equal(centre[:, 1], np.asarray(z0)[:, 0])
+    assert np.ptp(centre[:, 0]) > 1000.0
+    # carried: the prior's part alone, whatever the chain's potential
+    assert np.max(np.abs(warm.states.potential_energy)) < 20.0
+    np.testing.assert_array_equal(np.asarray(run.pe_center), centre)
+    pe = np.asarray(run.states.potential_energy, np.float64)
+    np.testing.assert_allclose(pe + centre[:, 0],
+                               _potential64(run.states.z, data), rtol=2e-6)
+    plain = ends[Logistic][1]
+    assert plain.pe_center is None
+    np.testing.assert_allclose(np.asarray(run.states.z),
+                               np.asarray(plain.states.z), atol=1e-4)

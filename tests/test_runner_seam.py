@@ -198,3 +198,20 @@ def test_a_fresh_checkpoint_has_the_fixtures_names_dtypes_and_meta(
     assert ({k: (v.dtype, v.shape) for k, v in arrays.items()}
             == {k: (v.dtype, v.shape) for k, v in want_arrays.items()})
     assert set(meta) == set(want_meta)
+
+
+def test_one_chips_centred_file_holds_the_potential_and_its_centre():
+    # written by a JaxBackend run of a model with `center_per_chain` (PR 32);
+    # the two cases above resume it with its `pe_center`, bit for bit
+    arrays, meta = load_checkpoint(paths("chees_centred")[0])
+    assert meta["kernel"] == "chees" and meta["model"].endswith("Grouped")
+    # a row a chain: the constant, then what the model keeps of the position
+    # it was taken at (the noise scale, its logarithm, its inverse square)
+    centre = arrays["pe_center"]
+    assert arrays["pe"].dtype == np.float64 and centre.shape == (8, 4)
+    assert np.all(centre[:, 0] != 0) and np.all(centre[:, 1] > 0)
+    np.testing.assert_allclose(
+        centre[:, 2:], np.stack([np.log(centre[:, 1]), centre[:, 1] ** -2.0], 1),
+        rtol=1e-6, atol=1e-6)
+    plain, _ = load_checkpoint(paths("chees_sample")[0])
+    assert "pe_center" not in plain and plain["pe"].dtype == np.float32

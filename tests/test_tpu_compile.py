@@ -1,0 +1,52 @@
+"""The chip's compiler on the grouped Gaussian kernel at the benchmark's tile
+(PR 32): Mosaic refused it at its default scoped-VMEM limit, which no
+interpreted test could see.  Compiled here for a described TPU v5e, with no
+chip: nothing runs, so this says nothing of results or times.  One file, one
+fixture: only the worker that is given this file loads the TPU's library."""
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    try:
+        topo = topologies.get_topology_desc("v5e:2x2", "tpu")
+    except Exception as e:  # noqa: BLE001 - no TPU compiler here
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile for a described chip is written to the persistent cache and
+    # cannot be read back without one: keep it out
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def test_grouped_lmm_kernel_fits_the_cores_vmem_at_the_cells_tile(one_chip):
+    from stark_tpu.ops.hier_fused import _LMM_VMEM_LIMIT, _grouped_lmm_call
+
+    c, d, q, groups, tile, k_loc = 16, 8, 2, 64, 8192, 8
+    n = 16 * tile
+
+    def shape(dims, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    def call(beta, u, intercept, xt, zt, y, gl, first_gid):
+        return _grouped_lmm_call(
+            beta, u, intercept, xt, zt, y, gl, first_gid, k_loc=k_loc,
+            lane_tile=tile, interpret=False)
+
+    compiled = jax.jit(call).lower(
+        shape((c, d)), shape((c, groups, q)), shape((c,)), shape((d, n)),
+        shape((q, n)), shape((n,)), shape((n,), jnp.int32),
+        shape((n // tile,), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "stark_lmm_ll_grouped" in text
+    assert _LMM_VMEM_LIMIT > 16 * 1024 * 1024  # Mosaic's default refused it
