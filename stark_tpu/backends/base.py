@@ -97,6 +97,9 @@ class PendingBlock:
     carried: Dict[str, Any]
     extras: Any = None  # what else the kernel's checkpoint needs
     key: Any = None  # the host key as of this block's split (the loop's)
+    # the streaming ESS row and draw counts, reduced on the device behind
+    # the block (the loop's: ``stark_stream_ess``), or None
+    ess: Any = None
     t_enq: float = 0.0  # seconds the dispatch took (the loop's)
 
 

@@ -230,11 +230,32 @@ def stream_diag_from_draws(draws, lags: int, chains=None, ndim=None,
     return out
 
 
-def ess_from_suffstats(n, anchor, s1, s2, cross, ring, head) -> np.ndarray:
+def uniform_count(n) -> int:
+    """The draw count the chains of a streaming accumulator share, from
+    its host ``n``; ragged counts raise (the estimator below assumes one
+    count, and under ``jit`` it cannot look)."""
+    n = np.asarray(n)
+    count = int(n.max()) if n.size else 0
+    if n.size and count != int(n.min()):
+        raise ValueError(f"ragged per-chain counts: {n}")
+    return count
+
+
+def ess_from_suffstats(n, anchor, s1, s2, cross, ring, head):
     """Geyer initial-positive-sequence ESS LOWER BOUND from the streaming
     accumulators (`kernels.base.StreamDiagState`, leaves batched over a
     leading chains axis) — the adaptive runner's O(chains*d*L) convergence
     signal, replacing the full-history FFT pass in the hot loop.
+
+    Namespace-generic, as `rhat_from_suffstats`: numpy in -> numpy float64
+    out (the reference, and the fleet's per-lane gate); jnp in -> jnp out
+    in the accumulators' dtype, the runner's device program
+    (``stark_stream_ess``), which reduces the accumulator where it lies so
+    the gate fetches one (d,) row.  The draw count is DATA, not shape:
+    lags the chain has not reached yet and the Geyer pairs over them are
+    masked out of the full ``lags``, so one compiled program serves every
+    count.  Ragged counts raise on the numpy path alone (`uniform_count`);
+    a jnp caller checks its host ``n``.
 
     Bias direction: the accumulator truncates the autocovariance at lag L.
     When the Geyer initial-positive pair sequence terminates WITHIN the
@@ -247,68 +268,70 @@ def ess_from_suffstats(n, anchor, s1, s2, cross, ring, head) -> np.ndarray:
     candidate stop is still validated by the full split-form pass
     (runner.py), so this estimator only decides *when to look*.
 
-    Returns (d,) float64; NaN for frozen components (no defined ESS, so a
-    stuck parameter fails an ``ess > target`` gate — same convention as
-    ``ess``).
+    Returns (d,); NaN below four draws and for frozen components (no
+    defined ESS, so a stuck parameter fails an ``ess > target`` gate —
+    same convention as ``ess``).
     """
-    n = np.asarray(n)
-    count = int(n.max()) if n.size else 0
-    if n.size and count != int(n.min()):
-        raise ValueError(f"ragged per-chain counts: {n}")
-    anchor = np.asarray(anchor, np.float64)
-    s1 = np.asarray(s1, np.float64)
-    s2 = np.asarray(s2, np.float64)
-    cross = np.asarray(cross, np.float64)
-    ring = np.asarray(ring, np.float64)
-    head = np.asarray(head, np.float64)
+    if isinstance(cross, jnp.ndarray):
+        xp, dtype = jnp, cross.dtype
+        count = jnp.max(n)
+    else:
+        xp, dtype = np, np.float64
+        count = uniform_count(n)
+    anchor, s1, s2, cross, ring, head = (
+        xp.asarray(a, dtype) for a in (anchor, s1, s2, cross, ring, head))
     c, lags, d = cross.shape
-    if count < 4:
-        return np.full((d,), np.nan)
-    # per-chain centered moments -> per-chain autocovariance at lags 0..L
-    mean_c = s1 / count  # centered chain mean, (c, d)
-    gamma0 = (s2 - count * mean_c**2) / count
-    l_eff = min(lags, count - 1)
-    ls = np.arange(1, l_eff + 1)[None, :, None]  # (1, L_eff, 1)
-    # sums over the lagged/leading windows from the boundary buffers:
-    #   sum_{t=l+1..n} y_{t-l} = s1 - (last l draws)   (ring, newest first)
-    #   sum_{t=l+1..n} y_t     = s1 - (first l draws)  (head, in order)
-    s_head = s1[:, None, :] - np.cumsum(ring[:, :l_eff], axis=1)
-    s_tail = s1[:, None, :] - np.cumsum(head[:, :l_eff], axis=1)
-    gamma = (
-        cross[:, :l_eff]
-        - mean_c[:, None, :] * (s_head + s_tail)
-        + (count - ls) * mean_c[:, None, :] ** 2
-    ) / count  # (c, L_eff, d)
-    # cross-chain combine — the non-split analogue of _ess_chunk
-    chain_var = gamma0 * count / (count - 1.0)
-    mean_var = chain_var.mean(axis=0)  # (d,)
-    var_plus = mean_var * (count - 1.0) / count
-    if c > 1:
-        var_plus = var_plus + (anchor + mean_c).var(axis=0, ddof=1)
+    cnt = xp.asarray(count, dtype)  # the count, in the sums' arithmetic
+    # a count under 2 divides by zero on the way to the NaN it is given
     with np.errstate(divide="ignore", invalid="ignore"):
+        # per-chain centered moments -> per-chain autocovariance, lags 1..L
+        mean_c = s1 / cnt  # centered chain mean, (c, d)
+        gamma0 = (s2 - cnt * mean_c**2) / cnt
+        ls = xp.arange(1, lags + 1, dtype=dtype)[None, :, None]  # (1, L, 1)
+        # sums over the lagged/leading windows from the boundary buffers:
+        #   sum_{t=l+1..n} y_{t-l} = s1 - (last l draws)   (ring, newest first)
+        #   sum_{t=l+1..n} y_t     = s1 - (first l draws)  (head, in order)
+        s_head = s1[:, None, :] - xp.cumsum(ring, axis=1)
+        s_tail = s1[:, None, :] - xp.cumsum(head, axis=1)
+        gamma = (
+            cross
+            - mean_c[:, None, :] * (s_head + s_tail)
+            + (cnt - ls) * mean_c[:, None, :] ** 2
+        ) / cnt  # (c, L, d); rows of lags >= count are masked below
+        # cross-chain combine — the non-split analogue of _ess_chunk
+        chain_var = gamma0 * cnt / (cnt - 1.0)
+        mean_var = chain_var.mean(axis=0)  # (d,)
+        var_plus = mean_var * (cnt - 1.0) / cnt
+        if c > 1:
+            var_plus = var_plus + (anchor + mean_c).var(axis=0, ddof=1)
         rho = 1.0 - (mean_var[None] - gamma.mean(axis=0)) / var_plus[None]
-    rho = np.concatenate([np.ones((1, d)), rho], axis=0)  # lag 0
-    max_pairs = (l_eff + 1) // 2
-    pair = rho[0 : 2 * max_pairs : 2] + rho[1 : 2 * max_pairs : 2]
-    valid = np.cumprod(pair >= 0.0, axis=0).astype(bool)
-    mono = np.minimum.accumulate(np.where(valid, pair, np.inf), axis=0)
-    tau = -1.0 + 2.0 * np.sum(np.where(valid, mono, 0.0), axis=0)
-    # unterminated sequence: conservative geometric tail extension
-    if max_pairs >= 2:
-        unterminated = valid.all(axis=0)
-        g_last, g_prev = mono[-1], mono[-2]
-        with np.errstate(divide="ignore", invalid="ignore"):
-            r = np.where(g_prev > 0, g_last / g_prev, 0.0)
-        r = np.clip(r, 0.0, 0.995)
-        tail = np.where(unterminated, g_last * r / (1.0 - r), 0.0)
-        tau = tau + 2.0 * np.where(np.isfinite(tail), tail, 0.0)
-    tau = np.maximum(tau, 1.0 / np.log10(c * count + 10.0))
-    out = c * count / tau
+        rho = xp.concatenate([xp.ones((1, d), dtype), rho], axis=0)  # lag 0
+        # Geyer pairs (rho[2t], rho[2t+1]); the chain has reached lags
+        # 1..count-1, so the first ``n_pairs`` of them are there to read
+        all_pairs = (lags + 1) // 2
+        pair = rho[0 : 2 * all_pairs : 2] + rho[1 : 2 * all_pairs : 2]
+        n_pairs = (xp.minimum(lags, count - 1) + 1) // 2
+        reached = (xp.arange(all_pairs) < n_pairs)[:, None]
+        valid = xp.cumprod((pair >= 0.0) & reached, axis=0).astype(bool)
+        mono = xp.minimum.accumulate(xp.where(valid, pair, xp.inf), axis=0)
+        tau = -1.0 + 2.0 * xp.sum(xp.where(valid, mono, 0.0), axis=0)
+        # unterminated sequence: conservative geometric tail extension from
+        # the last two pairs reached (``valid`` is a prefix: its last
+        # reached row says whether every one passed)
+        last = xp.maximum(n_pairs - 1, 0)
+        unterminated = valid[last] & (n_pairs >= 2)
+        g_last, g_prev = mono[last], mono[xp.maximum(n_pairs - 2, 0)]
+        r = xp.where(g_prev > 0, g_last / g_prev, 0.0)
+        r = xp.clip(r, 0.0, 0.995)
+        tail = xp.where(unterminated, g_last * r / (1.0 - r), 0.0)
+        tau = tau + 2.0 * xp.where(xp.isfinite(tail), tail, 0.0)
+        tau = xp.maximum(tau, 1.0 / xp.log10(c * cnt + 10.0))
+        out = c * cnt / tau
     # frozen components: zero within-chain variance everywhere (exact —
     # centered sums make a constant chain's moments identically zero)
-    const = np.all(gamma0 <= 0.0, axis=0)
-    out[const | ~np.isfinite(var_plus) | (var_plus <= 0.0)] = np.nan
-    return out
+    const = xp.all(gamma0 <= 0.0, axis=0)
+    undefined = const | ~xp.isfinite(var_plus) | (var_plus <= 0.0)
+    return xp.where(undefined | (count < 4), xp.nan, out)
 
 
 class DrawHistory:

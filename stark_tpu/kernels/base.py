@@ -10,7 +10,9 @@ Also home to the ON-DEVICE streaming-diagnostics accumulator
 autocovariance sums carried through the sampling scans, so the adaptive
 runner's convergence gate reads O(chains*d*L) sufficient statistics per
 block instead of depending on the accumulated O(draws) history
-(`diagnostics.ess_from_suffstats` is the host-side consumer).
+(`diagnostics.ess_from_suffstats` is the consumer: on the device behind
+each block for the runner, whose gate fetches the ESS row alone; on the
+host for the fleet's lanes).
 """
 
 from __future__ import annotations
@@ -40,7 +42,7 @@ class StreamDiagState(NamedTuple):
     (``anchor``) — autocovariances are shift-invariant, so centering on a
     typical-set point keeps the float32 sums catastrophic-cancellation
     free without knowing the mean in advance; the true chain mean is
-    recovered on the host as ``anchor + s1/n``.
+    recovered as ``anchor + s1/n``.
 
     n       ()      draws accumulated
     anchor  (d,)    first draw (centering anchor)

@@ -27,8 +27,9 @@ def named_jit(fn, name: str, **jit_kwargs):
     module, the compile log), and a trace reduction that keys on it has
     to survive a refactor of the function behind it.  The names in use:
     ``stark_chees_init`` / ``stark_chees_warm`` / ``stark_chees_sample``
-    (the ensemble sampler's three programs) and ``stark_constrain`` (the
-    final layout of all draws)."""
+    (the ensemble sampler's three programs), ``stark_stream_ess`` (the
+    streaming gate's reduction of a block's accumulator to its ESS row)
+    and ``stark_constrain`` (the final layout of all draws)."""
     import functools
 
     import jax

@@ -52,8 +52,18 @@ from .backends.base import KernelEnv
 from .chees import CheesBlockKernel, load_adapt_state  # noqa: F401 (re-export)
 from .ops import quantize as _quantize
 from .model import Model
+from .platform import named_jit
 from .sampler import ChainBlockKernel, Posterior, SamplerConfig
 from .sampler import _constrain_draws
+
+#: the streaming gate's device half: a block's accumulator
+#: (`kernels.base.StreamDiagState`, chains-batched) reduced to its ESS row
+#: where it lies, beside the draw counts the host checks.  One program for
+#: every draw count (the count is data); the sampler's business in no way,
+#: so it lives on this side of the `BlockKernel` seam.
+_stream_ess = named_jit(
+    lambda diag: (diagnostics.ess_from_suffstats(*diag), diag.n),
+    "stark_stream_ess")
 
 
 class AdaptiveResult(Posterior):
@@ -260,9 +270,12 @@ def _sample_until_converged(
     moments + lag-1..``diag_lags`` autocovariance sums, per chain per
     coordinate), and the per-block ESS signal comes from
     `diagnostics.ess_from_suffstats` on that O(chains*d*L) summary
-    instead of the full-history FFT pass over the worst-k components —
-    the convergence gate's host transfer stops scaling with the draw
-    count (the ``diag_bytes_to_host`` trace field documents it).  The
+    instead of the full-history FFT pass over the worst-k components.
+    The summary never leaves the device: a small program
+    (``stark_stream_ess``) enqueued behind each block reduces it to the
+    ESS row, and the gate fetches that row and the draw counts, ``d``
+    floats and ``chains`` integers whatever the draw count and the lags
+    (the ``diag_bytes_to_host`` trace field documents it).  The
     streaming estimate is an ESS LOWER BOUND (truncation errs
     conservative), and it only decides *when to look*: every candidate
     stop is still validated by the same full split-R-hat/ESS pass over
@@ -765,6 +778,11 @@ def _dispatch_next(run: _Run):
         if profiled:
             jax.block_until_ready(pend.outs)
     run.diag, pend.key = pend.diag, run.key
+    if run.stream_diag:
+        # enqueued HERE, behind block k and ahead of block k+1: dispatched
+        # from the gate it would wait on the device behind the block in
+        # flight, and the gate would serialise with the device
+        pend.ess = _stream_ess(pend.diag)
     enq_span.close()
     pend.t_enq = enq_span.seconds
     run.blocks_dispatched += 1
@@ -834,10 +852,11 @@ def _gate_block(run: _Run, b: _Block):
         float(np.max(finite_rhat)) if finite_rhat.size else float("inf"))
     if run.stream_diag:
         # streaming gate: the convergence signal's ONLY device->host traffic
-        # is the O(chains*d*L) accumulator summary, constant per block
-        diag_host = run.ap.collect(pend.diag)
-        b.diag_bytes = int(sum(np.asarray(a).nbytes for a in diag_host))
-        ess_vals = diagnostics.ess_from_suffstats(*diag_host)
+        # is the ESS row the device reduced its accumulator to behind the
+        # block (`_dispatch_next`) and the chains' draw counts
+        ess_vals, n_host = run.ap.collect(pend.ess)
+        b.diag_bytes = int(ess_vals.nbytes + n_host.nbytes)
+        diagnostics.uniform_count(n_host)  # ragged counts raise
     else:
         # host gate: ESS on the worst-mixing components alone (by streaming
         # R-hat, NaN counting as worst): O(draws * k) host work per block
@@ -1008,7 +1027,8 @@ def _trace_block(run: _Run, b: _Block, next_in_flight: bool):
         block_len=pend.length,
         block_grad_evals=b.host.grad_evals,
         **({"fused": run.fused_tag} if run.fused_tag else {}),
-        # O(chains*d*L) with streaming diagnostics, O(draws*k) without
+        # the ESS row and the draw counts with streaming diagnostics (d
+        # floats, whatever the draws and lags), O(draws*k) without
         stream_diag=run.stream_diag,
         diag_bytes_to_host=b.diag_bytes,
         **b.host.sched_fields,

@@ -408,9 +408,10 @@ def make_block_runner(fm: FlatModel, cfg: SamplerConfig, block_size: int,
       block_run(key, state, diag, step_size, inv_mass, data)
         -> (HMCState, StreamDiagState, zs, accept, divergent, energy,
             ngrad)
-    so the adaptive runner's convergence gate transfers O(d*L) sufficient
+    so the adaptive runner's convergence gate reads O(d*L) sufficient
     statistics per chain per block instead of re-reading the draw history
-    (`diagnostics.ess_from_suffstats`).
+    (`diagnostics.ess_from_suffstats`, on the device: the host fetches
+    the ESS row).
 
     ``ragged`` (STARK_RAGGED_NUTS, NUTS only): route the block through the
     step-synchronized scheduler (`kernels.nuts_ragged`) — one batched

@@ -1455,9 +1455,10 @@ def summarize_trace(events: List[Dict[str, Any]], run: Optional[int] = None
     starved).
 
     ``diag`` aggregates the convergence-gate transfer accounting
-    (``diag_bytes_to_host`` per ``sample_block``: constant O(chains*d*L)
-    with streaming diagnostics on, growing O(draws*k) under the legacy
-    full-history gate), the last ESS forecast (predicted draws-per-chain
+    (``diag_bytes_to_host`` per ``sample_block``: the ESS row and the
+    draw counts with streaming diagnostics on, constant; growing
+    O(draws*k) under the legacy full-history gate), the last ESS
+    forecast (predicted draws-per-chain
     to reach the ESS target), and ``run_end``'s ``overshoot_draws``.
 
     ``nutssched`` aggregates the step-synchronized NUTS scheduler's

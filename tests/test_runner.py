@@ -6,6 +6,7 @@ import os
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 import stark_tpu
 from stark_tpu.checkpoint import load_checkpoint, save_checkpoint
@@ -168,7 +169,8 @@ def test_runner_span_sites_fresh_then_resumed(tmp_path, caplog):
             progress_cb=recs.append, time_budget_s=1e-3, **kw)
         resumed = _spans_of_last_run()
     for name in ("stark_chees_init", "stark_chees_warm",
-                 "stark_chees_sample", "stark_constrain"):
+                 "stark_chees_sample", "stark_stream_ess",
+                 "stark_constrain"):
         assert f"transforming {name} " in caplog.text, name
 
     assert _tree(fresh) == FRESH_TREE
@@ -229,6 +231,86 @@ def test_runner_span_sites_fresh_then_resumed(tmp_path, caplog):
     assert budget.name == "block.record"
     (drain,) = [r for r in resumed if r.name == "collect.drain"]
     assert drain.start_ns >= budget.end_ns
+
+
+# ---------------------------------------------------------------------------
+# the streaming gate: the ESS is reduced on the device behind each block
+# (`stark_stream_ess`), and the gate fetches the row
+# ---------------------------------------------------------------------------
+
+_GATE_KW = {
+    "hmc": dict(chains=2, kernel="hmc", num_leapfrog=4, num_warmup=30),
+    "chees": dict(chains=4, kernel="chees", init_step_size=0.5,
+                  num_warmup=30, map_init_steps=5),
+}
+
+
+def _gate_run(kernel, **kw):
+    kw = {**dict(block_size=20, max_blocks=3, min_blocks=3, rhat_target=0.0,
+                 seed=5, adaptive_blocks=False), **_GATE_KW[kernel], **kw}
+    return stark_tpu.sample_until_converged(StdNormal2(), **kw)
+
+
+def _reference_min_ess(draws, lags):
+    """The float64 host reference over the accumulators of ``draws``."""
+    from stark_tpu import diagnostics
+    from stark_tpu.kernels.base import StreamDiagState
+
+    st = StreamDiagState(**diagnostics.stream_diag_from_draws(
+        np.asarray(draws, np.float32), lags))
+    return float(np.min(diagnostics.ess_from_suffstats(*st)))
+
+
+@pytest.mark.parametrize("kernel", sorted(_GATE_KW))
+def test_streaming_gate_fetches_the_ess_row(kernel):
+    """Through either `BlockKernel`: a block's record says the gate
+    fetched ``d`` floats and the chains' draw counts, not the
+    ``chains x lags x d`` accumulator three times over, and its
+    ``min_ess`` is the host reference's over the draws so far."""
+    from stark_tpu.kernels.base import STREAM_DIAG_LAGS as lags
+
+    post = _gate_run(kernel)
+    chains, d = post.draws_flat.shape[0], post.draws_flat.shape[2]
+    assert [r["diag_bytes_to_host"] for r in post.history] == [
+        d * 4 + chains * 4] * 3
+    assert d * 4 + chains * 4 < 3 * chains * lags * d * 4
+    for r in post.history:
+        np.testing.assert_allclose(
+            r["min_ess"], _reference_min_ess(
+                post.draws_flat[:, : r["draws_per_chain"]], lags),
+            rtol=1e-3)  # float32 on the device: tests/test_stream_diag.py
+
+
+@pytest.mark.parametrize("kernel", sorted(_GATE_KW))
+def test_pipelined_and_serial_gates_read_the_same_ess(kernel):
+    """The summary program is enqueued at dispatch, behind its block: with
+    the next block already in flight (pipelined) or not (serial, where
+    the accumulator is donated to the next block AFTER the summary read
+    it), every block's ``min_ess`` is the same number."""
+    piped = _gate_run(kernel, sync_blocks=False)
+    serial = _gate_run(kernel, sync_blocks=True)
+    np.testing.assert_array_equal(piped.draws_flat, serial.draws_flat)
+    assert [r["min_ess"] for r in piped.history] == [
+        r["min_ess"] for r in serial.history]
+    assert all(r["min_ess"] is not None for r in piped.history)
+
+
+@pytest.mark.parametrize("kernel", sorted(_GATE_KW))
+def test_resumed_gate_covers_the_whole_history(tmp_path, kernel):
+    """A resume rebuilds the device carry from the stored draws
+    (`stream_diag_from_draws`), so its first gate's ESS row is over every
+    draw, not over the resumed block's alone."""
+    from stark_tpu.kernels.base import STREAM_DIAG_LAGS as lags
+
+    ckpt = str(tmp_path / "c.npz")
+    _gate_run(kernel, max_blocks=2, min_blocks=2, checkpoint_path=ckpt)
+    post = _gate_run(kernel, resume_from=ckpt)
+    first = post.history[2]
+    assert first["draws_per_chain"] == 60
+    whole = _reference_min_ess(post.draws_flat, lags)
+    alone = _reference_min_ess(post.draws_flat[:, 40:], lags)
+    np.testing.assert_allclose(first["min_ess"], whole, rtol=1e-3)
+    assert abs(alone - whole) > 0.05 * whole  # the two are told apart
 
 
 def test_likelihood_kernels_are_named_in_the_jaxpr():

@@ -129,3 +129,33 @@ def test_supervised_sharded_chees_kill_resume(tmp_path, monkeypatch, setup):
     # continues from the checkpointed count, not from zero
     resumed_blocks = [l for l in lines if l["event"] == "block"]
     assert resumed_blocks[-1]["draws_per_chain"] >= 150
+
+
+@pytest.mark.parametrize("sync_blocks", [False, True],
+                         ids=["pipelined", "serial"])
+def test_streaming_gate_fetches_the_ess_row_on_mesh(setup, sync_blocks):
+    """The mesh twin of `tests/test_runner.py`'s: the accumulator is
+    sharded over ``chains`` (and replicated over ``data``), the summary
+    program (`stark_stream_ess`) is a plain jitted program over the global
+    arrays, and the gate fetches its row: ``d`` floats and the draw
+    counts, with the host reference's ``min_ess``, in both loops."""
+    from stark_tpu import diagnostics
+    from stark_tpu.kernels.base import STREAM_DIAG_LAGS as lags
+    from stark_tpu.kernels.base import StreamDiagState
+
+    model, data = setup
+    post = stark_tpu.sample_until_converged(
+        model, data, backend=ShardedBackend(_mesh()), seed=2,
+        kernel="chees", chains=8, num_warmup=20, map_init_steps=5,
+        init_step_size=0.1, block_size=10, max_blocks=2, min_blocks=2,
+        rhat_target=0.0, adaptive_blocks=False, sync_blocks=sync_blocks)
+    chains, _, d = post.draws_flat.shape
+    assert [r["diag_bytes_to_host"] for r in post.history] == [
+        d * 4 + chains * 4] * 2
+    for r in post.history:
+        st = StreamDiagState(**diagnostics.stream_diag_from_draws(
+            post.draws_flat[:, : r["draws_per_chain"]].astype(np.float32),
+            lags))
+        np.testing.assert_allclose(
+            r["min_ess"], np.min(diagnostics.ess_from_suffstats(*st)),
+            rtol=1e-3)

@@ -10,8 +10,10 @@ The tentpole contracts (runner.py / kernels/base.py / diagnostics.py):
   stream-off runs produce bit-identical draws/checkpoints/stores;
 * `STARK_STREAM_DIAG=0 STARK_ADAPTIVE_BLOCKS=0` restores the historical
   fixed-block runner bit-exactly (the escape hatches);
-* the convergence gate's host transfer is CONSTANT O(chains*d*L) per block
-  with streaming on (``diag_bytes_to_host`` trace field);
+* the convergence gate's host transfer is CONSTANT per block with
+  streaming on, the ESS row and the draw counts (``diag_bytes_to_host``
+  trace field): the O(chains*d*L) accumulator is reduced on the device, by
+  the same `ess_from_suffstats` under ``jit``, one program for every count;
 * adaptive scheduling converges in fewer post-warmup draws than the fixed
   march on the eight-schools benchmark at equal targets;
 * the streaming gate can NEVER stop a run the full-pass validation rejects
@@ -134,6 +136,127 @@ def test_device_accumulator_matches_host_reference():
     np.testing.assert_allclose(e_dev, e_host, rtol=1e-3)
 
 
+# ---------------------------------------------------------------------------
+# the estimator under jit: the runner's device program (`stark_stream_ess`)
+# against the float64 host reference, on the same float32 accumulators
+# ---------------------------------------------------------------------------
+
+#: Why 1e-3.  Both sides start from the same float32 sums; the device then
+#: works in float32 (eps 6e-8) where the reference works in float64.  What
+#: float32 loses is in the differences: an autocovariance is a cross sum
+#: less mean terms of its own size (the sums are anchored at the chain's
+#: first draw, so the ratio is the chain's travel over its spread, squared:
+#: some 4 for a chain that drifts a hundred standard deviations), and a
+#: rho near zero is a difference of two numbers near one, summed over up to
+#: 25 pairs.  That reads 1e-7 to 6e-6 on these chains over seeds and 1.3e-5
+#: on the drifting one (CPU float32).  A Geyer cut that falls one pair
+#: apart moves tau by a pair near zero (the running minimum caps what
+#: follows it), so the estimate is continuous in its inputs.  1e-3 is
+#: seventy times the worst reading, and a tenth of a draw in a forecast of
+#: a hundred: nothing the block scheduler can see.
+_DEVICE_RTOL = 1e-3
+
+
+def _stream_ess_jit():
+    return jax.jit(lambda *st: diagnostics.ess_from_suffstats(*st))
+
+
+_ESS_J = _stream_ess_jit()  # shared: a shape compiles once for the file
+
+
+def _device_and_reference(ess_j, draws, lags=STREAM_DIAG_LAGS):
+    st = diagnostics.stream_diag_from_draws(
+        np.asarray(draws, np.float32), lags)
+    st = [st[k] for k in _DIAG_FIELDS]
+    ref = diagnostics.ess_from_suffstats(*st)
+    dev = ess_j(*[jnp.asarray(a) for a in st])
+    assert ref.dtype == np.float64 and dev.dtype == jnp.float32
+    return np.asarray(dev), ref
+
+
+def _assert_device_matches(dev, ref):
+    np.testing.assert_array_equal(np.isnan(dev), np.isnan(ref))
+    ok = ~np.isnan(ref)
+    np.testing.assert_allclose(dev[ok], ref[ok], rtol=_DEVICE_RTOL)
+
+
+#: below, at and past the point where the 50 lags fill
+_COUNTS = (3, 4, 5, 30, 50, 51, 52, 400)
+
+
+@pytest.mark.parametrize("count", _COUNTS)
+def test_device_ess_matches_reference_at_count(count):
+    rng = np.random.default_rng(100 + count)
+    x = _ar1(rng, 0.7, chains=4, n=count, d=5)
+    dev, ref = _device_and_reference(_ESS_J, x)
+    assert np.all(np.isnan(ref)) == (count < 4)
+    _assert_device_matches(dev, ref)
+
+
+def test_device_ess_is_one_program_for_every_count():
+    """The draw count is data: lags not reached yet are masks over the
+    full ``lags``, not shapes, so the whole sweep compiles once (on the
+    chip: no compile inside the window while the lags fill)."""
+    ess_j = _stream_ess_jit()  # its own: the cache below counts this sweep
+    rng = np.random.default_rng(7)
+    x = _ar1(rng, 0.5, chains=4, n=max(_COUNTS), d=5)
+    for count in _COUNTS:
+        _assert_device_matches(*_device_and_reference(ess_j, x[:, :count]))
+    assert ess_j._cache_size() == 1
+
+
+def _frozen_component(rng):
+    x = rng.standard_normal((3, 200, 4))
+    x[:, :, 1] = 7.0
+    return x
+
+
+def _all_frozen(rng):  # every chain stands still, each somewhere else
+    return np.repeat(rng.standard_normal((3, 1, 4)), 200, axis=1)
+
+
+def _one_chain(rng):
+    return _ar1(rng, 0.6, chains=1, n=300, d=3)
+
+
+def _drifting(rng):  # a hundred standard deviations over its length
+    return (_ar1(rng, 0.5, chains=4, n=600, d=3)
+            - np.linspace(0.0, 100.0, 600)[None, :, None])
+
+
+def _unterminated(rng):  # tau ~ 199 >> L = 50
+    return _ar1(rng, 0.99, chains=4, n=2000, d=3)
+
+
+@pytest.mark.parametrize("make, n_nan", [
+    (_frozen_component, 1), (_all_frozen, 4), (_one_chain, 0),
+    (_drifting, 0), (_unterminated, 0)], ids=lambda v: getattr(
+        v, "__name__", str(v)).lstrip("_"))
+def test_device_ess_edge_cases(make, n_nan):
+    """NaN where the reference says NaN (a frozen component fails the
+    gate, never passes it), one chain (no between-chain term), a chain
+    that comes down all window (the LMM cell's do), and the sequence the
+    lags cannot terminate: the tail bound, still erring low."""
+    # seed 1: the chains of `..._conservative_when_truncated` above
+    x = make(np.random.default_rng(1))
+    dev, ref = _device_and_reference(_ESS_J, x)
+    assert int(np.isnan(ref).sum()) == n_nan
+    _assert_device_matches(dev, ref)
+    if make is _unterminated:
+        assert np.all(dev <= diagnostics.ess(x) * 1.1)
+
+
+def test_ragged_counts_raise_on_the_host():
+    st = diagnostics.stream_diag_from_draws(
+        np.zeros((3, 10, 2), np.float32), 4)
+    st["n"] = np.array([10, 9, 10], np.int32)
+    with pytest.raises(ValueError, match="ragged"):
+        diagnostics.ess_from_suffstats(*[st[k] for k in _DIAG_FIELDS])
+    with pytest.raises(ValueError, match="ragged"):
+        diagnostics.uniform_count(st["n"])
+    assert diagnostics.uniform_count(np.full((3,), 10, np.int32)) == 10
+
+
 def _run(tmp_path, tag, **kw):
     d = tmp_path / tag
     d.mkdir()
@@ -220,8 +343,10 @@ def test_adaptive_budget_run_same_total_draws(tmp_path):
 
 def test_diag_bytes_constant_per_block(tmp_path):
     """With streaming on, the convergence gate's per-block host transfer
-    is CONSTANT at O(chains*d*L) — independent of the accumulated draw
-    count; the legacy gate's grows with the history."""
+    is CONSTANT, and no accumulator is in it: the device reduces that to
+    its ESS row behind the block, and the gate fetches the row and the
+    draw counts — independent of the accumulated draw count AND of the
+    lags; the legacy gate's grows with the history."""
     p = tmp_path / "t.jsonl"
     chains, d, lags = 2, 2, STREAM_DIAG_LAGS
     with RunTrace(str(p)) as tr:
@@ -233,9 +358,12 @@ def test_diag_bytes_constant_per_block(tmp_path):
     blocks = [e for e in events if e["event"] == "sample_block"]
     assert len(blocks) == 3
     sizes = [e["diag_bytes_to_host"] for e in blocks]
-    # n:int32 + (anchor,s1,s2):(d,) + (cross,ring,head):(L,d), all f32
-    expected = chains * 4 * (1 + 3 * d + 3 * lags * d)
+    # the ESS row (d,) f32 + n (chains,) int32; the accumulator the row
+    # came from stays on the device: n + (anchor,s1,s2):(d,) +
+    # (cross,ring,head):(L,d) a chain
+    expected = d * 4 + chains * 4
     assert sizes == [expected] * 3, (sizes, expected)
+    assert expected < chains * 4 * (1 + 3 * d + 3 * lags * d)
     assert all(e["stream_diag"] is True for e in blocks)
     s = summarize_trace(events)
     assert s["diag"]["bytes_last"] == expected
