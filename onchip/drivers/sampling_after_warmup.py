@@ -108,6 +108,7 @@ def run(env):
 
     blocks = [r for r in records if r.get("event") == "block"]
     skip = block  # call A's one block comes back with B's result
+    flat = np.asarray(result.draws_flat)
     measured = {
         "window_s": clock["window_s"], "blocks": blocks,
         "attempted": len(blocks),
@@ -115,14 +116,17 @@ def run(env):
         "collect_s": clock["collect_s"],
         "time_to_first_draw_s": first.get("t"),
         "warmup_done": first.get("rec"),
-        "draws_flat": np.asarray(result.draws_flat)[:, skip:],
+        "draws_flat": flat[:, skip:],
+        # call A's block, as B's result hands it back: where each chain stood
+        # between warm-up and the window (a check may want how far it has come)
+        "draws_before": flat[:, :skip],
         "draws": {k: np.asarray(v)[:, skip:] for k, v in result.draws.items()},
         "chains": chains, "block_size": block, "sizes": sizes,
         "state_start": _state(ck_a), "state_end": _state(ck_b),
         "full_warmup": bool(cfg.get("full_warmup", False)),
     }
     # free what the program holds on the device before the reference runs
-    del result, data, model, backend
+    del result, flat, data, model, backend
     return measured
 
 
