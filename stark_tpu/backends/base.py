@@ -38,6 +38,9 @@ class AdaptiveParts(NamedTuple):
                    a block's diag after dispatching the next one — the
                    runner's serial mode)
       seg_warmup   run(warm_keys, z0, data, seg) for per-chain kernels
+      map_init     map_init(z0, data) -> z0 for per-chain kernels: the
+                   configured MAP descent (``map_init_steps``) of every
+                   chain's start, compiled; None where none is configured
       get_block    get_block(block_size, diag_lags=None, donate_diag=False)
                    -> compiled v_block(keys, state, step_size, inv_mass,
                    data); with ``diag_lags`` the block threads a per-chain
@@ -60,6 +63,7 @@ class AdaptiveParts(NamedTuple):
     samp_j: Any = None
     samp_diag: Any = None
     seg_warmup: Any = None
+    map_init: Any = None
     get_block: Any = None
 
 
@@ -107,6 +111,48 @@ def carried_state(state, step_size, inv_mass) -> Dict[str, Any]:
     """`PendingBlock.carried` of an `HMCState` and its step and mass."""
     return {"z": state.z, "pe": state.potential_energy, "grad": state.grad,
             "step_size": step_size, "inv_mass": inv_mass}
+
+
+def checkpoint_potential(arrays: Dict[str, Any], centering) -> Dict[str, Any]:
+    """Checkpoint arrays whose ``pe`` is the potential itself.  A sampler
+    that carries its energies relative to a centre (``pe_center``,
+    collected beside them; ``centering``: its `model.Centering`) has the
+    centre's constant added in float64, which holds both to the last bit of
+    the float32 that was carried; ``pe_center`` stays in the file for the
+    resume."""
+    import numpy as np
+
+    center = arrays.pop("pe_center", None)
+    if center is not None:
+        arrays["pe"] = (np.asarray(arrays["pe"], np.float64)
+                        + np.float64(centering.constant(center)))
+        arrays["pe_center"] = center
+    return arrays
+
+
+def carried_potential(arrays, centering):
+    """-> (pe, pe_center) as a sampler's carry holds them, from a
+    checkpoint's arrays: `checkpoint_potential` undone.  ``centering``: the
+    `model.Centering` of the programs that resume, None where they carry no
+    centre; a file without one (written by programs that summed the plain
+    potential) then resumes relative to 0, which a centre that holds more
+    than its constant cannot."""
+    import numpy as np
+
+    if centering is None:
+        return arrays["pe"], None
+    if "pe_center" in arrays:
+        center = np.asarray(arrays["pe_center"], np.float32)
+    elif centering.width > 1:
+        raise ValueError(
+            "this checkpoint holds no pe_center, and the model's centre "
+            "keeps more than a constant: it cannot be made up on resume")
+    else:
+        center = np.asarray(
+            centering.zero(np.asarray(arrays["z"]).shape[0]), np.float32)
+    pe = (np.asarray(arrays["pe"], np.float64)
+          - np.float64(centering.constant(center)))
+    return pe.astype(np.float32), center
 
 
 def restored_key(arrays, name, reseed):

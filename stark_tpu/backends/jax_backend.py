@@ -21,15 +21,18 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..model import Model, flatten_model, prepare_model_data
+from ..platform import named_jit
 from ..telemetry import get_trace
 from .base import annotate_dispatch
 from ..sampler import (
     Posterior,
     SamplerConfig,
     _constrain_draws,
+    block_program,
     drive_segmented_sampling,
     make_block_runner,
     make_chain_runner,
+    make_map_init,
     make_segmented_warmup,
 )
 
@@ -207,27 +210,31 @@ class JaxBackend:
         the O(chains*d*L) accumulators in place.  ``ragged``
         (STARK_RAGGED_NUTS) selects the step-synchronized NUTS scheduler —
         same signatures plus one trailing per-chain lane-iteration output
-        (drivers that request it unpack accordingly)."""
+        (drivers that request it unpack accordingly).  The program has
+        one name a kernel, ``stark_nuts_block`` / ``stark_hmc_block``
+        (`sampler.block_program`), whatever its length and carries."""
+        name = block_program(cfg)
 
         def get(length, diag_lags=None, donate_diag=False, ragged=False):
             if diag_lags is None:
                 return self._cached(
                     model, cfg, ("block", length, ragged),
-                    lambda: jax.jit(jax.vmap(
+                    lambda: named_jit(jax.vmap(
                         make_block_runner(fm, cfg, length, ragged=ragged),
                         in_axes=(0, 0, 0, 0, None),
-                    )),
+                    ), name),
                 )
             return self._cached(
                 model, cfg, ("block", length, diag_lags, donate_diag,
                              ragged),
-                lambda: jax.jit(
+                lambda: named_jit(
                     jax.vmap(
                         make_block_runner(fm, cfg, length,
                                           diag_lags=diag_lags,
                                           ragged=ragged),
                         in_axes=(0, 0, 0, 0, 0, None),
                     ),
+                    name,
                     donate_argnums=(2,) if donate_diag else (),
                 ),
             )
@@ -275,7 +282,6 @@ class JaxBackend:
         )
         if cfg.kernel == "chees":
             from ..chees import CHEES_PROGRAMS, make_chees_parts
-            from ..platform import named_jit
 
             parts = self._cached(
                 model, cfg, "chees_parts", lambda: make_chees_parts(fm, cfg)
@@ -314,7 +320,12 @@ class JaxBackend:
         seg_warmup = self._cached(
             model, cfg, "seg_warmup", lambda: make_segmented_warmup(fm, cfg)
         )
+        map_init = make_map_init(fm, cfg)
         return bundle._replace(
             seg_warmup=seg_warmup,
+            map_init=map_init and self._cached(
+                model, cfg, "map_init",
+                lambda: named_jit(map_init, f"stark_{cfg.kernel}_map"),
+            ),
             get_block=self._get_block(model, fm, cfg),
         )

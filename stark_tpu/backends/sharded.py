@@ -31,10 +31,12 @@ from ..sampler import (
     Posterior,
     SamplerConfig,
     _constrain_draws,
+    block_program,
     drive_segmented_sampling,
     drive_segmented_warmup,
     make_block_runner,
     make_chain_runner,
+    make_map_init,
     make_warmup_parts,
 )
 from .base import annotate_dispatch
@@ -146,7 +148,7 @@ class ShardedBackend:
             # Works on multi-process meshes as well: the segmented
             # drivers keep chains-sharded keys/state on device and
             # collect via the draw allgather.
-            seg_warmup, get_block = self._segmented_parts(
+            seg_warmup, get_block, _ = self._segmented_parts(
                 model, fm, cfg, data, row_axes
             )
             from ..distributed import gather_draws
@@ -305,7 +307,8 @@ class ShardedBackend:
         return (parts,) + self._cache[cache_key]
 
     def _segmented_parts(self, model, fm, cfg, data, row_axes):
-        """(seg_warmup, get_block) for the per-chain kernels, shard_mapped:
+        """(seg_warmup, get_block, map_init) for the per-chain kernels,
+        shard_mapped:
         chains-sharded state/keys, data-sharded likelihood, driven by the
         same host drivers as the single-device backend."""
         S, R = P("chains"), P()
@@ -316,12 +319,12 @@ class ShardedBackend:
         )
         if cache_key not in self._cache:
 
-            def smap_seg(fn, in_specs, out_specs, donate=()):
+            def smap_seg(fn, in_specs, out_specs, donate=(), name=None):
                 # the segmented drivers pass data as a trailing arg even
                 # when it is None (the single-device vmapped parts need
                 # it); tolerate-and-drop it in the dataless mesh case
                 inner = self._smap(fn, in_specs, out_specs, data, data_specs,
-                                   donate=donate)
+                                   donate=donate, name=name)
                 if data is None:
                     return lambda *a: inner(*a[:-1])
                 return inner
@@ -351,7 +354,7 @@ class ShardedBackend:
                                 make_block_runner(fm, cfg, length),
                                 in_axes=(0, 0, 0, 0, None),
                             ),
-                            (S, S, S, S), S,
+                            (S, S, S, S), S, name=block_program(cfg),
                         )
                     else:
                         # the chains-batched StreamDiagState rides the
@@ -366,10 +369,15 @@ class ShardedBackend:
                             ),
                             (S, S, S, S, S), S,
                             donate=(2,) if donate_diag else (),
+                            name=block_program(cfg),
                         )
                 return blocks[key]
 
-            self._cache[cache_key] = (seg_warmup, get_block)
+            map_init = make_map_init(fm, cfg)
+            if map_init is not None:  # the chains' starts, where they lie
+                map_init = smap_seg(map_init, (S,), S,
+                                    name=f"stark_{cfg.kernel}_map")
+            self._cache[cache_key] = (seg_warmup, get_block, map_init)
         return self._cache[cache_key]
 
     def adaptive_parts(self, model, cfg: SamplerConfig, data):
@@ -435,10 +443,11 @@ class ShardedBackend:
                 chees=parts, init_j=init_j, warm_j=warm_j, samp_j=samp_j,
                 samp_diag=samp_diag,
             )
-        seg_warmup, get_block = self._segmented_parts(
+        seg_warmup, get_block, map_init = self._segmented_parts(
             model, fm, cfg, data, row_axes
         )
-        return bundle._replace(seg_warmup=seg_warmup, get_block=get_block)
+        return bundle._replace(
+            seg_warmup=seg_warmup, get_block=get_block, map_init=map_init)
 
     def _run_chees(
         self, model, fm, cfg, data, row_axes, *, chains, seed, init_params,

@@ -53,6 +53,7 @@ from .adaptation import (
     welford_init,
     welford_variance,
 )
+from .backends.base import carried_potential, checkpoint_potential
 from .kernels.base import HMCState, value_and_grad_of
 from .kernels.chees import (
     _cmean,
@@ -119,6 +120,40 @@ def _welford_batch(w: WelfordState, xs: jax.Array, chains_axis=None) -> WelfordS
     mean = w.mean + delta * nb / tot
     m2 = w.m2 + bm2 + delta * delta * na * nb / tot
     return WelfordState(w.count + bc, mean, m2)
+
+
+def map_descent(potential_fn, z0, steps: int):
+    """Descend each chain of ``z0`` (C, d) toward the mode with ``steps``
+    of Adam on the potential, before warm-up (``map_init_steps``; the
+    ensemble sampler's start and the per-chain kernels' alike): on peaked
+    big-N posteriors a random unconstrained init is thousands of posterior
+    sds from the mode and warm-up burns its whole budget descending; a few
+    hundred fused-gradient Adam steps cost seconds and let warm-up adapt in
+    the typical set.  Chains stay distinct (each descends its own init,
+    stopping well short of collapse)."""
+    vg_pot = jax.vmap(value_and_grad_of(potential_fn))
+
+    def adam_body(carry, _):
+        z, adam = carry
+        _, g = vg_pot(z)
+        g = jnp.where(jnp.isfinite(g), g, 0.0)
+        adam, step = _adam_ascent(adam, -g, lr=0.05, b2=0.999)
+        return (z + step, adam), None
+
+    (z0, _), _ = jax.lax.scan(
+        adam_body,
+        (
+            z0,
+            AdamState(
+                jnp.zeros_like(z0),
+                jnp.zeros_like(z0),
+                jnp.zeros((), jnp.int32),
+            ),
+        ),
+        None,
+        length=steps,
+    )
+    return z0
 
 
 class CheesWarmCarry(NamedTuple):
@@ -237,36 +272,7 @@ def make_chees_parts(
     def init_carry(key, z0, data=None) -> CheesWarmCarry:
         potential_fn = fm.bind(data)
         if cfg.map_init_steps > 0:
-            # descend each chain toward the mode with Adam on the
-            # potential before warmup: on peaked big-N posteriors a random
-            # unconstrained init is thousands of posterior sds from the
-            # mode and warmup burns its whole budget descending; a few
-            # hundred fused-gradient Adam steps cost seconds and let
-            # warmup adapt in the typical set.  Chains stay distinct
-            # (each descends its own init, stopping well short of
-            # collapse).
-            vg_pot = jax.vmap(value_and_grad_of(potential_fn))
-
-            def adam_body(carry, _):
-                z, adam = carry
-                _, g = vg_pot(z)
-                g = jnp.where(jnp.isfinite(g), g, 0.0)
-                adam, step = _adam_ascent(adam, -g, lr=0.05, b2=0.999)
-                return (z + step, adam), None
-
-            (z0, _), _ = jax.lax.scan(
-                adam_body,
-                (
-                    z0,
-                    AdamState(
-                        jnp.zeros_like(z0),
-                        jnp.zeros_like(z0),
-                        jnp.zeros((), jnp.int32),
-                    ),
-                ),
-                None,
-                length=cfg.map_init_steps,
-            )
+            z0 = map_descent(potential_fn, z0, cfg.map_init_steps)
         centres = fm.centering is not None and data is not None
         carry = CheesWarmCarry(
             states=init_ensemble(potential_fn, z0),
@@ -759,42 +765,6 @@ def load_adapt_state(path, *, kernel, model_name, ndim, data_fp=None):
         return None, repr(e)
 
 
-def _with_potential(arrays: Dict[str, Any], centering) -> Dict[str, Any]:
-    """Checkpoint arrays whose ``pe`` is the potential itself.  An ensemble
-    that carries its energies relative to a centre (``pe_center``,
-    collected beside them; ``centering``: its `model.Centering`) has the
-    centre's constant added in float64, which holds both to the last bit of
-    the float32 that was carried; ``pe_center`` stays in the file for the
-    resume."""
-    center = arrays.pop("pe_center", None)
-    if center is not None:
-        arrays["pe"] = (np.asarray(arrays["pe"], np.float64)
-                        + np.float64(centering.constant(center)))
-        arrays["pe_center"] = center
-    return arrays
-
-
-def _carried_potential(arrays, centering):
-    """-> (pe, pe_center) as an ensemble's carry holds them, from a
-    checkpoint's arrays: `_with_potential` undone.  ``centering``: the
-    `model.Centering` of the programs that resume, None where they carry no
-    centre; a file without one (written off the mesh) then resumes relative
-    to 0, which a centre that holds more than its constant cannot."""
-    if centering is None:
-        return arrays["pe"], None
-    if "pe_center" in arrays:
-        center = np.asarray(arrays["pe_center"], np.float32)
-    elif centering.per_chain:
-        raise ValueError(
-            "this checkpoint holds no pe_center, and the model's centre "
-            "keeps more than a constant: it cannot be made up on resume")
-    else:
-        center = np.float32(0.0)
-    pe = (np.asarray(arrays["pe"], np.float64)
-          - np.float64(centering.constant(center)))
-    return pe.astype(np.float32), center
-
-
 class CheesBlockKernel:
     """`backends.base.BlockKernel` for the ensemble sampler: blocks advance
     the whole ensemble through sample segments (frozen adaptation) from a
@@ -955,7 +925,7 @@ class CheesBlockKernel:
                 named[f"{part}_{f}"] = getattr(getattr(carry, part), f)
         # ap.collect (gather_draws on a mesh): np.asarray alone cannot read
         # non-addressable shards on multi-process meshes
-        arrays = _with_potential(self.ap.collect(named), self._centering)
+        arrays = checkpoint_potential(self.ap.collect(named), self._centering)
         arrays["step_size"] = np.exp(arrays["da_log_step"])
         # PRNG keys are host-side driver state, never mesh-sharded
         arrays["key"] = np.asarray(key)
@@ -1047,7 +1017,7 @@ class CheesBlockKernel:
         re-placed on the backend's layout: the ensemble's state over the
         chains, ``rep(name)`` for its shared adaptation, replicated."""
         pc, pr = self.ap.put_chains, self.ap.put_rep
-        pe, pe_center = _carried_potential(arrays, self._centering)
+        pe, pe_center = carried_potential(arrays, self._centering)
         if pe_center is not None:
             # a row a chain lies where the chains' states lie
             put = pc if self._centering.per_chain else pr
@@ -1149,7 +1119,7 @@ class CheesBlockKernel:
 
     def checkpoint_arrays(self, pending):
         extras = pending.extras  # the run carry's replicated scalars
-        arrays = _with_potential(self.ap.collect(
+        arrays = checkpoint_potential(self.ap.collect(
             {**pending.carried, "pe_center": extras.pe_center}
         ), self._centering)
         # the host key AS OF this block's dispatch: the pipeline may have split

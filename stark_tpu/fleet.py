@@ -1151,11 +1151,19 @@ class _FleetParts:
 _PARTS_CACHE: Dict[Tuple[Any, ...], Tuple[Any, _FleetParts]] = {}
 
 
+def _lane_model(model: Model):
+    """The flat model of a fleet's lanes.  A lane carries the plain
+    `HMCState` (the pool's slots, donors and checkpoints read its fields),
+    so the per-chain centre of `sampler.chain_potential` stays off here: a
+    fleet's problems are small by design."""
+    return dataclasses.replace(flatten_model(model), chain_centering=None)
+
+
 def _fleet_parts_for(model: Model, cfg: SamplerConfig, mesh=None):
     key = (model, cfg, mesh)
     hit = _PARTS_CACHE.get(key)
     if hit is None:
-        fm = flatten_model(model)
+        fm = _lane_model(model)
         hit = _PARTS_CACHE[key] = (fm, _FleetParts(fm, cfg, mesh))
     return hit
 
@@ -1199,7 +1207,7 @@ def _fleet_warmup(parts: _FleetParts, cfg, warm_keys, z0, data, seg, trace,
         e = min(s + seg, nw)
         with trace.phase("warmup_block", start=s, end=e,
                          fleet=int(z0.shape[0])):
-            state, da, welford, inv_mass, ndiv = jax.block_until_ready(
+            state, da, welford, inv_mass, ndiv, _ = jax.block_until_ready(
                 parts.v_seg(
                     wkeys[:, s:e], jnp.asarray(aflags[s:e]),
                     jnp.asarray(wflags[s:e]), state, da, welford, inv_mass,

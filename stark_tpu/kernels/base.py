@@ -108,6 +108,19 @@ class HMCState(NamedTuple):
     grad: Array  # shape (d,)
 
 
+class CentredState(NamedTuple):
+    """What a per-chain program carries for a model that can centre
+    (`model.Centering`, `FlatModel.chain_centering`): the chain's state, its
+    ``potential_energy`` RELATIVE to the constant of the row ``center``
+    beside it, so that leaf energies, multinomial weights and the accept
+    statistic are differences of small numbers and keep float32's
+    resolution at any number of rows.  A model without `Model.center_data`
+    carries the plain `HMCState`."""
+
+    state: HMCState
+    center: Array  # (width,): the constant, then what the model keeps
+
+
 class HMCInfo(NamedTuple):
     accept_prob: Array  # mean MH accept prob (dual-averaging signal)
     is_accepted: Array
@@ -158,6 +171,36 @@ def value_and_grad_of(potential_fn: PotentialFn):
 def init_state(potential_fn: PotentialFn, z: Array) -> HMCState:
     pe, grad = value_and_grad_of(potential_fn)(z)
     return HMCState(z=z, potential_energy=pe, grad=grad)
+
+
+def chain_potential(fm, data, carried):
+    """A per-chain program's view of what it carries: ``(potential_fn,
+    HMCState, rewrap)``.  ``fm``: the `model.FlatModel`, or a stand-in that
+    binds (`profiling.DispatchProbe`).  A model that can centre (`model.Centering`,
+    `FlatModel.chain_centering`) carries a `CentredState`: the potential is
+    bound relative to the chain's centre, and ``rewrap`` puts the centre
+    back beside the state the program leaves.  Every other model carries
+    the plain state and compiles the plain program."""
+    if getattr(fm, "chain_centering", None) is None or data is None:
+        return fm.bind(data), carried, lambda state: state
+    centre = carried.center
+    return (fm.bind_chain(data, centre), carried.state,
+            lambda state: CentredState(state, centre))
+
+
+def chain_recentred(fm, data, carried):
+    """``carried`` with the chain's centre moved to where the chain stands,
+    and its state evaluated again relative to it: one gradient.  Warm-up
+    moves far (from the start positions to the typical set), so each of its
+    programs starts with this, as ChEES's do (`chees.make_chees_parts`,
+    ``recentre``); sampling keeps the centre warm-up's last program left."""
+    cen = getattr(fm, "chain_centering", None)
+    if cen is None or data is None:
+        return carried
+    st = carried.state
+    centre = cen.at(st.z[None], st.potential_energy[None],
+                    carried.center[None])[0]
+    return CentredState(init_state(fm.bind_chain(data, centre), st.z), centre)
 
 
 def kinetic_energy(r: Array, inv_mass_diag: Array) -> Array:

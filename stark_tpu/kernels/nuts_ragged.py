@@ -71,6 +71,7 @@ import numpy as np
 
 from .base import (
     HMCState,
+    chain_potential,
     kinetic_energy,
     sample_momentum,
     stream_diag_update,
@@ -210,7 +211,7 @@ def make_ragged_block_runner(fm, cfg, block_size: int,
     max_depth = cfg.max_tree_depth
 
     def _block(key, state, diag, step_size, inv_mass_diag, data):
-        potential_fn = fm.bind(data)
+        potential_fn, state, rewrap = chain_potential(fm, data, state)
         d = state.z.shape[0]
         dtype = state.z.dtype
         slots = jnp.arange(max_depth, dtype=jnp.int32)
@@ -374,7 +375,7 @@ def make_ragged_block_runner(fm, cfg, block_size: int,
 
         c = jax.lax.while_loop(cond, body, init)
         outs = (c.out_z, c.out_accept, c.out_div, c.out_energy, c.out_ngrad)
-        return c.state, c.diag, outs, c.iters
+        return rewrap(c.state), c.diag, outs, c.iters
 
     def block_run(key, state, step_size, inv_mass, data=None):
         state, _, (zs, accept, divergent, energy, ngrad), iters = _block(

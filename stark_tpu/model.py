@@ -119,11 +119,14 @@ class Model:
         accept step of every sampler lives on energy differences of a
         tenth of a nat.  The ChEES programs ask for the potential relative
         to its value where the chains are (`Centering`,
-        `chees.make_chees_parts`)."""
+        `chees.make_chees_parts`); the per-chain kernels (NUTS, HMC) for
+        each chain's relative to where that chain stands, wherever they
+        run (`FlatModel.chain_centering`, `kernels.base.CentredState`)."""
         return None
 
-    #: how a model with `center_data` is centred.  False: one constant for
-    #: the ensemble (where the first chain stands), and only over a data
+    #: how the ensemble sampler centres a model with `center_data` (the
+    #: per-chain kernels always centre chain by chain).  False: one constant
+    #: for the ensemble (where the first chain stands), and only over a data
     #: mesh: `FusedLogistic`'s, whose one-chip programs stay the plain
     #: ones.  True: every chain relative to where it stands itself, on one
     #: chip as on a mesh: an accept step compares a chain with itself
@@ -241,8 +244,10 @@ class FlatModel:
         default_factory=dict, compare=False
     )
     # optional (models with `center_data`): the potential summed relative
-    # to a constant, see `Centering`
+    # to a constant, see `Centering`: the ensemble sampler's, and the
+    # per-chain kernels' (always a row a chain: `sampler.ChainBlockKernel`)
     centering: Optional["Centering"] = None
+    chain_centering: Optional["Centering"] = None
 
     def bind(self, data=None, pe_center=None) -> Potential:
         """Close over a dataset -> a Potential for the kernels.  With
@@ -250,6 +255,14 @@ class FlatModel:
         centre) the potential comes back less it."""
         if pe_center is not None:
             data = self.centering.data(data, pe_center)
+        return self._bound(data)
+
+    def bind_chain(self, data, centre) -> Potential:
+        """`bind` for a per-chain kernel: the potential of ONE chain less
+        the constant of its row ``centre`` (`chain_centering`)."""
+        return self._bound(self.chain_centering.data(data, centre))
+
+    def _bound(self, data) -> Potential:
         if self.potential_factory is not None:
             return self.potential_factory(data)
         return Potential(
@@ -394,36 +407,64 @@ def flatten_model(
         return pe, grad
 
     per_chain = bool(getattr(model, "center_per_chain", False))
-    centers = (
-        (axis_name is not None or per_chain)
-        and getattr(type(model), "center_data", Model.center_data)
+    has_center = (
+        getattr(type(model), "center_data", Model.center_data)
         is not Model.center_data
     )
 
     def center_keep(z: Array):
         return model.center_keep(constrain(z))
 
-    def center_at(z: Array, pe: Array, centre=None):
-        # pe = -(prior + lik): what is left when the prior's part goes is
-        # the whole mesh's log-likelihood term (0 where it is not finite)
-        c = pe + jax.vmap(prior_part)(z)
-        c = jax.lax.stop_gradient(jnp.where(jnp.isfinite(c), c, 0.0))
-        if not per_chain:
-            return c
-        return jax.lax.stop_gradient(jnp.concatenate(
-            [(centre[:, 0] + c)[:, None], jax.vmap(center_keep)(z)], axis=1))
+    def centering_of(rows: bool) -> "Centering":
+        """The model's `Centering` with one constant for the ensemble, or
+        (``rows``) a row a chain: the constant and what the model keeps
+        beside it (nothing, unless it says `center_per_chain`)."""
 
-    def centered_data(data: PyTree, centre: Array):
-        # each shard's sums take an even share of it off: rows dealt to
-        # shards at random differ by a few thousand nats, still small
-        # (one shard off the mesh)
-        from .parallel.primitives import mapped_axis_size
+        def center_at(z: Array, pe: Array, centre=None):
+            # pe = -(prior + lik): what is left when the prior's part goes
+            # is the whole mesh's log-likelihood term (0 where it is not
+            # finite)
+            c = pe + jax.vmap(prior_part)(z)
+            c = jax.lax.stop_gradient(jnp.where(jnp.isfinite(c), c, 0.0))
+            if not rows:
+                return c
+            return jax.lax.stop_gradient(jnp.concatenate(
+                [(centre[:, 0] + c)[:, None], jax.vmap(center_keep)(z)],
+                axis=1))
 
-        shards = lik_scale * mapped_axis_size(axis_name)
-        if per_chain:
+        def centered_data(data: PyTree, centre: Array):
+            # each shard's sums take an even share of it off: rows dealt to
+            # shards at random differ by a few thousand nats, still small
+            # (one shard off the mesh)
+            from .parallel.primitives import mapped_axis_size
+
+            shards = lik_scale * mapped_axis_size(axis_name)
+            if not rows:
+                return model.center_data(data, -centre / shards)
+            if not per_chain:  # the model's centre is the scalar alone
+                return model.center_data(data, -centre[0] / shards)
             return model.center_data(
                 data, jnp.concatenate([-centre[:1] / shards, centre[1:]]))
-        return model.center_data(data, -centre / shards)
+
+        return Centering(
+            center_at, centered_data,
+            # a row: the constant and what the model keeps beside it
+            1 + jax.eval_shape(center_keep, jnp.zeros((ndim,))).shape[0]
+            if rows else 0,
+        )
+
+    # the ensemble sampler centres over a data mesh, or where the model
+    # asks for a centre a chain; the per-chain kernels wherever the model
+    # can, each chain where it stands (an accept step compares a chain with
+    # itself alone)
+    centering = (
+        centering_of(per_chain)
+        if has_center and (axis_name is not None or per_chain) else None
+    )
+    chain_centering = (
+        (centering if per_chain else centering_of(True))
+        if has_center else None
+    )
 
     def init_flat(key: Array) -> Array:
         init = model.init_params(key)
@@ -439,10 +480,6 @@ def flatten_model(
         unconstrain=unconstrain,
         init_flat=init_flat,
         comm=comm,
-        centering=Centering(
-            center_at, centered_data,
-            # a row: the constant and what the model keeps beside it
-            1 + jax.eval_shape(center_keep, jnp.zeros((ndim,))).shape[0]
-            if per_chain else 0,
-        ) if centers else None,
+        centering=centering,
+        chain_centering=chain_centering,
     )

@@ -340,15 +340,27 @@ class FusedHierLogisticGrouped(HierLogistic):
         if "gl" not in data:  # fallback layout
             from ..ops.logistic_fused import logistic_offset_loglik
 
-            return logistic_offset_loglik(
+            ll = logistic_offset_loglik(
                 beta, alpha[data["g"]], data["xT"], data["y"]
             )
+            # no tiles to take a centre off: it comes off the total
+            return ll - data["ll_center"] if "ll_center" in data else ll
         from ..ops.hier_fused import hier_logistic_loglik
 
         return hier_logistic_loglik(
             beta, alpha, data["xT"], data["y"], data["gl"],
             data["first_gid"], data["k_loc"], data["lt128"],
+            data.get("ll_center"),
         )
+
+    def center_data(self, data, center):
+        """`Model.center_data`: the grouped kernel's tile sums take
+        ``center`` off before they are added
+        (`ops.logistic_fused._sum_tiles`).  The per-chain kernels ask for
+        it, a constant a chain (`sampler.ChainBlockKernel`); the one-chip
+        ensemble sampler sums the plain potential (``center_per_chain``
+        stays off)."""
+        return {**data, "ll_center": center}
 
 
 def synth_logistic_data(key, n, d, *, num_groups=0, dtype=jnp.float32):

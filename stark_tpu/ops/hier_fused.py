@@ -281,11 +281,15 @@ def _make_grouped_kernel(n, lane_tile, k_loc, link):
 
 
 def _grouped_call(beta, alpha, xt, y, gl, first_gid, *, k_loc, lane_tile,
-                  interpret, link="bernoulli_logit"):
+                  interpret, link="bernoulli_logit", center=None):
     """Chain-batched fused hierarchical pass.
 
     beta: (C, D), alpha: (C, G) -> (val (C,), gbeta (C, D),
-    galpha (C, G)).  C pads to a sublane multiple of 8.
+    galpha (C, G)).  C pads to a sublane multiple of 8.  ``center`` (C,):
+    a constant a chain; val comes back less it, taken off tile by tile
+    (`logistic_fused._sum_tiles`).  Nothing here multiplies the tiles'
+    totals by a scale of the position, so a constant is all a centre needs
+    (the Gaussian kernel's `_gauss_loglik` needs more).
     """
     interpret = _resolve_interpret(interpret)
     c, d = beta.shape
@@ -342,7 +346,9 @@ def _grouped_call(beta, alpha, xt, y, gl, first_gid, *, k_loc, lane_tile,
         interpret=interpret,
         name="stark_hier_ll_grouped",
     )(*args)
-    val = jnp.sum(out[0], axis=0)[:c, 0]
+    if center is not None:  # a row a chain, beside the tiles' (C, 1)
+        center = jnp.pad(center.astype(jnp.float32), (0, cpad - c))[:, None]
+    val = _sum_tiles(out[0], center)[:c, 0]
     gbeta = jnp.sum(out[1], axis=0)[:c]
     # windowed scatter-add of the per-tile partials: grid*K_LOC indices
     galpha = (
@@ -358,46 +364,50 @@ def _bcast(x, batched, axis_size):
 
 
 @functools.partial(jax.custom_batching.custom_vmap)
-def _vg_grouped(beta, alpha, xt, y, gl, first_gid, k_loc_arr, lt_arr):
+def _vg_grouped(beta, alpha, xt, y, gl, first_gid, k_loc_arr, lt_arr, center):
     # k_loc and lane_tile ride as shape-encoded dummies so they stay
-    # static through jit/vmap (lane_tile = 128 * lt_arr.shape[0])
+    # static through jit/vmap (lane_tile = 128 * lt_arr.shape[0]);
+    # ``center``: None, or the scalar the value comes back less
     val, gbeta, galpha = _grouped_call(
         beta[None], alpha[None], xt, y, gl, first_gid,
         k_loc=k_loc_arr.shape[0], lane_tile=128 * lt_arr.shape[0],
-        interpret=None,
+        interpret=None, center=None if center is None else center[None],
     )
     return val[0], gbeta[0], galpha[0]
 
 
 @_vg_grouped.def_vmap
 def _vg_grouped_vmap(axis_size, in_batched, beta, alpha, xt, y, gl,
-                     first_gid, k_loc_arr, lt_arr):
-    beta_b, alpha_b, xt_b, y_b, gl_b, fg_b, _, _ = in_batched
+                     first_gid, k_loc_arr, lt_arr, center):
+    beta_b, alpha_b, xt_b, y_b, gl_b, fg_b, _, _, center_b = in_batched
     if xt_b or y_b or gl_b or fg_b:
         out = jax.lax.map(
-            lambda a: _vg_grouped(*a, k_loc_arr, lt_arr),
+            lambda a: _vg_grouped(*a[:-1], k_loc_arr, lt_arr, a[-1]),
             tuple(
-                _bcast(v, b, axis_size)
+                v if v is None else _bcast(v, b, axis_size)
                 for v, b in zip(
-                    (beta, alpha, xt, y, gl, first_gid),
-                    (beta_b, alpha_b, xt_b, y_b, gl_b, fg_b),
+                    (beta, alpha, xt, y, gl, first_gid, center),
+                    (beta_b, alpha_b, xt_b, y_b, gl_b, fg_b, center_b),
                 )
             ),
         )
         return out, (True, True, True)
     beta = _bcast(beta, beta_b, axis_size)
     alpha = _bcast(alpha, alpha_b, axis_size)
+    if center is not None:  # one centre for all chains, or one a chain
+        center = _bcast(center, center_b, axis_size)
     return (
         _grouped_call(
             beta, alpha, xt, y, gl, first_gid, k_loc=k_loc_arr.shape[0],
-            lane_tile=128 * lt_arr.shape[0], interpret=None,
+            lane_tile=128 * lt_arr.shape[0], interpret=None, center=center,
         ),
         (True, True, True),
     )
 
 
 @jax.custom_vjp
-def hier_logistic_loglik(beta, alpha, xt, y, gl, first_gid, k_loc_arr, lt_arr):
+def hier_logistic_loglik(beta, alpha, xt, y, gl, first_gid, k_loc_arr, lt_arr,
+                         center=None):
     """Differentiable fused hierarchical Bernoulli-logit log-lik.
 
     One Pallas pass over group-sorted data yields the value, ∂/∂beta and
@@ -405,24 +415,27 @@ def hier_logistic_loglik(beta, alpha, xt, y, gl, first_gid, k_loc_arr, lt_arr):
     per-row LOCAL group ids, ``first_gid`` the per-tile group bases, and
     ``k_loc_arr`` a dummy (K_LOC,) array carrying the static window size
     in its shape (all three produced by `grouped_layout`).  Under vmap
-    over chains the ensemble shares ONE X pass.
+    over chains the ensemble shares ONE X pass.  ``center``: a scalar close
+    to the log-lik where the chain is (one a chain under vmap, still one X
+    pass); the value comes back less it, taken off tile by tile.  A
+    constant of the program: it gets no cotangent.
     """
     val, _, _ = _vg_grouped(
-        beta, alpha, xt, y, gl, first_gid, k_loc_arr, lt_arr
+        beta, alpha, xt, y, gl, first_gid, k_loc_arr, lt_arr, center
     )
     return val
 
 
-def _hier_fwd(beta, alpha, xt, y, gl, first_gid, k_loc_arr, lt_arr):
+def _hier_fwd(beta, alpha, xt, y, gl, first_gid, k_loc_arr, lt_arr, center):
     val, gbeta, galpha = _vg_grouped(
-        beta, alpha, xt, y, gl, first_gid, k_loc_arr, lt_arr
+        beta, alpha, xt, y, gl, first_gid, k_loc_arr, lt_arr, center
     )
     return val, (gbeta, galpha)
 
 
 def _hier_bwd(res, ct):
     gbeta, galpha = res
-    return ct * gbeta, ct * galpha, None, None, None, None, None, None
+    return (ct * gbeta, ct * galpha) + (None,) * 7
 
 
 hier_logistic_loglik.defvjp(_hier_fwd, _hier_bwd)

@@ -274,8 +274,8 @@ def _batched_call(beta, xt, y, offsets, *, lane_tile, interpret,
     beta: (C, D); y: (N,), or (1, N) float32 (`_y_operand`); offsets:
     (C, N) or None -> (val (C,), grad (C, D) [, resid (C, N)]).  C is
     padded to a sublane multiple of 8 for Mosaic tiling; padded rows are
-    discarded on return.  ``center`` (a scalar): val comes back less it
-    (`_sum_tiles`).
+    discarded on return.  ``center`` (a scalar, or (C,): a constant a
+    chain): val comes back less it (`_sum_tiles`).
     """
     interpret = _resolve_interpret(interpret)
     c, d = beta.shape
@@ -324,6 +324,8 @@ def _batched_call(beta, xt, y, offsets, *, lane_tile, interpret,
         interpret=interpret,
         name="stark_logistic_ll",
     )(*args)
+    if center is not None and jnp.ndim(center):  # beside the tiles' (C, 1)
+        center = jnp.pad(center.astype(jnp.float32), (0, cpad - c))[:, None]
     val = _sum_tiles(out[0], center)[:c, 0]
     grad = jnp.sum(out[1], axis=0)[:c]
     if offsets is not None:
@@ -419,8 +421,8 @@ def _make_vg_noff(link):
 
     @vg_noff.def_vmap
     def _vmap_rule(axis_size, in_batched, beta, xt, y, center):
-        beta_b, *rest_b = in_batched
-        if any(rest_b):  # batched data or centre: nothing to share
+        beta_b, xt_b, y_b, _ = in_batched
+        if xt_b or y_b:  # batched data: nothing to share
             out = jax.lax.map(
                 lambda a: vg_noff(*a),
                 tuple(
@@ -430,6 +432,7 @@ def _make_vg_noff(link):
             )
             return out, (True, True)
         beta = _bcast(beta, beta_b, axis_size)
+        # a batched ``center`` is a constant a chain: still one X pass
         return (
             _batched_call(
                 beta, xt, y, None, lane_tile=None, interpret=None, link=link,

@@ -27,7 +27,9 @@ def named_jit(fn, name: str, **jit_kwargs):
     module, the compile log), and a trace reduction that keys on it has
     to survive a refactor of the function behind it.  The names in use:
     ``stark_chees_init`` / ``stark_chees_warm`` / ``stark_chees_sample``
-    (the ensemble sampler's three programs), ``stark_stream_ess`` (the
+    (the ensemble sampler's three programs), ``stark_nuts_block`` /
+    ``stark_hmc_block`` (the per-chain kernels' draw block:
+    `sampler.block_program`), ``stark_stream_ess`` (the
     streaming gate's reduction of a block's accumulator to its ESS row)
     and ``stark_constrain`` (the final layout of all draws)."""
     import functools

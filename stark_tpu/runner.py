@@ -400,10 +400,14 @@ def _sample_until_converged(
         )
     with trace.phase("compile", stage="build"):
         ap = backend.adaptive_parts(model, cfg, data)
-        # the posterior's width, and whether the programs carry the
-        # potential's centre (`model.Centering`), on the call's `run` span
-        telemetry.note(root=True, ndim=ap.fm.ndim,
-                       centred=ap.fm.centering is not None and data is not None)
+        # the posterior's width, the sampler, and whether its programs
+        # carry the potential's centre (`model.Centering`: the ensemble's,
+        # or a row a chain under the per-chain kernels), on the call's
+        # `run` span
+        centering = (ap.fm.centering if ap.chees is not None
+                     else ap.fm.chain_centering)
+        telemetry.note(root=True, ndim=ap.fm.ndim, kernel=cfg.kernel,
+                       centred=centering is not None and data is not None)
 
     if sync_blocks is None:
         # multi-process meshes run serial: collect is a process_allgather
@@ -811,6 +815,34 @@ class _Block:
         return (time.perf_counter_ns() - self.wait_span.end_ns) / 1e9
 
 
+def _tree_counters(cfg, hb) -> dict:
+    """What a per-chain kernel's block cost, for the `block.gate` span, from
+    the gradient counts it hands over anyway (``HostBlock.ngrad``, chains x
+    transitions; nothing for the ensemble sampler): ``tree_leaves``, their
+    sum; ``divergent``; ``lane_iterations``, the longest tree of every
+    vmapped transition added up: the chains run a transition's loops in
+    lockstep until the last has finished (every round before a chain's last
+    builds its whole subtree, so the deepest chain is also the longest in
+    every round), which makes ``tree_leaves / (chains x lane_iterations)``
+    the share of lanes that did work; and for NUTS ``tree_depths``, the
+    transitions by trajectory depth 0..max_tree_depth
+    (`kernels.nuts.tree_depth_from_leaves`)."""
+    if hb.ngrad is None:
+        return {}
+    out = {
+        "tree_leaves": int(np.sum(hb.ngrad)),
+        "divergent": int(np.sum(hb.divergent)),
+        "lane_iterations": int(np.sum(np.max(hb.ngrad, axis=0))),
+    }
+    if cfg.kernel == "nuts":
+        from .kernels.nuts import tree_depth_from_leaves
+
+        out["tree_depths"] = np.bincount(
+            tree_depth_from_leaves(hb.ngrad).ravel(),
+            minlength=cfg.max_tree_depth + 1).tolist()
+    return out
+
+
 def _gate_block(run: _Run, b: _Block):
     """`block.gate`: the host's work on the block up to its record —
     health gate, draw persistence, streaming R-hat / ESS, stop
@@ -819,6 +851,7 @@ def _gate_block(run: _Run, b: _Block):
     gate_span = telemetry.span(
         "block.gate", block=b.blk, block_grad_evals=hb.grad_evals,
         **_psum_counters(run.ap.fm, run.chains, run.backend),
+        **_tree_counters(run.cfg, hb),
     ).open()
     if run.health_check:
         # poisoned state must never reach the checkpoint (the supervisor

@@ -32,7 +32,13 @@ from .adaptation import (
     welford_update,
     welford_variance,
 )
-from .kernels.base import HMCState, init_state
+from .kernels.base import (
+    CentredState,
+    HMCState,
+    chain_potential,
+    chain_recentred,
+    init_state,
+)
 from .kernels.hmc import hmc_step
 from .kernels.nuts import nuts_step
 from .model import FlatModel, Model, Potential, flatten_model
@@ -57,6 +63,9 @@ class SamplerConfig:
     # `chees.make_chees_parts`, not by the per-chain vmapped runner):
     init_traj_length: Optional[float] = None
     max_leapfrog: int = 1000
+    # Adam steps toward the mode from every chain's start, before warm-up
+    # (`chees.map_descent`): the ensemble sampler's start, and the
+    # per-chain kernels' under the adaptive runner (`ChainBlockKernel`)
     map_init_steps: int = 0
     # telemetry opt-in: emit a jit-safe in-loop heartbeat (device -> host
     # via jax.debug.callback) every N transitions inside the compiled
@@ -129,7 +138,8 @@ def _make_warmup_body(cfg: SamplerConfig, kernel):
                 da = _tree_select(
                     window_end_f, da_init(jnp.exp(da.log_step)), da
                 )
-        return (state, da, welford, inv_mass), info.is_divergent
+        return (state, da, welford, inv_mass), (
+            info.is_divergent, info.num_grad_evals)
 
     return body
 
@@ -167,7 +177,7 @@ def make_warmup_fn(fm: FlatModel, cfg: SamplerConfig):
         carry = _warmup_carry_init(cfg, potential_fn, key_find, state)
         if cfg.num_warmup > 0:
             keys = jax.random.split(key_scan, cfg.num_warmup)
-            carry, divergent = jax.lax.scan(
+            carry, (divergent, _) = jax.lax.scan(
                 _make_warmup_body(cfg, kernel),
                 carry,
                 (keys, adapt_mass_flags, window_end_flags),
@@ -197,28 +207,44 @@ def make_warmup_parts(fm: FlatModel, cfg: SamplerConfig):
 
       init_carry(key, z0, data) -> (state, da, welford, inv_mass)
       segment(keys, adapt_flags, wend_flags, state, da, welford, inv_mass,
-              data) -> (state, da, welford, inv_mass, n_div)
+              data) -> (state, da, welford, inv_mass, n_div, n_grad)
       finalize(da) -> step_size            (host-side, cheap)
 
-    Slice ``build_warmup_schedule(cfg.num_warmup)`` flags to feed segments.
+    ``state`` is what the chain carries (`chain_potential`): an `HMCState`,
+    or for a model that can centre a `CentredState`, whose centre every
+    segment retakes where the chain stands (`chain_recentred`; the gradient
+    that costs is in ``n_grad``).  Slice
+    ``build_warmup_schedule(cfg.num_warmup)`` flags to feed segments.
     """
     step_kernel = make_kernel(cfg)
 
     def init_carry(key, z0, data=None):
-        potential_fn = fm.bind(data)
-        state = init_state(potential_fn, z0)
-        return _warmup_carry_init(cfg, potential_fn, key, state)
+        state = init_state(fm.bind(data), z0)
+        cen = getattr(fm, "chain_centering", None)
+        if cen is not None and data is not None:
+            # the plain evaluation places the centre; `chain_recentred`
+            # evaluates again relative to it
+            state = chain_recentred(
+                fm, data, CentredState(state, cen.zero(1)[0]))
+        potential_fn, state, rewrap = chain_potential(fm, data, state)
+        state, da, welford, inv_mass = _warmup_carry_init(
+            cfg, potential_fn, key, state)
+        return rewrap(state), da, welford, inv_mass
 
     def segment(keys, adapt_flags, wend_flags, state, da, welford, inv_mass,
                 data=None):
-        potential_fn = fm.bind(data)
+        carried = chain_recentred(fm, data, state)
+        potential_fn, state, rewrap = chain_potential(fm, data, carried)
         kernel = partial(step_kernel, potential_fn=potential_fn)
-        (state, da, welford, inv_mass), divergent = jax.lax.scan(
+        (state, da, welford, inv_mass), (divergent, ngrad) = jax.lax.scan(
             _make_warmup_body(cfg, kernel),
             (state, da, welford, inv_mass),
             (keys, adapt_flags, wend_flags),
         )
-        return state, da, welford, inv_mass, jnp.sum(divergent.astype(jnp.int32))
+        # `chain_recentred`'s evaluation, where there is a centre to move
+        n_grad = jnp.sum(ngrad) + int(isinstance(carried, CentredState))
+        return (rewrap(state), da, welford, inv_mass,
+                jnp.sum(divergent.astype(jnp.int32)), n_grad)
 
     def finalize(da):
         if cfg.adapt_step_size:
@@ -233,7 +259,9 @@ def drive_segmented_warmup(cfg, v_init, v_seg, finalize, warm_keys, z0, data,
     """The ONE host-side schedule driver over compiled warmup segments.
 
     ``v_init(keys, z0, data)`` and ``v_seg(keys, aflags, wflags, state, da,
-    welford, inv_mass, data)`` are the chain-vmapped warmup parts — plain
+    welford, inv_mass, data)`` are the chain-vmapped warmup parts (-> the
+    state, the step size, the mass, and the chains' divergences and
+    gradient evaluations) — plain
     jitted on one device (``make_segmented_warmup``) or shard_mapped over a
     mesh (``ShardedBackend``); the schedule slicing and key layout live
     here so the two execution paths cannot drift.
@@ -264,26 +292,43 @@ def drive_segmented_warmup(cfg, v_init, v_seg, finalize, warm_keys, z0, data,
             ),
             (1, 0, 2),
         )
-    warm_div = None  # accumulated on device (chains-sharded under a mesh)
+    counts = None  # accumulated on device (chains-sharded under a mesh)
     for s in range(0, cfg.num_warmup, seg):
         e = min(s + seg, cfg.num_warmup)
-        with trace.phase("warmup_block", start=s, end=e):
-            state, da, welford, inv_mass, ndiv = jax.block_until_ready(
+        with trace.phase("warmup_block", start=s, end=e) as ph:
+            state, da, welford, inv_mass, ndiv, ngrad = jax.block_until_ready(
                 v_seg(wkeys[s:e], jnp.asarray(aflags[s:e]),
                       jnp.asarray(wflags[s:e]), state, da, welford, inv_mass,
                       data)
             )
+            if ngrad.is_fully_addressable:  # one process holds every chain
+                ph.note(steps=e - s, grad_evals=int(np.sum(ngrad)))
         telemetry.notify_progress()  # watchdog liveness beat per segment
-        warm_div = ndiv if warm_div is None else warm_div + ndiv
-    if warm_div is None:
-        warm_div = jnp.zeros((warm_keys.shape[0],), jnp.int32)
-    return state, finalize(da), inv_mass, warm_div
+        counts = (ndiv, ngrad) if counts is None else (
+            counts[0] + ndiv, counts[1] + ngrad)
+    if counts is None:
+        counts = (jnp.zeros((warm_keys.shape[0],), jnp.int32),) * 2
+    return state, finalize(da), inv_mass, counts
+
+
+def make_map_init(fm: FlatModel, cfg: SamplerConfig):
+    """``map_init(z0 (chains, d), data) -> z0``: the configured MAP descent of
+    every chain's start (`chees.map_descent` on the plain potential: only its
+    gradient is used), for a backend to compile; None where
+    ``map_init_steps`` is 0, so that a run without one compiles nothing."""
+    if cfg.map_init_steps <= 0:
+        return None
+    from .chees import map_descent
+
+    return lambda z0, data=None: map_descent(
+        fm.bind(data), z0, cfg.map_init_steps)
 
 
 def make_segmented_warmup(fm: FlatModel, cfg: SamplerConfig):
     """Single-device segmented warmup: jit+vmap the warmup parts, return
     ``run(warm_keys, z0, data, seg) -> (state, step_size, inv_mass,
-    warm_div device (chains,))`` driven by ``drive_segmented_warmup``.
+    (warm_div, warm_grad) device (chains,))`` driven by
+    ``drive_segmented_warmup``.
 
     Used by JaxBackend._run_segmented and the adaptive runner; the sharded
     backend builds shard_mapped parts and shares the same driver.
@@ -387,13 +432,24 @@ def make_chain_runner(fm: FlatModel, cfg: SamplerConfig):
     return run
 
 
+def block_program(cfg: SamplerConfig) -> str:
+    """The fixed name of the per-chain kernels' block program
+    (`platform.named_jit`): ``jit_stark_nuts_block`` / ``jit_stark_hmc_block``
+    on the profiler's modules line, as ``jit_stark_chees_sample`` is the
+    ensemble sampler's."""
+    return f"stark_{cfg.kernel}_block"
+
+
 def make_block_runner(fm: FlatModel, cfg: SamplerConfig, block_size: int,
                       diag_lags: Optional[int] = None,
                       ragged: bool = False):
     """One draw block for the segmented/adaptive drivers, jit/vmap-able
     per chain:
       block_run(key, state, step_size, inv_mass, data)
-        -> (HMCState, zs, accept, divergent, energy, ngrad)
+        -> (state, zs, accept, divergent, energy, ngrad)
+
+    ``state`` is an `HMCState`, or for a model that can centre a
+    `CentredState` (`chain_potential`), in and out.
 
     Control crosses host<->device once per BLOCK (SURVEY.md §4: "periodic
     async draw fetch + convergence check"), which is how wall-clock-to-
@@ -446,11 +502,10 @@ def make_block_runner(fm: FlatModel, cfg: SamplerConfig, block_size: int,
         plain block; the streaming accumulator is threaded through the
         carry otherwise.  One body so the transitions cannot drift
         between the stream-on and stream-off compiled programs."""
-        potential_fn = fm.bind(data)
+        # ``state`` is what the chain carries: an `HMCState` with pe and
+        # grad, or a `CentredState`, whose centre the block keeps
+        potential_fn, state, rewrap = chain_potential(fm, data, state)
         kernel = partial(step_kernel, potential_fn=potential_fn)
-        # state was checkpointed/carried as raw arrays; rebuild gradient
-        # lazily only if absent is not possible under jit, so the carried
-        # state must include pe/grad (it does — HMCState is the carry).
 
         def body(carry, x):
             state, diag = carry
@@ -474,7 +529,8 @@ def make_block_runner(fm: FlatModel, cfg: SamplerConfig, block_size: int,
 
         keys = jax.random.split(key, block_size)
         xs = (jnp.arange(block_size), keys) if tick is not None else keys
-        return jax.lax.scan(body, (state, diag), xs)
+        (state, diag), outs = jax.lax.scan(body, (state, diag), xs)
+        return (rewrap(state), diag), outs
 
     def block_run(key, state, step_size, inv_mass, data=None):
         (state, _), (zs, accept, divergent, energy, ngrad) = _block_scan(
@@ -499,7 +555,12 @@ class ChainBlockKernel:
     both NUTS schedulers; with and without the streaming-diagnostics
     carry): every chain carries its own state, step size and mass through
     the backend's compiled blocks (`make_block_runner`), and warm-up is the
-    segmented driver's (`drive_segmented_warmup`)."""
+    segmented driver's (`drive_segmented_warmup`).  For a model that can
+    centre, ``state`` is a `CentredState`: each chain's energies relative
+    to the centre beside them (`kernels.base.chain_potential`), which
+    warm-up's segments move and sampling keeps; a checkpoint's ``pe`` is
+    the potential itself, in float64
+    (`backends.base.checkpoint_potential`)."""
 
     def __init__(self, ap, cfg: SamplerConfig, chains: int, env):
         self.ap, self.cfg, self.chains, self.env = ap, cfg, chains, env
@@ -522,11 +583,19 @@ class ChainBlockKernel:
                 ap.get_block(env.block_size, ragged=True)
             except TypeError:
                 self._ragged = False
+        # the carries' `model.Centering`, None where they hold no centre
+        self._centering = (
+            ap.fm.chain_centering if ap.data is not None else None)
         self.state = self.step_size = self.inv_mass = None
 
     @property
+    def _hmc(self) -> HMCState:
+        """The chains' states, whatever carries them."""
+        return self.state if self._centering is None else self.state.state
+
+    @property
     def dtype(self):
-        return np.dtype(self.state.z.dtype)
+        return np.dtype(self._hmc.z.dtype)
 
     def _block(self, length):
         """Compiled block runner for ``length`` transitions (the backend
@@ -554,25 +623,43 @@ class ChainBlockKernel:
             z0 = ap.put_chains(z0)
             warm_keys = ap.put_chains(jax.random.split(key_warm, chains))
             jax.block_until_ready(z0)
+        map_steps = self.cfg.map_init_steps if ap.map_init is not None else 0
+        if map_steps:
+            # the descent toward the mode, as the ensemble sampler's start
+            # has it: its compile counters say how much was compilation
+            with env.trace.phase("compile", stage="init+map",
+                                 map_init_steps=map_steps):
+                with telemetry.span("map_init", steps=map_steps,
+                                    grad_evals=map_steps * chains):
+                    z0 = jax.block_until_ready(ap.map_init(z0, ap.data))
         # warm-up runs as block_size-bounded dispatches too (checkpointable,
         # beats the watchdog); the segmented driver reads the ambient trace,
         # which the public wrapper pinned to THIS run's
-        with telemetry.span("warmup", steps=self.cfg.num_warmup):
-            self.state, self.step_size, self.inv_mass, n_div = ap.seg_warmup(
+        with telemetry.span("warmup", steps=self.cfg.num_warmup) as warm_span:
+            self.state, self.step_size, self.inv_mass, counts = ap.seg_warmup(
                 warm_keys, z0, ap.data, env.block_size)
             # per-chain counts are chain-sharded
-            n_div = ap.collect(n_div)
-        return key, n_div, {}
+            n_div, n_grad = ap.collect(counts)
+            n_grad = int(np.sum(n_grad))
+            warm_span.note(grad_evals=n_grad)
+        # gradient evaluations spent before sampling: the MAP descent (one
+        # a step a chain), warm-up's leaves (or leapfrogs) and the centre's
+        # evaluations, all chains
+        return key, n_div, {
+            "warmup_grad_evals": n_grad + map_steps * chains}
 
     def restore(self, arrays, meta, reseed):
-        from .backends.base import restored_key
+        from .backends.base import carried_potential, restored_key
 
         # checkpoints are host numpy; per-chain kernels carry per-chain
         # step/mass: everything goes on the chains layout
-        put = lambda name: self.ap.put_chains(  # noqa: E731
-            jnp.asarray(arrays[name]))
-        self.state = HMCState(put("z"), put("pe"), put("grad"))
-        self.step_size, self.inv_mass = put("step_size"), put("inv_mass")
+        put = lambda a: self.ap.put_chains(jnp.asarray(a))  # noqa: E731
+        pe, centre = carried_potential(arrays, self._centering)
+        self.state = HMCState(put(arrays["z"]), put(pe), put(arrays["grad"]))
+        if centre is not None:
+            self.state = CentredState(self.state, put(centre))
+        self.step_size = put(arrays["step_size"])
+        self.inv_mass = put(arrays["inv_mass"])
         self.chains = arrays["z"].shape[0]
         return restored_key(arrays, "key", reseed), None, None
 
@@ -588,13 +675,14 @@ class ChainBlockKernel:
         ))
         # per-chain kernels CARRY the (possibly poisoned) state into the
         # next dispatch — same rebinding as the serial loop
-        st = self.state = faults.poison("runner.carried_nan", out.pop(0))
+        self.state = faults.poison("runner.carried_nan", out.pop(0))
         if self.stream_diag:
             diag = out.pop(0)
         # what is left: zs, accept, divergent, energy, ngrad[, lane_iters]
         return PendingBlock(
             length, tuple(out), diag,
-            carried_state(st, self.step_size, self.inv_mass),
+            carried_state(self._hmc, self.step_size, self.inv_mass),
+            extras=None if self._centering is None else self.state.center,
         )
 
     def host_block(self, pending, energy=False):
@@ -624,7 +712,13 @@ class ChainBlockKernel:
         )
 
     def checkpoint_arrays(self, pending):
-        arrays = self.ap.collect(dict(pending.carried))
+        from .backends.base import checkpoint_potential
+
+        named = dict(pending.carried)
+        if pending.extras is not None:
+            named["pe_center"] = pending.extras
+        arrays = checkpoint_potential(
+            self.ap.collect(named), self._centering)
         arrays["key"] = np.asarray(pending.key)  # as of this block's split
         return arrays
 
@@ -654,7 +748,8 @@ def drive_segmented_sampling(fm: FlatModel, cfg: SamplerConfig, seg_warmup,
     chains = z0.shape[0]
     keys = jax.vmap(lambda k: jax.random.split(k, 2))(chain_keys)
     warm_keys, sample_keys = keys[:, 0], keys[:, 1]
-    state, step_size, inv_mass, warm_div = seg_warmup(warm_keys, z0, data, seg)
+    state, step_size, inv_mass, (warm_div, _) = seg_warmup(
+        warm_keys, z0, data, seg)
     warm_div = np.asarray(collect(warm_div))
 
     total = cfg.num_samples * cfg.thin

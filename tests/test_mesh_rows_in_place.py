@@ -44,8 +44,19 @@ def sharded_rows(mesh, host_rows):
     }
 
 
+def _mark():
+    """The newest span there is: the log is a bounded deque, so a position
+    in it says nothing once a worker has closed more spans than it holds."""
+    log = telemetry.span_log()
+    return log[-1] if log else None
+
+
 def _spans(name, since):
-    return [r for r in telemetry.span_log()[since:] if r.name == name]
+    """The spans called `name` closed after the record `_mark` gave."""
+    log = telemetry.span_log()
+    start = next((i + 1 for i in range(len(log) - 1, -1, -1)
+                  if log[i] is since), 0)
+    return [r for r in log[start:] if r.name == name]
 
 
 def test_prepare_keeps_sharded_rows_on_their_devices(mesh, sharded_rows):
@@ -81,7 +92,7 @@ def test_prepare_cuts_y_lanes_with_the_rows_of_xT(mesh, sharded_rows):
 def test_shard_data_moves_nothing_that_is_placed(mesh, sharded_rows):
     model = FusedLogistic(D)
     data = prepare_model_data(model, sharded_rows)
-    since = len(telemetry.span_log())
+    since = _mark()
     out = shard_data(data, mesh, "data",
                      row_axes=model.data_shard_row_axes(data))
     (sp,) = _spans("shard_data", since)
@@ -93,7 +104,7 @@ def test_shard_data_moves_nothing_that_is_placed(mesh, sharded_rows):
 
 
 def test_shard_data_counts_what_it_moves_from_the_host(mesh, host_rows):
-    since = len(telemetry.span_log())
+    since = _mark()
     out = shard_data(host_rows, mesh, "data")
     (sp,) = _spans("shard_data", since)
     assert sp.fields["moved_bytes"] == sp.fields["bytes"] == N * D * 4 + N * 4
@@ -106,7 +117,7 @@ def test_shard_data_counts_what_it_moves_from_the_host(mesh, host_rows):
 
 def test_adaptive_parts_leaves_no_leaf_on_one_device(mesh, sharded_rows):
     model = FusedLogistic(D)
-    since = len(telemetry.span_log())
+    since = _mark()
     ap = ShardedBackend(mesh).adaptive_parts(
         model, SamplerConfig(kernel="chees"), sharded_rows)
     assert _spans("shard_data", since)[0].fields["moved_bytes"] == 0

@@ -10,27 +10,9 @@ a reader learns it here and not from the driver.  One file on purpose: under
 
 import glob
 import importlib.util
-import json
 import os
-import subprocess
-import sys
-
-import pytest
 
 _ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-
-#: PR 35: `onchip/tests/test_lmm.py` pins `diag_mb_per_block` of the LMM's
-#: dry run at the size of the streaming accumulator (8 chains x 44
-#: coordinates x 153 rows), which the gate fetched until the device reduced
-#: it to its ESS row (`runner._dispatch_next`).  The file is the
-#: benchmark's and not a `perf_opt` PR's to edit; the test below holds the
-#: same run to the new number.  Strict: the `benchmark` PR that corrects the
-#: assertion takes this entry out.
-_STALE = {
-    "test_lmm_dry_run_comes_out_correct":
-        "pins the gate's fetch at the accumulator's size (PR 32); a "
-        "benchmark PR's to correct: 44 x 4 B + 8 x 4 B since PR 35",
-}
 
 _TESTS = os.path.join(_ROOT, "onchip", "tests")
 
@@ -46,29 +28,4 @@ for _path in sorted(glob.glob(os.path.join(_TESTS, "test_*.py"))):
         # two files with a test of one name would silently lose one
         if _k.startswith("test_") and _k in globals():
             raise ImportError(f"onchip/tests: {_k} is defined twice")
-        if _k in _STALE:
-            _v = pytest.mark.xfail(reason=_STALE[_k], strict=True)(_v)
         globals()[_k] = _v
-
-
-def test_lmm_dry_run_gate_fetches_the_ess_row():
-    """`test_lmm_dry_run_comes_out_correct`'s run and assertions, with what
-    the gate fetches since PR 35: the ESS row (44 float32) and the draw
-    counts of 8 chains, a block."""
-    cell = "lmm_n49m.sample"
-    p = subprocess.run(
-        [sys.executable, os.path.join(_ROOT, "onchip", "run.py"),
-         "--workload", cell, "--seed", str(2**31 + 404), "--seconds", "2",
-         "--trace", "1", "--dry-run"], cwd=_ROOT,
-        env=dict(os.environ, JAX_PLATFORMS="cpu"), capture_output=True,
-        text=True, timeout=900)
-    assert p.returncode == 0, p.stderr[-2000:]
-    line = json.loads(p.stdout.strip().splitlines()[-1])
-    assert line["correct"] is True, line["compared"]
-    assert line["attempted"] > 0 and line["failed"] == 0
-    with open(os.path.join(_ROOT, "onchip", "workloads", cell + ".json")) as f:
-        checks = json.load(f)["checks"]
-    assert [c[0] for c in line["compared"]] == list(checks)
-    assert line["metrics"]["compiles_in_window"]["value"] == 0
-    assert line["metrics"]["diag_mb_per_block"]["value"] == pytest.approx(
-        (44 * 4 + 8 * 4) / 1e6)
