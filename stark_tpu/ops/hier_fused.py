@@ -205,7 +205,7 @@ def prepare_grouped(data, d_eff, transpose_keys=("x",)):
 
 
 def _check_chain_vmem(cpad, lane_tile, interpret, k_loc=0, q=1, slab_rows=0,
-                      chain_slabs=3, transposed=0, budget=10 * 1024 * 1024):
+                      transposed=0, budget=10 * 1024 * 1024):
     """The kernel holds ~3 (C, TILE) f32 intermediates (logits, resid,
     value terms) in scoped VMEM; past ~16 MB Mosaic refuses to compile
     (measured: C=128 at TILE=8192 asked for 20 MB).  The grouped kernels
@@ -213,8 +213,8 @@ def _check_chain_vmem(cpad, lane_tile, interpret, k_loc=0, q=1, slab_rows=0,
     per-tile (C, Q*K_LOC) group window (ADVICE r3: a small-C /
     large-K_LOC config could OOM past the C-only estimate), and the
     hierarchical kernel a stacked copy of the design slab and the one-hot
-    (``slab_rows`` = D + K_LOC).  ``chain_slabs`` is the number of live
-    (C, TILE)s where it is not three; ``transposed`` counts the (TILE, k)
+    (``slab_rows`` = D + K_LOC, and D + Q*K_LOC for the Gaussian kernel's
+    weighted one-hots).  ``transposed`` counts the (TILE, k)
     operands a kernel transposes for a dot over the lanes: each is laid
     out in tiles of 128 lanes whatever k is (4 MB at TILE 8192).  The
     default ``budget`` is conservative (the OOM had >3 live (C, TILE)s);
@@ -223,7 +223,7 @@ def _check_chain_vmem(cpad, lane_tile, interpret, k_loc=0, q=1, slab_rows=0,
     if interpret:
         return
     need = (
-        chain_slabs * cpad * lane_tile * 4  # (C, TILE) logits/resid/val terms
+        3 * cpad * lane_tile * 4        # (C, TILE) logits/resid/val terms
         + 2 * k_loc * lane_tile * 4     # (K_LOC, TILE) one-hot + iota
         + slab_rows * lane_tile * 4     # (D + K_LOC, TILE) stacked slab
         + cpad * q * k_loc * 4          # (C, Q*K_LOC) group window block
@@ -446,17 +446,25 @@ hier_logistic_loglik.defvjp(_hier_fwd, _hier_bwd)
 # slopes, 10k groups; over configs/lmm.yaml's 100k rows, ~10 rows/group,
 # grouped_layout shrinks the lane tile to 1024 until each tile's window
 # fits; over the on-chip cell's 81.9M rows it keeps 8192).  The kernel
-# computes mu = intercept + X·beta + Σ_q z_q ⊙ (u_q-window @ onehot)
-# entirely in-register and emits SSR, Σresid, X·resid and the per-tile
-# windowed u-gradient partials; sigma stays outside (scale-free kernel,
-# like ops/logistic_fused.py's gaussian link).
+# computes mu = intercept + X·beta + Σ_q z_q ⊙ u_q[g] entirely in-register
+# and emits SSR, Σresid, X·resid and the per-tile windowed u-gradient
+# partials; sigma stays outside (scale-free kernel, like
+# ops/logistic_fused.py's gaussian link).  As in the hierarchical kernel the
+# windows ride in the design slab: (u_q @ onehot) ⊙ z_q is u_q @ (onehot ⊙
+# z_q), so [beta | u_1 window | ... | u_Q window] against [X ; onehot ⊙ z_1 ;
+# ... ; onehot ⊙ z_Q] is one dot forward and its transpose one backward.  A
+# dot a random effect each way (2·Q + 2 dots of contraction 8 at `highest`,
+# each as many MXU passes over the (C, TILE) block) spilled half the
+# schedule: at the on-chip cell's shapes 14 522 bundles a tile and 101 ms a
+# call, folded 3 312 and 23.4 ms (a compile for a described v5e and my chip
+# run, PR 39: PERF.md §5).
 
 # What the kernel may ask of the core's VMEM (128 MiB on a v5e).  Mosaic's
 # default scoped limit is 16 MB, and at the lane tile the layout picks for
-# D + Q = 10 (8192) the chip's compiler asks 20.2 MB for C = 16, D = 8,
-# Q = 2, K_LOC = 8 (a compile for a described v5e, PR 32): three dots
-# contract over the lanes, and their transposed operands ``xt.T`` and
-# ``onehot.T`` take 4 MB each (`_check_chain_vmem`).
+# D + Q = 10 (8192) the chip's compiler asked 20.2 MB for C = 16, D = 8,
+# Q = 2, K_LOC = 8 when each random effect had its own dots (a compile for a
+# described v5e, PR 32).  The one dot over the lanes transposes the
+# (D + Q*K_LOC, TILE) slab, 4 MB in tiles of 128 lanes (`_check_chain_vmem`).
 _LMM_VMEM_LIMIT = 48 * 1024 * 1024
 
 
@@ -471,38 +479,32 @@ def _make_grouped_lmm_kernel(n, lane_tile, k_loc, q):
         zt = jnp.where(mask, zt_ref[...].astype(jnp.float32), 0.0)  # (Q, TILE)
         y = jnp.where(mask, y_ref[...], 0.0)  # (1, TILE)
         gl = jnp.where(mask, gl_ref[...], 0)  # (1, TILE)
-        beta = beta_ref[...]  # (C, D)
-        ic = ic_ref[...]  # (C, 1)
-        u = u_ref[0]  # (C, Q*K_LOC) — per-q windows side by side
         krows = jax.lax.broadcasted_iota(jnp.int32, (k_loc, lane_tile), 0)
         onehot = jnp.where(krows == gl, 1.0, 0.0)  # (K_LOC, TILE)
-        mu = ic + jax.lax.dot(
-            beta, xt, precision=prec,
+        # the windows ride in the design slab, each one-hot weighted by its
+        # random effect's covariate (exact: z times 1 or 0), in the order
+        # u_ref lays the q-windows side by side
+        slab = jnp.concatenate(
+            [xt] + [onehot * zt[j : j + 1, :] for j in range(q)], axis=0
+        )  # (D + Q*K_LOC, TILE)
+        params = jnp.concatenate(
+            [beta_ref[...], u_ref[0]], axis=1
+        )  # (C, D + Q*K_LOC) — beta resident, this tile's windows
+        mu = ic_ref[...] + jax.lax.dot(
+            params, slab, precision=prec,
             preferred_element_type=jnp.float32,
         )  # (C, TILE)
-        for j in range(q):  # static unroll: Q is 2-3
-            uq = u[:, j * k_loc : (j + 1) * k_loc]  # (C, K_LOC)
-            mu = mu + jax.lax.dot(
-                uq, onehot, precision=prec,
-                preferred_element_type=jnp.float32,
-            ) * zt[j : j + 1, :]
         resid = jnp.where(mask, y - mu, 0.0)  # (C, TILE)
         ssr = jnp.sum(resid * resid, axis=1)  # (C,)
         sresid = jnp.sum(resid, axis=1)  # (C,) — the intercept gradient
         acc_ref[...] = jnp.stack([ssr, sresid], axis=-1)[None]  # (1, C, 2)
-        gbeta_ref[...] = jax.lax.dot(
-            resid, xt.T, precision=prec,
+        grads = jax.lax.dot(
+            resid, slab.T, precision=prec,
             preferred_element_type=jnp.float32,
-        )[None]
-        parts = [
-            jax.lax.dot(
-                resid * zt[j : j + 1, :], onehot.T,
-                precision=prec,
-                preferred_element_type=jnp.float32,
-            )
-            for j in range(q)
-        ]
-        gu_ref[...] = jnp.concatenate(parts, axis=-1)[None]  # (1, C, Q*K_LOC)
+        )  # (C, D + Q*K_LOC): [beta partial | windowed u partials]
+        d = xt.shape[0]
+        gbeta_ref[...] = grads[:, :d][None]  # (1, C, D)
+        gu_ref[...] = grads[:, d:][None]  # (1, C, Q*K_LOC)
 
     return kernel
 
@@ -518,10 +520,9 @@ def _grouped_lmm_call(beta, u, intercept, xt, zt, y, gl, first_gid, *,
     n = xt.shape[1]
     grid = -(-n // lane_tile)
     cpad = -(-c // 8) * 8
-    # (C, TILE)s: mu, resid, a dot's result and a weighted resid a random
-    # effect; transposed: xt.T and an onehot.T a random effect
+    # (C, TILE)s: mu, resid and its square; transposed: the slab
     _check_chain_vmem(cpad, lane_tile, interpret, k_loc=k_loc, q=q,
-                      slab_rows=d + q, chain_slabs=3 + q, transposed=1 + q,
+                      slab_rows=d + q * k_loc, transposed=1,
                       budget=_LMM_VMEM_LIMIT // 2)
     if cpad != c:
         beta = jnp.pad(beta, ((0, cpad - c), (0, 0)))

@@ -15,7 +15,9 @@ before it could write it
 from the repo's root with `PYTHONPATH=.` and, as `conftest.py` sets them for
 the tests, `JAX_PLATFORMS=cpu STARK_PROFILE=0
 XLA_FLAGS=--xla_force_host_platform_device_count=8`: with one host device the
-draws differ in their last digits).
+draws differ in their last digits).  Its next block's draws were written again
+by PR 39's tree from the same checkpoint: the grouped Gaussian kernel there
+sums in another order, and 62 of the 736 draws moved by one float32 ulp.
 """
 
 import os

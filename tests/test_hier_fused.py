@@ -169,15 +169,11 @@ def _count_primitive(jaxpr, name):
     return count
 
 
-def test_grouped_kernel_body_holds_two_dots():
-    """One contraction forward and one backward a tile: at `highest` every
-    further dot costs six MXU passes over the whole (C, TILE) block, however
-    few of the array's rows it uses (the four-dot form ran at 7 % of the
-    roofline: PERF.md, PR 27)."""
+def _hier_kernel_jaxpr():
     from stark_tpu.ops.hier_fused import _grouped_call
 
     n, d, chains, groups, k_loc, lane_tile = 1024, 8, 8, 40, 24, 512
-    jaxpr = jax.make_jaxpr(
+    return jax.make_jaxpr(
         lambda b, a, xt, y, gl, fg: _grouped_call(
             b, a, xt, y, gl, fg, k_loc=k_loc, lane_tile=lane_tile,
             interpret=True,
@@ -187,9 +183,95 @@ def test_grouped_kernel_body_holds_two_dots():
         jnp.zeros((d, n)), jnp.zeros((n,)), jnp.zeros((n,), jnp.int32),
         jnp.zeros((n // lane_tile,), jnp.int32),
     ).jaxpr
+
+
+def _lmm_kernel_jaxpr(q):
+    from stark_tpu.ops.hier_fused import _grouped_lmm_call
+
+    n, d, chains, groups, k_loc, lane_tile = 1024, 8, 16, 40, 8, 512
+    return jax.make_jaxpr(
+        lambda b, u, ic, xt, zt, y, gl, fg: _grouped_lmm_call(
+            b, u, ic, xt, zt, y, gl, fg, k_loc=k_loc, lane_tile=lane_tile,
+            interpret=True,
+        )
+    )(
+        jnp.zeros((chains, d)), jnp.zeros((chains, groups, q)),
+        jnp.zeros((chains,)), jnp.zeros((d, n)), jnp.zeros((q, n)),
+        jnp.zeros((n,)), jnp.zeros((n,), jnp.int32),
+        jnp.zeros((n // lane_tile,), jnp.int32),
+    ).jaxpr
+
+
+@pytest.mark.parametrize("kernel, q", [
+    ("stark_hier_ll_grouped", None),
+    ("stark_lmm_ll_grouped", 1),
+    ("stark_lmm_ll_grouped", 2),
+    ("stark_lmm_ll_grouped", 3),
+])
+def test_grouped_kernel_body_holds_two_dots(kernel, q):
+    """One contraction forward and one backward a tile, at every number of
+    random effects: at `highest` every further dot costs six MXU passes over
+    the whole (C, TILE) block, however few of the array's rows it uses (the
+    four-dot hier form ran at 7 % of the roofline: PERF.md, PR 27; the LMM's
+    2·Q + 2 dots spilled half its schedule: PR 39)."""
+    jaxpr = _hier_kernel_jaxpr() if q is None else _lmm_kernel_jaxpr(q)
     (call,) = [e for e in jaxpr.eqns if e.primitive.name == "pallas_call"]
-    assert call.params["name"] == "stark_hier_ll_grouped"
+    assert call.params["name"] == kernel
     assert _count_primitive(call.params["jaxpr"], "dot_general") == 2
+
+
+@pytest.mark.parametrize("q", [1, 2, 3])
+def test_lmm_kernel_matches_plain_sums(q, monkeypatch):
+    """`_grouped_lmm_call` in interpret mode against plain jax.numpy at
+    `highest` on the same group-sorted rows: the tiles' sums of squares,
+    sum resid, X·resid and the u-gradient, at a ragged last tile, five chains
+    (padded to eight) and groups that straddle tiles."""
+    from stark_tpu.ops.hier_fused import _grouped_lmm_call
+
+    monkeypatch.setenv("STARK_GROUPED_LANE_TILE", "512")
+    n, d, chains, groups = 3 * 512 + 37, 5, 5, 40
+    g = _sorted_groups(n, groups)
+    lane_tile, k_loc, first_gid, gl = grouped_layout(g, d + q)
+    assert lane_tile == 512 and n % lane_tile
+    edges = np.arange(lane_tile, n, lane_tile)
+    assert np.any(g[edges - 1] == g[edges])  # a group straddles a tile edge
+    ks = jax.random.split(jax.random.PRNGKey(q), 6)
+    xt = jax.random.normal(ks[0], (d, n))
+    zt = jax.random.normal(ks[1], (q, n))
+    y = 2.0 * jax.random.normal(ks[2], (n,))
+    beta = 0.3 * jax.random.normal(ks[3], (chains, d))
+    u = 0.5 * jax.random.normal(ks[4], (chains, groups, q))
+    ic = jax.random.normal(ks[5], (chains,))
+
+    ssr, sresid, gbeta, gu = _grouped_lmm_call(
+        beta, u, ic, xt, zt, y, jnp.asarray(gl), jnp.asarray(first_gid),
+        k_loc=k_loc, lane_tile=lane_tile, interpret=True,
+    )
+
+    hi = jax.lax.Precision.HIGHEST
+    mu = (
+        ic[:, None] + jnp.dot(beta, xt, precision=hi)
+        + jnp.einsum("cnq,qn->cn", u[:, g, :], zt, precision=hi)
+    )
+    resid = y - mu  # (C, N)
+    tiles = -(-n // lane_tile)
+    padded = jnp.pad(resid, ((0, 0), (0, tiles * lane_tile - n)))
+    ssr0 = jnp.sum(padded.reshape(chains, tiles, lane_tile) ** 2, axis=-1)
+    gu0 = jnp.stack(
+        [
+            jax.ops.segment_sum((resid * zt[j]).T, g, num_segments=groups).T
+            for j in range(q)
+        ],
+        axis=-1,
+    )
+    np.testing.assert_allclose(ssr, ssr0, rtol=2e-5)
+    for name, a, b in (
+        ("sresid", sresid, jnp.sum(resid, axis=1)),
+        ("gbeta", gbeta, jnp.dot(resid, xt.T, precision=hi)),
+        ("gu", gu, gu0),
+    ):
+        assert a.shape == b.shape, name
+        np.testing.assert_allclose(a, b, rtol=3e-4, atol=3e-4, err_msg=name)
 
 
 @pytest.mark.slow
