@@ -105,6 +105,13 @@ class PendingBlock:
     # the block (the loop's: ``stark_stream_ess``), or None
     ess: Any = None
     t_enq: float = 0.0  # seconds the dispatch took (the loop's)
+    # the device timeline (the loop's, ``time.perf_counter_ns``): when the
+    # dispatch had enqueued everything, and when the device had finished
+    # the block and its ESS row (the loop's waiter thread stamps it and
+    # sets ``done``; None where the wait raised)
+    dispatched_ns: int = 0
+    t_done_ns: Optional[int] = None
+    done: Any = None  # `threading.Event`
 
 
 def carried_state(state, step_size, inv_mass) -> Dict[str, Any]:

@@ -812,8 +812,7 @@ class CheesBlockKernel:
                              map_init_steps=cfg.map_init_steps):
             with telemetry.span("map_init", steps=cfg.map_init_steps,
                                 grad_evals=cfg.map_init_steps * chains):
-                carry = jax.block_until_ready(
-                    ap.init_j(key_init, z0, *ap.extra))
+                carry = telemetry.wait(ap.init_j(key_init, z0, *ap.extra))
         warm_span = telemetry.span("warmup", steps=cfg.num_warmup).open()
         if imported is not None:
             pr = ap.put_rep
@@ -890,7 +889,7 @@ class CheesBlockKernel:
             e = min(s + env.block_size, n)
             with env.trace.phase(
                     "warmup_block", start=s, end=e, **stage) as ph:
-                carry, (nd, nl) = jax.block_until_ready(ap.warm_j(
+                carry, (nd, nl) = telemetry.wait(ap.warm_j(
                     carry, wkeys[s:e], us[s:e], idxs[s:e],
                     aflags[s:e], wflags[s:e], *ap.extra,
                 ))

@@ -1207,7 +1207,7 @@ def _fleet_warmup(parts: _FleetParts, cfg, warm_keys, z0, data, seg, trace,
         e = min(s + seg, nw)
         with trace.phase("warmup_block", start=s, end=e,
                          fleet=int(z0.shape[0])):
-            state, da, welford, inv_mass, ndiv, _ = jax.block_until_ready(
+            state, da, welford, inv_mass, ndiv, *_ = jax.block_until_ready(
                 parts.v_seg(
                     wkeys[:, s:e], jnp.asarray(aflags[s:e]),
                     jnp.asarray(wflags[s:e]), state, da, welford, inv_mass,

@@ -181,9 +181,8 @@ def prepare_model_data(model: Model, data: PyTree) -> PyTree:
     if data is None:
         return None
     with telemetry.span("prepare_data", model=type(model).__name__) as sp:
-        out = jax.block_until_ready(
-            jax.tree.map(jnp.asarray, model.prepare_data(data))
-        )
+        out = telemetry.wait(
+            jax.tree.map(jnp.asarray, model.prepare_data(data)))
         sp.note(bytes_in=_tree_nbytes(data), bytes_out=_tree_nbytes(out))
         if isinstance(data, dict) and isinstance(out, dict):
             laid_out = [k for k in out if k not in data]

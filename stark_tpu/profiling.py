@@ -153,8 +153,9 @@ def _spans_from_phase_event(e: Dict[str, Any]) -> List[Dict[str, Any]]:
     hh = max(float(hh), 0.0) if isinstance(hh, (int, float)) else 0.0
     di = max(float(di), 0.0) if isinstance(di, (int, float)) else 0.0
     # the sub-attributions cannot exceed the block's own wall: scale
-    # down proportionally when an estimate overshoots (device_idle is
-    # an estimate on pipelined runs)
+    # down proportionally where they overshoot it (device_idle is measured
+    # on the device's timeline, which the host's record of the block need
+    # not cover; traces before PR 40 estimated it)
     if hh + di > dur and hh + di > 0:
         scale = dur / (hh + di)
         hh *= scale

@@ -21,7 +21,9 @@ in flight discards it — resume reconciliation (`drawstore.truncate_draws`)
 already accounts for the at-most-one-block skew between the draw store and
 the checkpoint.  The trace's ``sample_block`` events carry the overlap
 accounting (``t_wait_s`` / ``t_host_hidden_s`` / ``device_idle_s``) that
-`tools/trace_report.py` and bench.py surface as a device-idle fraction.
+`tools/trace_report.py` and bench.py surface as a device-idle fraction,
+measured from the device timeline: a waiter thread stamps when the device
+finished each block (``device_done_ns`` on its ``block.wait`` span).
 
 Auxiliary subsystems wired here (SURVEY.md §6):
   * metrics JSONL   — one line per block (max_rhat, min_ess, wall, divs)
@@ -40,6 +42,8 @@ import contextlib
 import dataclasses
 import json
 import os
+import queue
+import threading
 import time
 from typing import Any, Dict, Optional, Tuple
 
@@ -54,7 +58,7 @@ from .ops import quantize as _quantize
 from .model import Model
 from .platform import named_jit
 from .sampler import ChainBlockKernel, Posterior, SamplerConfig
-from .sampler import _constrain_draws
+from .sampler import _constrain_draws, tree_counters
 
 #: the streaming gate's device half: a block's accumulator
 #: (`kernels.base.StreamDiagState`, chains-batched) reduced to its ESS row
@@ -445,15 +449,19 @@ def _sample_until_converged(
     ))
     run.t_start = time.perf_counter()
     run.metrics_f = open(metrics_path, "a") if metrics_path else None
+    run.waiter = _DoneWaiter()
     try:
-        _setup(run, resume_from, reseed, draw_store_path)
-        _block_loop(run)
+        try:
+            _setup(run, resume_from, reseed, draw_store_path)
+            _block_loop(run)
+        finally:
+            if run.metrics_f:
+                run.metrics_f.close()
+            if run.draw_store is not None:
+                run.draw_store.close()
+        result = _collect(run, t_run0)
     finally:
-        if run.metrics_f:
-            run.metrics_f.close()
-        if run.draw_store is not None:
-            run.draw_store.close()
-    result = _collect(run, t_run0)
+        run.waiter.close()
     # the kernel holds `run.emit`: cut the cycle, nothing waits for a gc
     run.kernel = run.pending = None
     return result
@@ -513,10 +521,10 @@ class _Run:
     points: list = dataclasses.field(default_factory=list)
     forecast_draws: Optional[int] = None
     rate: Optional[float] = None
-    # overlap accounting: the previous cycle's host seconds and the running
-    # device-seconds-per-block estimate (exact whenever the host waited)
-    t_host_prev: float = 0.0
-    dev_est: Optional[float] = None
+    # the device timeline: the waiter that stamps each block's completion
+    # (`_DoneWaiter`), and the last processed block's stamp
+    waiter: Any = None
+    done_ns: Optional[int] = None
 
     @property
     def chains(self) -> int:
@@ -780,7 +788,7 @@ def _dispatch_next(run: _Run):
         pend = run.kernel.dispatch(
             key_block, length, run.diag, run.draws_dispatched)
         if profiled:
-            jax.block_until_ready(pend.outs)
+            telemetry.wait(pend.outs)
     run.diag, pend.key = pend.diag, run.key
     if run.stream_diag:
         # enqueued HERE, behind block k and ahead of block k+1: dispatched
@@ -788,10 +796,64 @@ def _dispatch_next(run: _Run):
         # flight, and the gate would serialise with the device
         pend.ess = _stream_ess(pend.diag)
     enq_span.close()
-    pend.t_enq = enq_span.seconds
+    pend.t_enq, pend.dispatched_ns = enq_span.seconds, enq_span.end_ns
+    run.waiter.put(pend)
     run.blocks_dispatched += 1
     run.draws_dispatched += length
     return pend
+
+
+class _DoneWaiter:
+    """One thread beside the block loop that blocks on every dispatched
+    block's outputs and ESS row, in dispatch order, and stamps
+    ``pend.t_done_ns`` when the device has finished them: the block
+    loop's own wait returns only when the HOST gets there, which says
+    nothing of the device when the host was late.  A wait that raises
+    leaves no stamp (the loop's own wait raises the same error); the
+    thread ends at `close`, after the blocks handed to it, and holds a
+    block only until the device has finished it.  `sample_until_converged`
+    owns it, from `_setup` to the end of `_collect`."""
+
+    #: how long `close` waits for the thread: past it (a device wedged on a
+    #: queued block) the daemon thread ends once the device lets it go
+    JOIN_S = 10.0
+
+    def __init__(self):
+        self._queue = queue.SimpleQueue()
+        self._thread = threading.Thread(
+            target=self._run, name="stark-block-done", daemon=True)
+        self._thread.start()
+
+    def put(self, pend):
+        pend.done = threading.Event()
+        self._queue.put(pend)
+
+    def _run(self):
+        while (pend := self._queue.get()) is not None:
+            try:
+                jax.block_until_ready((pend.outs, pend.ess))
+                pend.t_done_ns = time.perf_counter_ns()
+            except Exception:  # noqa: BLE001 — the loop's wait raises it
+                pass
+            finally:
+                pend.done.set()
+                del pend
+
+    def close(self):
+        if self._thread.is_alive():
+            self._queue.put(None)
+            self._thread.join(self.JOIN_S)
+
+
+def _device_done_ns(pend, ready_ns: int) -> int:
+    """When the device finished ``pend`` and its ESS row: the earlier of
+    two moments by which it had, the waiter's stamp and ``ready_ns``, when
+    the block loop had both (`_Block.ready_ns`).  The loop's is exact when the host waited; the
+    waiter's when the host came late, unless the loop held the
+    interpreter lock then (a lag of at most its switch interval)."""
+    if pend.done.wait(timeout=60.0) and pend.t_done_ns is not None:
+        return min(pend.t_done_ns, ready_ns)
+    return ready_ns
 
 
 @dataclasses.dataclass
@@ -802,6 +864,11 @@ class _Block:
     blk: int
     host: Any = None  # `backends.base.HostBlock`
     wait_span: Any = None
+    # when the loop had the block's outputs and ESS row (`block.wait`'s
+    # wait, or the gate's fetch of the row), and when the device had
+    # finished them (`_device_done_ns`)
+    ready_ns: int = 0
+    done_ns: int = 0
     rec: Any = None
     n_stuck: int = 0
     max_rhat: float = float("inf")
@@ -810,30 +877,16 @@ class _Block:
     diag_bytes: int = 0
     t_ckpt: float = 0.0
 
-    def host_since_wait(self) -> float:
-        """The host cycle so far: since the device's outputs arrived."""
-        return (time.perf_counter_ns() - self.wait_span.end_ns) / 1e9
-
 
 def _tree_counters(cfg, hb) -> dict:
-    """What a per-chain kernel's block cost, for the `block.gate` span, from
-    the gradient counts it hands over anyway (``HostBlock.ngrad``, chains x
-    transitions; nothing for the ensemble sampler): ``tree_leaves``, their
-    sum; ``divergent``; ``lane_iterations``, the longest tree of every
-    vmapped transition added up: the chains run a transition's loops in
-    lockstep until the last has finished (every round before a chain's last
-    builds its whole subtree, so the deepest chain is also the longest in
-    every round), which makes ``tree_leaves / (chains x lane_iterations)``
-    the share of lanes that did work; and for NUTS ``tree_depths``, the
-    transitions by trajectory depth 0..max_tree_depth
+    """What a per-chain kernel's block cost, for the `block.gate` span
+    (nothing for the ensemble sampler): `sampler.tree_counters`,
+    ``divergent`` and, for NUTS, ``tree_depths``, the transitions by
+    trajectory depth 0..max_tree_depth
     (`kernels.nuts.tree_depth_from_leaves`)."""
     if hb.ngrad is None:
         return {}
-    out = {
-        "tree_leaves": int(np.sum(hb.ngrad)),
-        "divergent": int(np.sum(hb.divergent)),
-        "lane_iterations": int(np.sum(np.max(hb.ngrad, axis=0))),
-    }
+    out = dict(tree_counters(hb.ngrad), divergent=int(np.sum(hb.divergent)))
     if cfg.kernel == "nuts":
         from .kernels.nuts import tree_depth_from_leaves
 
@@ -888,6 +941,7 @@ def _gate_block(run: _Run, b: _Block):
         # is the ESS row the device reduced its accumulator to behind the
         # block (`_dispatch_next`) and the chains' draw counts
         ess_vals, n_host = run.ap.collect(pend.ess)
+        b.ready_ns = time.perf_counter_ns()
         b.diag_bytes = int(ess_vals.nbytes + n_host.nbytes)
         diagnostics.uniform_count(n_host)  # ragged counts raise
     else:
@@ -1020,28 +1074,31 @@ def _checkpoint_block(run: _Run, b: _Block):
         )
 
 
-def _trace_block(run: _Run, b: _Block, next_in_flight: bool):
+def _trace_block(run: _Run, b: _Block):
     """One phase event (timing) + one health event (diagnostics) per block,
     emitted once the block's ENTIRE host cycle (diagnostics + persistence +
     checkpoint) is done. ``dur_s`` excludes the checkpoint time — the
     checkpoint phase has its own event and the per-run phase durations must
-    still tile the wall without double counting. Overlap accounting:
-    ``t_host_hidden_s`` is this block's host-cycle time that ran while the next
-    block computed on device; ``device_idle_s`` is the device idle the host
-    caused before this block ran — exact in sync mode (the whole previous host
-    cycle), estimated in pipelined mode from the latest
-    device-seconds-per-block observation (0 whenever the host had to wait, i.e.
-    the device never starved).  Both are bounded by the host-cycle totals, so
-    the summarized idle fraction (idle over sample_block + checkpoint phase
-    time) stays in [0, 1]."""
+    still tile the wall without double counting.  Overlap, from the device
+    timeline (`_DoneWaiter`): a block runs on the device from the later of
+    its dispatch's end and the previous block's completion to its own
+    completion; ``device_idle_s`` is the gap before this block's start
+    (after the previous block's completion: 0 for a run's first block),
+    ``t_host_hidden_s`` the part of this block's host cycle during which
+    the next block ran on the device (0 in sync mode).  Both are bounded by
+    the host-cycle totals, so the summarized idle fraction (idle over
+    sample_block + checkpoint phase time) stays in [0, 1]."""
     pend, rec, t_wait = b.pend, b.rec, b.wait_span.seconds
-    host_cycle = b.host_since_wait()
-    if run.sync_blocks:
-        hidden, idle = 0.0, run.t_host_prev
-    else:
-        hidden = host_cycle if next_in_flight else 0.0
-        idle = (0.0 if t_wait > 1e-4 or run.dev_est is None
-                else max(0.0, run.t_host_prev - run.dev_est))
+    now = time.perf_counter_ns()
+    host_cycle = (now - b.wait_span.end_ns) / 1e9
+    idle = hidden = 0.0
+    if run.done_ns is not None:  # the previous block's completion
+        idle = max(0, min(pend.dispatched_ns, b.done_ns) - run.done_ns) / 1e9
+    nxt = run.pending
+    if nxt is not None:
+        start = max(nxt.dispatched_ns, b.done_ns, b.wait_span.end_ns)
+        end = min(now, nxt.t_done_ns or now)
+        hidden = max(0, end - start) / 1e9
     forecast = {} if run.forecast_draws is None else {
         "ess_forecast": run.forecast_draws}
     run.trace.emit(
@@ -1099,7 +1156,7 @@ def _over_budget(run: _Run) -> bool:
     return over
 
 
-def _process_block(run: _Run, pend, next_in_flight: bool) -> bool:
+def _process_block(run: _Run, pend) -> bool:
     """Host side of ONE finished block, along its spans: wait, gate, record,
     checkpoint.  Returns True when the run stops (converged or over budget); an
     in-flight speculative block is then discarded by the caller."""
@@ -1110,24 +1167,23 @@ def _process_block(run: _Run, pend, next_in_flight: bool) -> bool:
     faults.fail_point("runner.block.pre")
     b = _Block(pend, run.blocks_done + 1)
     # waits only until the DEVICE finishes block k: k+1 may be running
-    b.wait_span = telemetry.span("block.wait", block=b.blk).open()
-    b.host = run.kernel.host_block(pend, energy=run.monitor is not None)
-    b.wait_span.close()
+    with telemetry.span("block.wait", block=b.blk) as b.wait_span:
+        telemetry.wait(pend.outs)
+        b.ready_ns = time.perf_counter_ns()
+        b.host = run.kernel.host_block(pend, energy=run.monitor is not None)
     _gate_block(run, b)
+    # the block's completion, ESS row included, once the gate has the row:
+    # on `block.wait`, whose own wait leaves the row to the gate
+    b.done_ns = _device_done_ns(pend, b.ready_ns)
+    b.wait_span.note(device_done_ns=b.done_ns)
     _record_block(run, b)
     _checkpoint_block(run, b)
     if run.trace.enabled:
-        _trace_block(run, b, next_in_flight)
+        _trace_block(run, b)
+    run.done_ns = b.done_ns
     # failpoint: crash/preempt after the block is fully accounted (metrics +
     # checkpoint durable), the next block in flight: the orphaned-block drill
     faults.fail_point("runner.block.post")
-
-    # overlap bookkeeping: the device-seconds estimate is exact when the host
-    # waited; the host cycle feeds the next block's idle attribution
-    t_wait = b.wait_span.seconds
-    if t_wait > 1e-4 or run.dev_est is None:
-        run.dev_est = t_wait if run.sync_blocks else run.t_host_prev + t_wait
-    run.t_host_prev = b.host_since_wait()
 
     if run.converged:
         return True
@@ -1136,7 +1192,10 @@ def _process_block(run: _Run, pend, next_in_flight: bool) -> bool:
         # returned (and persisted) result accounts for every draw
         run.budget_exhausted = True
         with telemetry.span("block.record", block=b.blk,
-                            event="budget_exhausted"):
+                            event="budget_exhausted") as rec_span:
+            # the window's seconds since the device finished the last block
+            # it counts: the host cycle that ends here
+            rec_span.note(tail_s=(rec_span.start_ns - b.done_ns) / 1e9)
             run.emit({
                 "event": "budget_exhausted",
                 "time_budget_s": float(run.time_budget_s),
@@ -1174,7 +1233,7 @@ def _block_loop(run: _Run):
             # the overlap: block k+1 starts on the device while the
             # host processes block k below
             run.pending = _dispatch_next(run)
-        if _process_block(run, current, run.pending is not None):
+        if _process_block(run, current):
             # the block in flight is discarded: the serial path never ran
             # it, and no persisted artifact shows its draws or key split
             break
@@ -1199,7 +1258,7 @@ def _collect(run: _Run, t_run0: float) -> AdaptiveResult:
             # device runs it to its end before anything queued behind it:
             # wait here, so that `collect.constrain` is the layout alone
             with telemetry.span("collect.drain", block=run.blocks_dispatched):
-                jax.block_until_ready(run.pending.outs)
+                telemetry.wait(run.pending.outs)
         with telemetry.span("collect.constrain", bytes=all_draws.nbytes):
             draws = _constrain_draws(fm, all_draws)
     result = AdaptiveResult(

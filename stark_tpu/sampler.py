@@ -207,8 +207,13 @@ def make_warmup_parts(fm: FlatModel, cfg: SamplerConfig):
 
       init_carry(key, z0, data) -> (state, da, welford, inv_mass)
       segment(keys, adapt_flags, wend_flags, state, da, welford, inv_mass,
-              data) -> (state, da, welford, inv_mass, n_div, n_grad)
+              data) -> (state, da, welford, inv_mass, n_div, n_grad,
+                        ngrad)
       finalize(da) -> step_size            (host-side, cheap)
+
+    ``ngrad`` is the gradient count of each transition of the segment
+    (its trees' leaves under NUTS), int32 (transitions,) a chain: what
+    `tree_counters` reads.
 
     ``state`` is what the chain carries (`chain_potential`): an `HMCState`,
     or for a model that can centre a `CentredState`, whose centre every
@@ -244,7 +249,8 @@ def make_warmup_parts(fm: FlatModel, cfg: SamplerConfig):
         # `chain_recentred`'s evaluation, where there is a centre to move
         n_grad = jnp.sum(ngrad) + int(isinstance(carried, CentredState))
         return (rewrap(state), da, welford, inv_mass,
-                jnp.sum(divergent.astype(jnp.int32)), n_grad)
+                jnp.sum(divergent.astype(jnp.int32)), n_grad,
+                ngrad.astype(jnp.int32))
 
     def finalize(da):
         if cfg.adapt_step_size:
@@ -276,7 +282,7 @@ def drive_segmented_warmup(cfg, v_init, v_seg, finalize, warm_keys, z0, data,
     # compile-stage phase covers them so phase sums tile the wall
     with trace.phase("compile", stage="warmup_init"):
         kinit = jax.vmap(lambda k: jax.random.split(k, 2))(warm_keys)
-        state, da, welford, inv_mass = jax.block_until_ready(
+        state, da, welford, inv_mass = telemetry.wait(
             v_init(kinit[:, 0], z0, data)
         )
         schedule = build_warmup_schedule(cfg.num_warmup)
@@ -296,19 +302,36 @@ def drive_segmented_warmup(cfg, v_init, v_seg, finalize, warm_keys, z0, data,
     for s in range(0, cfg.num_warmup, seg):
         e = min(s + seg, cfg.num_warmup)
         with trace.phase("warmup_block", start=s, end=e) as ph:
-            state, da, welford, inv_mass, ndiv, ngrad = jax.block_until_ready(
-                v_seg(wkeys[s:e], jnp.asarray(aflags[s:e]),
-                      jnp.asarray(wflags[s:e]), state, da, welford, inv_mass,
-                      data)
-            )
+            state, da, welford, inv_mass, ndiv, ngrad, leaves = (
+                telemetry.wait(v_seg(
+                    wkeys[s:e], jnp.asarray(aflags[s:e]),
+                    jnp.asarray(wflags[s:e]), state, da, welford, inv_mass,
+                    data)))
             if ngrad.is_fully_addressable:  # one process holds every chain
                 ph.note(steps=e - s, grad_evals=int(np.sum(ngrad)))
+                # the segment's trees, on its span alone (not the event)
+                telemetry.note(**tree_counters(leaves))
         telemetry.notify_progress()  # watchdog liveness beat per segment
         counts = (ndiv, ngrad) if counts is None else (
             counts[0] + ndiv, counts[1] + ngrad)
     if counts is None:
         counts = (jnp.zeros((warm_keys.shape[0],), jnp.int32),) * 2
     return state, finalize(da), inv_mass, counts
+
+
+def tree_counters(ngrad) -> Dict[str, int]:
+    """What a per-chain kernel's transitions cost, from their gradient
+    counts (``ngrad``, chains x transitions: a sampling block's
+    ``HostBlock.ngrad``, a warm-up segment's per-transition counts):
+    ``tree_leaves``, their sum; ``lane_iterations``, the longest tree of
+    every vmapped transition added up: the chains run a transition's
+    loops in lockstep until the last has finished (every round before a
+    chain's last builds its whole subtree, so the deepest chain is also
+    the longest in every round), which makes ``tree_leaves / (chains x
+    lane_iterations)`` the share of lanes that did work."""
+    ngrad = np.asarray(ngrad)
+    return {"tree_leaves": int(np.sum(ngrad)),
+            "lane_iterations": int(np.sum(np.max(ngrad, axis=0)))}
 
 
 def make_map_init(fm: FlatModel, cfg: SamplerConfig):
@@ -622,7 +645,7 @@ class ChainBlockKernel:
                 z0 = jax.vmap(fm.init_flat)(jax.random.split(key_init, chains))
             z0 = ap.put_chains(z0)
             warm_keys = ap.put_chains(jax.random.split(key_warm, chains))
-            jax.block_until_ready(z0)
+            telemetry.wait(z0)
         map_steps = self.cfg.map_init_steps if ap.map_init is not None else 0
         if map_steps:
             # the descent toward the mode, as the ensemble sampler's start
@@ -631,7 +654,7 @@ class ChainBlockKernel:
                                  map_init_steps=map_steps):
                 with telemetry.span("map_init", steps=map_steps,
                                     grad_evals=map_steps * chains):
-                    z0 = jax.block_until_ready(ap.map_init(z0, ap.data))
+                    z0 = telemetry.wait(ap.map_init(z0, ap.data))
         # warm-up runs as block_size-bounded dispatches too (checkpointable,
         # beats the watchdog); the segmented driver reads the ambient trace,
         # which the public wrapper pinned to THIS run's

@@ -570,6 +570,27 @@ def note(root: bool = False, **fields: Any) -> None:
         sp.fields.update(fields)
 
 
+def wait(x: Any) -> Any:
+    """``jax.block_until_ready(x)``, timed on the innermost open span: the
+    seconds waited are added to its ``device_wait_s`` (the path
+    `_on_jax_event` takes for ``compile_s``).  In a synchronous loop the
+    wait is the device's time for what was just dispatched, less whatever
+    ran while the host was still enqueuing: a lower bound on device-busy
+    time, tight when programs are cached.  With no span open it only
+    waits."""
+    import jax
+
+    sp = _OPEN_SPAN.get()
+    if sp is None:
+        return jax.block_until_ready(x)
+    t0 = time.perf_counter_ns()
+    out = jax.block_until_ready(x)
+    sp.fields["device_wait_s"] = (
+        sp.fields.get("device_wait_s", 0.0)
+        + (time.perf_counter_ns() - t0) / 1e9)
+    return out
+
+
 def span_log() -> List[SpanRecord]:
     """The closed spans the bounded log still holds, oldest close first."""
     return list(_SPAN_LOG)
@@ -1450,7 +1471,8 @@ def summarize_trace(events: List[Dict[str, Any]], run: Optional[int] = None
 
     ``overlap`` aggregates the runner's pipelined ``sample_block``
     accounting: total host work hidden behind device compute, total
-    estimated device idle, total host wait, and the idle fraction
+    device idle (both from the blocks' completion stamps), total host
+    wait, and the idle fraction
     (device_idle_s / total sample_block time — 0.0 when the device never
     starved).
 
