@@ -313,14 +313,20 @@ class FusedHierLogisticGrouped(HierLogistic):
     def prepare_data(self, data):
         if "gl" in data or "offsets_path" in data:
             return data  # already prepared (resume path)
-        from ..ops.hier_fused import prepare_grouped
+        from .. import telemetry
+        from ..ops.hier_fused import grouped_mxu_form, prepare_grouped
 
-        out = prepare_grouped(data, int(np.asarray(data["x"]).shape[1]))
+        d = int(np.asarray(data["x"]).shape[1])
+        out = prepare_grouped(data, d)
         if out is None:
             # degenerate grouping (tiny groups scattered wide): keep the
             # offset-path layout, just transposed
             out = _transpose_x(data)
             out["offsets_path"] = jnp.zeros((0,))
+            return out
+        # on the caller's `prepare_data` span: how the kernel will contract
+        form, rows = grouped_mxu_form(d, out["k_loc"].shape[0])
+        telemetry.note(mxu_form=form, mxu_rows=rows)
         return out
 
     def data_row_axes(self, data):

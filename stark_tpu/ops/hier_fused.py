@@ -31,17 +31,28 @@ grid*K_LOC ≈ 2k windowed indices — thousands of elements, not millions.
 HBM traffic per evaluation drops from ~644 MB (C=32) to ~136 MB, nearly
 all of it the unavoidable X stream.
 
-Why two dots and not four: at ``highest`` an f32 dot is six bf16 MXU
-passes, and a matmul instruction costs the same whether it uses 8 or 128
-of the array's rows, so a dot over the K_LOC window costs as much as the
-dot over D.  With the window contracted on its own (two more dots a tile)
-the kernel was MXU-issue-bound: 36.6 ms a call at N=16M, C=64, D=32,
+Why two dots, and why the kernel forms the bf16 products itself: a matmul
+instruction costs the same whether it uses 8 or 128 of the MXU's rows, and
+at ``highest`` an f32 dot is six single-pass bf16 products of its
+operands' three-way splits.  With the K_LOC window contracted on its own
+(four dots a tile) the kernel ran 36.6 ms a call at N=16M, C=64, D=32,
 K_LOC=8 on one v5e, 7.26 % of its HBM roofline (PERF_LEDGER.jsonl, PR 26,
-`hier_n16m.sample`); folded, 19.3 ms and 13.76 % (builder's chip run,
-PR 27: PERF.md §6; §3 there has the recipe to read the kernel's static
-schedule without a chip).  D + K_LOC <= 128 is one MXU tile of
-contraction; beyond it ceil((D + K_LOC)/128) <= ceil(D/128) +
-ceil(K_LOC/128), so the folded form never takes more tiles.
+`hier_n16m.sample`); with the window folded into the slab (two f32 dots,
+six passes each over 40 of 128 contraction rows) 16.66 ms and 15.9 %
+(ledger, PR 40), 12 036 bundles a tile of which the MXU filled 97.9 %.
+At ``highest`` (`grouped_mxu_form`) the kernel now splits the operands
+itself, rounding to nearest, and packs the six products into rows the
+passes left idle (`ops.precision.split6_operands`): the forward is one
+bf16 dot over a 216-row contraction, the backward one over the three
+residual splits stacked on the streamed axis, the six products added in
+f32.  8 089 bundles a tile at C = 64 (VALU-bound, 92.0 %) and 2 302 at
+C = 8, against 3 587 (my compiles for a described v5e, PR 41, by the
+README's recipe); on the chip one value-and-gradient of the op went from
+17.13 to 11.25 ms at C = 64 and from 5.41 to 3.91 at C = 8, and the
+beta-gradient of the cell's rows came seven times nearer float64 than
+the compiler's truncating passes put it (my chip runs, PR 41: PERF.md
+§6).  At ``high`` and ``default`` the two dots stay f32 at that
+precision.
 
 Capability parity: same posterior as `HierLogistic`/`FusedHierLogistic`
 (BASELINE.json:8 flagship config); reference tree absent (SURVEY.md §0),
@@ -69,6 +80,10 @@ from .logistic_fused import (
 )
 from .precision import (
     dot_precision as _dot_precision,
+    split6_backward as _split6_backward,
+    split6_forward as _split6_forward,
+    split6_operands as _split6_operands,
+    split6_rows as _split6_rows,
     stream_arg as _stream_arg,
     x_stream_dtype as _x_stream_dtype,
 )
@@ -77,6 +92,14 @@ from .precision import (
 # the MXU extra work stop being negligible next to the X stream, and the
 # layout falls back to the offset path.
 _K_LOC_MAX = 128
+
+# What the Bernoulli kernel may ask of the core's scoped VMEM (128 MiB on a
+# v5e; Mosaic's default limit is 16 MB).  Compiled for a described v5e at
+# TILE 8192, D = 32, K_LOC = 8 it takes 6.7 MiB at C = 64 and 8.9 at
+# C = 128 with the packed splits, 9.9 and 11.8 with six passes a dot (my
+# compiles, PR 41): `_check_chain_vmem`'s estimate is the looser, and half
+# this limit is its budget.
+_HIER_VMEM_LIMIT = 48 * 1024 * 1024
 
 
 def grouped_lane_tile(d: int) -> int:
@@ -205,7 +228,7 @@ def prepare_grouped(data, d_eff, transpose_keys=("x",)):
 
 
 def _check_chain_vmem(cpad, lane_tile, interpret, k_loc=0, q=1, slab_rows=0,
-                      transposed=0, budget=10 * 1024 * 1024):
+                      transposed=0, bf16_rows=0, budget=10 * 1024 * 1024):
     """The kernel holds ~3 (C, TILE) f32 intermediates (logits, resid,
     value terms) in scoped VMEM; past ~16 MB Mosaic refuses to compile
     (measured: C=128 at TILE=8192 asked for 20 MB).  The grouped kernels
@@ -214,7 +237,10 @@ def _check_chain_vmem(cpad, lane_tile, interpret, k_loc=0, q=1, slab_rows=0,
     large-K_LOC config could OOM past the C-only estimate), and the
     hierarchical kernel a stacked copy of the design slab and the one-hot
     (``slab_rows`` = D + K_LOC, and D + Q*K_LOC for the Gaussian kernel's
-    weighted one-hots).  ``transposed`` counts the (TILE, k)
+    weighted one-hots).  ``bf16_rows`` counts the rows of bfloat16
+    (·, TILE) operands: the packed slabs and the residual's three splits of
+    the hierarchical kernel at ``highest`` (`grouped_mxu_form`).
+    ``transposed`` counts the (TILE, k)
     operands a kernel transposes for a dot over the lanes: each is laid
     out in tiles of 128 lanes whatever k is (4 MB at TILE 8192).  The
     default ``budget`` is conservative (the OOM had >3 live (C, TILE)s);
@@ -226,6 +252,7 @@ def _check_chain_vmem(cpad, lane_tile, interpret, k_loc=0, q=1, slab_rows=0,
         3 * cpad * lane_tile * 4        # (C, TILE) logits/resid/val terms
         + 2 * k_loc * lane_tile * 4     # (K_LOC, TILE) one-hot + iota
         + slab_rows * lane_tile * 4     # (D + K_LOC, TILE) stacked slab
+        + bf16_rows * lane_tile * 2     # bfloat16 (., TILE) operands
         + cpad * q * k_loc * 4          # (C, Q*K_LOC) group window block
         + transposed * lane_tile * 128 * 4  # (TILE, k) in 128-lane tiles
     )
@@ -239,6 +266,18 @@ def _check_chain_vmem(cpad, lane_tile, interpret, k_loc=0, q=1, slab_rows=0,
             f"STARK_GROUPED_LANE_TILE, or use the offset-layout Fused "
             f"model, whose lane tile shrinks with the chain count"
         )
+
+
+def grouped_mxu_form(d: int, k_loc: int):
+    """(form, rows): how the grouped Bernoulli kernel contracts a tile at
+    the resolved `dot_precision`.  At ``highest``, ``"split6"``: the six
+    bf16 products formed by the kernel and packed into one contraction of
+    `split6_rows` (216 at D = 32, K_LOC = 8; `ops.precision`); otherwise
+    ``"lax"``: one f32 dot over D + K_LOC at that precision.  The kernel
+    builds its operands from this, and ``prepare_data`` notes it."""
+    if _dot_precision() == jax.lax.Precision.HIGHEST:
+        return "split6", _split6_rows(d, k_loc)
+    return "lax", d + k_loc
 
 
 def _make_grouped_kernel(n, lane_tile, k_loc, link):
@@ -256,26 +295,40 @@ def _make_grouped_kernel(n, lane_tile, k_loc, link):
         gl = jnp.where(mask, gl_ref[...], 0)  # (1, TILE) int32
         krows = jax.lax.broadcasted_iota(jnp.int32, (k_loc, lane_tile), 0)
         onehot = jnp.where(krows == gl, 1.0, 0.0)  # (K_LOC, TILE)
+        d = xt.shape[0]
+        form, rows = grouped_mxu_form(d, k_loc)
         # the group window rides in the design slab: [beta | alpha window]
-        # against [X ; one-hot] is X.beta + alpha[g] in one contraction
-        # over D + K_LOC, summed in the MXU's f32 accumulator
-        slab = jnp.concatenate([xt, onehot], axis=0)  # (D + K_LOC, TILE)
-        params = jnp.concatenate(
-            [beta_ref[...], alpha_ref[0]], axis=1
-        )  # (C, D + K_LOC) — beta resident, this tile's group window
-        logits = jax.lax.dot(
-            params, slab, precision=prec,
-            preferred_element_type=jnp.float32,
-        )  # (C, TILE) — offsets never touch HBM
+        # against [X ; one-hot] is X.beta + alpha[g] in one contraction,
+        # summed in the MXU's f32 accumulator
+        if form == "split6":
+            # `highest`'s six bf16 products, packed: the forward's
+            # contraction holds all six (rows deep), the backward streams
+            # the residual's three splits against [X splits ; one-hot]
+            fwd, bwd = _split6_operands(xt, onehot)
+            assert fwd.shape[0] == rows, (fwd.shape, rows)
+            logits = _split6_forward(beta_ref[...], alpha_ref[0], fwd)
+        else:
+            slab = jnp.concatenate([xt, onehot], axis=0)  # (D + K_LOC, TILE)
+            params = jnp.concatenate(
+                [beta_ref[...], alpha_ref[0]], axis=1
+            )  # (C, D + K_LOC) — beta resident, this tile's group window
+            logits = jax.lax.dot(
+                params, slab, precision=prec,
+                preferred_element_type=jnp.float32,
+            )  # (C, TILE) — offsets never touch HBM
         val_terms, resid = _link_parts(link, y, logits, mask)  # (C, TILE)
         val_ref[...] = jnp.sum(val_terms, axis=1)[None, :, None]
-        grads = jax.lax.dot(
-            resid, slab.T, precision=prec,
-            preferred_element_type=jnp.float32,
-        )  # (C, D + K_LOC): [beta partial | group-gradient partials]
-        d = xt.shape[0]
-        gbeta_ref[...] = grads[:, :d][None]  # (1, C, D)
-        galpha_ref[...] = grads[:, d:][None]  # (1, C, K_LOC)
+        # [beta partial | group-gradient partials]
+        if form == "split6":
+            gbeta, galpha = _split6_backward(resid, bwd, d)
+        else:
+            grads = jax.lax.dot(
+                resid, slab.T, precision=prec,
+                preferred_element_type=jnp.float32,
+            )  # (C, D + K_LOC)
+            gbeta, galpha = grads[:, :d], grads[:, d:]
+        gbeta_ref[...] = gbeta[None]  # (1, C, D)
+        galpha_ref[...] = galpha[None]  # (1, C, K_LOC)
 
     return kernel
 
@@ -297,8 +350,13 @@ def _grouped_call(beta, alpha, xt, y, gl, first_gid, *, k_loc, lane_tile,
     n = xt.shape[1]
     grid = -(-n // lane_tile)
     cpad = -(-c // 8) * 8
+    form, rows = grouped_mxu_form(d, k_loc)
+    operands = (  # the packed slabs and the residual's splits, or the slab
+        dict(bf16_rows=rows + 3 * d + k_loc + 3 * cpad, transposed=1)
+        if form == "split6" else dict(slab_rows=d + k_loc)
+    )
     _check_chain_vmem(cpad, lane_tile, interpret, k_loc=k_loc,
-                      slab_rows=d + k_loc)
+                      budget=_HIER_VMEM_LIMIT // 2, **operands)
     if cpad != c:
         beta = jnp.pad(beta, ((0, cpad - c), (0, 0)))
         alpha = jnp.pad(alpha, ((0, cpad - c), (0, 0)))
@@ -345,6 +403,9 @@ def _grouped_call(beta, alpha, xt, y, gl, first_gid, *, k_loc, lane_tile,
         out_shape=out_shape,
         interpret=interpret,
         name="stark_hier_ll_grouped",
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=_HIER_VMEM_LIMIT
+        ),
     )(*args)
     if center is not None:  # a row a chain, beside the tiles' (C, 1)
         center = jnp.pad(center.astype(jnp.float32), (0, cpad - c))[:, None]

@@ -57,10 +57,124 @@ __all__ = [
     "fused_value_and_grad",
     "precision_statics",
     "quant_percentile",
+    "split6_backward",
+    "split6_forward",
+    "split6_operands",
+    "split6_rows",
+    "split_bf16x3",
     "stream_arg",
     "x_stream_config",
     "x_stream_dtype",
 ]
+
+
+def split_bf16x3(x):
+    """(hi, mid, lo): three float32 arrays, each exactly a bfloat16, whose
+    sum is ``x``.
+
+    ``hi = bf16(x)``, ``mid = bf16(x - hi)``, ``lo = x - hi - mid``, each
+    conversion rounding to nearest and each difference exact in float32:
+    hi holds the top 8 significant bits, mid the next 8 and lo the 8 that
+    remain, so ``hi + mid + lo == x`` for every normal float32, and mid and
+    lo are as often negative as positive.  The terms stay float32 so that a
+    caller stacks them first and converts the stack once
+    (`split6_operands`).
+
+    Rounding, not truncation, matters in the products: the chip's compiler
+    splits an f32 dot's operands at ``highest`` by clearing their low 16
+    bits (PERF.md §6, PR 41), which leaves mid and lo with the sign of
+    ``x``; through the three products `highest` leaves out, such a lo
+    moves every logit along ``x`` the way a shifted ``beta`` would, and the
+    flagship's beta-gradient, a sum over 16M rows of such rows, read 2.3
+    times further from float64 than the kernel's own passes did (my chip
+    run, PR 41)."""
+    def round_bf16(v):
+        return v.astype(jnp.bfloat16).astype(jnp.float32)
+
+    x = x.astype(jnp.float32)
+    hi = round_bf16(x)
+    rest = x - hi
+    mid = round_bf16(rest)
+    return hi, mid, rest - mid
+
+
+# `highest` on the MXU: an f32 dot is the six single-pass products of the
+# operands' bf16 splits that are larger than float32's rounding, hi·hi,
+# hi·mid, mid·hi, hi·lo, lo·hi and mid·mid (jax.lax.DotAlgorithmPreset.
+# BF16_BF16_F32_X6), each over the whole contraction, added in float32.
+# Where the contraction is short, a pass leaves most of the array's 128
+# rows idle, so the functions below form the six products themselves and
+# pack them into one contraction (forward) or one streamed block
+# (backward): one bf16 dot for each, not six passes.  An operand ``e``
+# that bfloat16 holds exactly (a one-hot) has no mid or lo term: only its
+# three products with the other side's splits enter.
+
+
+def split6_rows(d: int, k: int) -> int:
+    """The packed forward contraction's height for ``d`` split rows and
+    ``k`` exact rows: six blocks of ``d`` and three of ``k``."""
+    return 6 * d + 3 * k
+
+
+def split6_operands(x, e):
+    """A tile's packed operands, formed once for both dots.
+
+    ``x`` (D, T) float32 and ``e`` (K, T), exact in bfloat16 ->
+    ``fwd`` (6D + 3K, T) bfloat16, rows [x_hi; x_mid; x_lo; e; e; x_hi;
+    x_mid; x_hi; e] (`split6_forward`), and ``bwd`` (3D + K, T) bfloat16,
+    rows [x_hi; x_mid; x_lo; e] (`split6_backward`)."""
+    hi, mid, lo = split_bf16x3(x)
+    e = e.astype(jnp.float32)
+    fwd = jnp.concatenate([hi, mid, lo, e, e, hi, mid, hi, e], axis=0)
+    bwd = jnp.concatenate([hi, mid, lo, e], axis=0)
+    return fwd.astype(jnp.bfloat16), bwd.astype(jnp.bfloat16)
+
+
+def split6_forward(w, a, fwd):
+    """``w @ x + a @ e`` (C, T) float32 at `highest`'s six products, as one
+    bf16 dot whose contraction holds them all.
+
+    ``w`` (C, D) and ``a`` (C, K) float32, ``fwd`` from `split6_operands`.
+    The parameters' row [w_hi | w_hi | w_hi | a_hi | a_mid | w_mid | w_mid
+    | w_lo | a_lo] meets the slab's blocks as hi·hi, hi·mid, hi·lo, mid·hi,
+    mid·mid and lo·hi over ``x`` and the three terms of ``a`` over ``e``,
+    all added in the MXU's float32 accumulator."""
+    wh, wm, wl = split_bf16x3(w)
+    ah, am, al = split_bf16x3(a)
+    params = jnp.concatenate([wh, wh, wh, ah, am, wm, wm, wl, al], axis=1)
+    return jax.lax.dot(
+        params.astype(jnp.bfloat16), fwd,
+        precision=jax.lax.Precision.DEFAULT,
+        preferred_element_type=jnp.float32,
+    )
+
+
+def split6_backward(r, bwd, d: int):
+    """``(r @ xᵀ (C, D), r @ eᵀ (C, K))`` float32 at `highest`'s six
+    products, as one bf16 dot over the streamed axis.
+
+    ``r`` (C, T) float32, ``bwd`` from `split6_operands`, ``d`` its split
+    rows.  [r_hi; r_mid; r_lo] (3C, T) against ``bwd``'s transpose gives
+    nine (C, D) blocks and three (C, K); the six that `highest` forms and
+    the three over ``e`` are added in float32, smallest first.  The MXU
+    also forms r_mid·x_lo, r_lo·x_mid and r_lo·x_lo: they are left out, as
+    `highest` leaves them out."""
+    c = r.shape[0]
+    rows = jnp.concatenate(split_bf16x3(r), axis=0).astype(jnp.bfloat16)
+    g = jax.lax.dot(
+        rows, bwd.T, precision=jax.lax.Precision.DEFAULT,
+        preferred_element_type=jnp.float32,
+    )  # (3C, 3D + K): rows r_hi, r_mid, r_lo; columns x_hi, x_mid, x_lo, e
+
+    def blk(i, j, width=d):
+        return g[i * c:(i + 1) * c, j * d:j * d + width]
+
+    gx = ((blk(2, 0) + blk(0, 2)) + blk(1, 1)) + (
+        (blk(1, 0) + blk(0, 1)) + blk(0, 0)
+    )
+    k = bwd.shape[0] - 3 * d
+    ge = (blk(2, 3, k) + blk(1, 3, k)) + blk(0, 3, k)
+    return gx, ge
 
 
 def dot_precision():
@@ -68,14 +182,16 @@ def dot_precision():
 
     f32 matmuls on the TPU MXU are EMULATED in bf16 passes: DEFAULT is
     one pass (inputs truncated to bf16), HIGH three passes (~f32-accurate),
-    HIGHEST six.  The grouped hierarchical kernel runs two dots per tile
-    (the group window is folded into the design slab, ops/hier_fused.py)
-    and at HIGHEST is bound by MXU and VPU issue slots, not by HBM:
-    13.76 % of its HBM roofline on `hier_n16m.sample` (builder's chip
-    run, PR 27; 7.26 % with four dots, PERF_LEDGER.jsonl PR 26), while the
-    chain-batched logistic kernel reads 61.9 % — the knob exists so the
-    on-chip roofline can measure the precision/throughput trade and the
-    sampler can adopt the cheapest setting whose posterior matches
+    HIGHEST six (the six largest cross products of three-way splits).
+    At HIGHEST the grouped hierarchical kernel forms those six products
+    itself, packed into the MXU rows six short passes leave idle
+    (`split6_forward`, `split6_backward`; ops/hier_fused.py): its static
+    schedule at `hier_n16m.sample`'s shapes went from 12 036 bundles a
+    tile, 97.9 % of them MXU, to 8 089 (my compiles, PR 41); the
+    chain-batched logistic kernel still runs its dots as six passes, and
+    reads 64.2 % of its HBM roofline (ledger, PR 40).  The knob exists
+    so the on-chip roofline can measure the precision/throughput trade
+    and the sampler can adopt the cheapest setting whose posterior matches
     (tools/precision_parity.py is that gate).  Default stays HIGHEST:
     numerics never change silently.
     """

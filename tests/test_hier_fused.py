@@ -202,6 +202,16 @@ def _lmm_kernel_jaxpr(q):
     ).jaxpr
 
 
+def _dot_eqns(jaxpr):
+    out = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "dot_general":
+            out.append(eqn)
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            out += _dot_eqns(sub)
+    return out
+
+
 @pytest.mark.parametrize("kernel, q", [
     ("stark_hier_ll_grouped", None),
     ("stark_lmm_ll_grouped", 1),
@@ -213,11 +223,252 @@ def test_grouped_kernel_body_holds_two_dots(kernel, q):
     random effects: at `highest` every further dot costs six MXU passes over
     the whole (C, TILE) block, however few of the array's rows it uses (the
     four-dot hier form ran at 7 % of the roofline: PERF.md, PR 27; the LMM's
-    2·Q + 2 dots spilled half its schedule: PR 39)."""
+    2·Q + 2 dots spilled half its schedule: PR 39).  The Bernoulli kernel at
+    `highest` forms the six bf16 products itself (PR 41): both of its dots
+    take bfloat16 operands and accumulate in float32, one MXU pass each."""
     jaxpr = _hier_kernel_jaxpr() if q is None else _lmm_kernel_jaxpr(q)
     (call,) = [e for e in jaxpr.eqns if e.primitive.name == "pallas_call"]
     assert call.params["name"] == kernel
     assert _count_primitive(call.params["jaxpr"], "dot_general") == 2
+    if q is None:
+        for eqn in _dot_eqns(call.params["jaxpr"]):
+            assert [v.aval.dtype for v in eqn.invars] == [jnp.bfloat16] * 2
+            assert eqn.params["preferred_element_type"] == jnp.float32
+            assert eqn.outvars[0].aval.dtype == jnp.float32
+
+
+def _round_bf16(v):
+    """NumPy's bfloat16 rounding to nearest, ties to even, in float32."""
+    bits = np.asarray(v, np.float32).view(np.uint32).astype(np.uint64)
+    bits = (bits + 0x7FFF + ((bits >> 16) & 1)) & 0xFFFF0000
+    return bits.astype(np.uint32).view(np.float32)
+
+
+def _np_split3(v):
+    v = np.asarray(v, np.float32)
+    hi = _round_bf16(v)
+    mid = _round_bf16(v - hi)
+    return [t.astype(np.float64) for t in (hi, mid, v - hi - mid)]
+
+
+#: (i, j) of the six products `highest` forms (hi 0, mid 1, lo 2) and of
+#: the three it leaves out
+_SIX = [(0, 0), (0, 1), (1, 0), (0, 2), (2, 0), (1, 1)]
+_NINE = _SIX + [(1, 2), (2, 1), (2, 2)]
+
+
+def _np_products(a, b, pairs):
+    """float64 sum over ``pairs`` of a's split i times b's split j."""
+    sa, sb = _np_split3(a), _np_split3(b)
+    return sum(sa[i] @ sb[j] for i, j in pairs)
+
+
+@pytest.mark.parametrize("sign", [1.0, -1.0])
+@pytest.mark.parametrize("scale", [1e-20, 3e-8, 1e-3, 1.0, 7e2, 1e30])
+def test_split_bf16x3_reconstructs_float32(sign, scale):
+    """hi + mid + lo is x to the bit, each term a bfloat16 rounded to
+    nearest, at the magnitudes a slab, a parameter or a residual holds
+    (subnormals aside); each term is at most 2**-8 of the one before, and
+    mid takes both signs whatever the sign of x."""
+    from stark_tpu.ops.precision import split_bf16x3
+
+    x = sign * scale * np.abs(np.random.default_rng(3).standard_normal(4096))
+    x = np.concatenate([x, sign * scale * np.array([1, 1 + 2**-8 + 2**-16,
+                                                    2 - 2**-23])])
+    x = x.astype(np.float32)
+    hi, mid, lo = (np.asarray(t) for t in split_bf16x3(jnp.asarray(x)))
+    for t, t0 in zip((hi, mid, lo), _np_split3(x)):
+        assert t.dtype == np.float32
+        np.testing.assert_array_equal(t, t0)
+        np.testing.assert_array_equal(_round_bf16(t), t)
+    np.testing.assert_array_equal((hi + mid) + lo, x)
+    np.testing.assert_array_equal(
+        hi.astype(np.float64) + mid + lo, x.astype(np.float64))
+    assert np.all(np.abs(mid) <= 2.0**-8 * np.abs(hi))
+    assert np.all(np.abs(lo) <= 2.0**-8 * np.abs(mid))
+    assert np.any(mid > 0) and np.any(mid < 0)
+
+
+def _cancelling_case(rows, rng):
+    """Two factors, (rows, 4) each, whose six `highest` products add to 0
+    over the 4 while the three it leaves out do not.  Entries are h + m + l
+    with h = ±1, m = ±2**-10, l = ±2**-20 (each its own rounded split); the
+    signs of the first factor's (h, m, l) and of the second's are, over the
+    4, s = (1, 1, 1, 1), t = u = (1, -1, -1, 1) and S = (1, 1, -1, -1),
+    T = (1, -1, 1, -1), U = (1, -1, -1, 1): s·S = s·T = t·S = s·U = u·S =
+    t·T = 0, so the products hh, hm, mh, hl, lh and mm cancel, every partial
+    sum a float32 in any order, while m·l and l·l add up to 4 (2**-30 +
+    2**-40).  Each row takes a random sign of its own."""
+    def pattern(p, q, r):
+        return np.array(p) + 2.0**-10 * np.array(q) + 2.0**-20 * np.array(r)
+
+    first = pattern((1, 1, 1, 1), (1, -1, -1, 1), (1, -1, -1, 1))
+    second = pattern((1, 1, -1, -1), (1, -1, 1, -1), (1, -1, -1, 1))
+    signs = [rng.choice([-1.0, 1.0], size=(rows, 1)) for _ in range(2)]
+    return tuple((sg * v[None]).astype(np.float32)
+                 for sg, v in zip(signs, (first, second)))
+
+
+def _one_hot(k, t, rng):
+    return np.eye(k, dtype=np.float32)[:, rng.integers(0, k, t)]
+
+
+@pytest.mark.parametrize("values", ["cancelling", "normal"])
+def test_split6_forward_is_highests_six_products(values):
+    """[w | a] against [x ; e] through the packed contraction is the float64
+    sum of `highest`'s six products of the rounded splits (a's three terms
+    over the exact one-hot) to float32 rounding; where those cancel and the
+    three `highest` leaves out do not, it is 0, not the nine-product sum."""
+    from stark_tpu.ops.precision import (
+        split6_forward, split6_operands, split6_rows)
+
+    rng = np.random.default_rng(5)
+    if values == "cancelling":
+        c, d, k, t = 8, 4, 8, 16
+        w, xt = _cancelling_case(c, rng)
+        _, x = _cancelling_case(t, rng)
+        x = x.T  # (4, t): the second factor along the contraction
+        a = np.zeros((c, k), np.float32)
+    else:
+        c, d, k, t = 8, 32, 8, 256
+        w, a = rng.standard_normal((c, d)), rng.standard_normal((c, k))
+        x = rng.standard_normal((d, t))
+        w, a, x = (v.astype(np.float32) for v in (w, a, x))
+    e = _one_hot(k, t, rng)
+    fwd, bwd = split6_operands(jnp.asarray(x), jnp.asarray(e))
+    assert fwd.dtype == bwd.dtype == jnp.bfloat16
+    assert fwd.shape == (split6_rows(d, k), t)
+    assert bwd.shape == (3 * d + k, t)
+    got = np.asarray(split6_forward(jnp.asarray(w), jnp.asarray(a), fwd))
+    assert got.dtype == np.float32 and got.shape == (c, t)
+    a_part = sum(_np_split3(a)) @ e.astype(np.float64)
+    six = _np_products(w, x, _SIX) + a_part
+    nine = _np_products(w, x, _NINE) + a_part
+    if values == "cancelling":
+        np.testing.assert_array_equal(six, 0.0)
+        np.testing.assert_array_equal(got, 0.0)
+        np.testing.assert_allclose(np.abs(nine), 4 * (2.0**-30 + 2.0**-40))
+    else:
+        size = np.abs(w).astype(np.float64) @ np.abs(x) + np.abs(a) @ e
+        assert np.max(np.abs(got - six) / size) < 2.0**-21
+
+
+@pytest.mark.parametrize("values", ["cancelling", "normal"])
+def test_split6_backward_is_highests_six_products(values):
+    """The residual's three splits streamed against [x splits ; e]: the
+    six products of r·xᵀ that `highest` forms, and r·eᵀ, each to float32
+    rounding of its float64 sum; the MXU's three further products are left
+    out, so where the six cancel and those do not the result is 0."""
+    from stark_tpu.ops.precision import split6_backward, split6_operands
+
+    rng = np.random.default_rng(6)
+    if values == "cancelling":
+        c, d, k = 16, 32, 8
+        r, _ = _cancelling_case(c, rng)
+        _, x = _cancelling_case(d, rng)  # (d, 4): streamed over 4 lanes
+    else:
+        c, d, k = 16, 32, 8
+        r = rng.standard_normal((c, 64)).astype(np.float32)
+        x = rng.standard_normal((d, 64)).astype(np.float32)
+    e = _one_hot(k, r.shape[1], rng)
+    _, bwd = split6_operands(jnp.asarray(x), jnp.asarray(e))
+    gx, ge = (np.asarray(v) for v in split6_backward(jnp.asarray(r), bwd, d))
+    assert gx.shape == (c, d) and ge.shape == (c, k)
+    six = _np_products(r, x.T, _SIX)
+    nine = _np_products(r, x.T, _NINE)
+    ge0 = sum(_np_split3(r)) @ e.T.astype(np.float64)
+    if values == "cancelling":
+        np.testing.assert_array_equal(six, 0.0)
+        np.testing.assert_array_equal(gx, 0.0)
+        np.testing.assert_allclose(np.abs(nine), 4 * (2.0**-30 + 2.0**-40))
+        np.testing.assert_array_equal(ge, ge0.astype(np.float32))
+    else:
+        size = np.abs(r).astype(np.float64) @ np.abs(x.T)
+        assert np.max(np.abs(gx - six) / size) < 2.0**-21
+        np.testing.assert_allclose(ge, ge0, rtol=0, atol=2.0**-21 * np.max(
+            np.abs(r).astype(np.float64) @ e.T))
+
+
+@pytest.mark.parametrize("chains", [8, 64])
+def test_grouped_kernel_matches_autodiff_at_chain_width(chains):
+    """The packed kernel at the NUTS cell's width (8) and the ChEES cell's
+    (64) against the plain autodiff oracle of
+    `test_grouped_matches_autodiff_value_and_grads`, at its tolerances:
+    value and every parameter gradient, chain by chain."""
+    ref, rdata, grp, gdata = _models()
+    ks = jax.random.split(jax.random.PRNGKey(chains), 4)
+    params = {
+        "beta": 0.3 * jax.random.normal(ks[0], (chains, 8)),
+        "alpha0": 0.3 + 0.1 * jax.random.normal(ks[1], (chains,)),
+        "sigma_alpha": jnp.full((chains,), 0.7),
+        "alpha_raw": 0.5 * jax.random.normal(ks[3], (chains, 50)),
+    }
+
+    def vg(model, data):
+        return jax.vmap(jax.value_and_grad(lambda p: model.log_lik(p, data)))(
+            params)
+
+    v_ref, g_ref = vg(ref, rdata)
+    v_grp, g_grp = vg(grp, gdata)
+    np.testing.assert_allclose(v_ref, v_grp, rtol=2e-5)
+    for k in params:
+        np.testing.assert_allclose(
+            np.asarray(g_ref[k]), np.asarray(g_grp[k]), rtol=2e-4,
+            atol=1e-4, err_msg=k,
+        )
+
+
+def test_grouped_width_8_batch_matches_per_chain_calls():
+    """Eight chains through the op's batching rule (one packed call of
+    width 8, as `hier_n16m.nuts` runs it) against eight one-chain calls."""
+    from stark_tpu.ops.hier_fused import hier_logistic_loglik
+
+    _, _, _, gdata = _models()
+    kb, ka = jax.random.split(jax.random.PRNGKey(8))
+    beta = 0.3 * jax.random.normal(kb, (8, 8))
+    alpha = 0.5 * jax.random.normal(ka, (8, 50))
+
+    def vg(b, a):
+        return jax.value_and_grad(
+            lambda b, a: hier_logistic_loglik(
+                b, a, gdata["xT"], gdata["y"], gdata["gl"],
+                gdata["first_gid"], gdata["k_loc"], gdata["lt128"]),
+            argnums=(0, 1))(b, a)
+
+    v_b, (gb_b, ga_b) = jax.vmap(vg)(beta, alpha)
+    for i in range(8):
+        v, (gb, ga) = vg(beta[i], alpha[i])
+        np.testing.assert_allclose(np.asarray(v_b[i]), np.asarray(v), rtol=2e-5)
+        np.testing.assert_allclose(
+            np.asarray(gb_b[i]), np.asarray(gb), rtol=2e-4, atol=1e-4)
+        np.testing.assert_allclose(
+            np.asarray(ga_b[i]), np.asarray(ga), rtol=2e-4, atol=1e-4)
+
+
+@pytest.mark.parametrize("precision, form, rows", [
+    (None, "split6", 6 * 8 + 3 * 56),
+    ("high", "lax", 8 + 56),
+    ("default", "lax", 8 + 56),
+])
+def test_prepare_span_names_the_mxu_form(precision, form, rows, monkeypatch):
+    """`prepare_data` says how the kernel will contract a tile, from the
+    function the kernel builds its operands with: the packed six products
+    at `highest` (6·D + 3·K_LOC rows deep), one dot at the precision
+    otherwise; the layout's own fields are as they were."""
+    from stark_tpu import telemetry
+    from stark_tpu.ops.hier_fused import grouped_mxu_form
+
+    if precision is None:
+        monkeypatch.delenv("STARK_FUSED_PRECISION", raising=False)
+    else:
+        monkeypatch.setenv("STARK_FUSED_PRECISION", precision)
+    _, _, _, gdata = _models()
+    (sp,) = [r for r in telemetry.span_log() if r.name == "prepare_data"][-1:]
+    assert gdata["k_loc"].shape[0] == 56  # 50 groups in one tile
+    assert (sp.fields["mxu_form"], sp.fields["mxu_rows"]) == (form, rows)
+    assert grouped_mxu_form(8, 56) == (form, rows)
+    assert (sp.fields["lane_tile"], sp.fields["k_loc"], sp.fields["tiles"]) \
+        == (8192, 56, 1)
 
 
 @pytest.mark.parametrize("q", [1, 2, 3])

@@ -1,6 +1,7 @@
-"""The chip's compiler on the grouped Gaussian kernel at the benchmark's tile
-(PR 32): Mosaic refused it at its default scoped-VMEM limit, which no
-interpreted test could see.  Compiled here for a described TPU v5e, with no
+"""The chip's compiler on the grouped kernels at the benchmark's tile: the
+Gaussian one (PR 32), which Mosaic refused at its default scoped-VMEM limit,
+which no interpreted test could see, and the Bernoulli one's packed bf16
+products (PR 41).  Compiled here for a described TPU v5e, with no
 chip: nothing runs, so this says nothing of results or times.  One file, one
 fixture: only the worker that is given this file loads the TPU's library."""
 
@@ -50,3 +51,30 @@ def test_grouped_lmm_kernel_fits_the_cores_vmem_at_the_cells_tile(one_chip):
     text = compiled.as_text()
     assert "tpu_custom_call" in text and "stark_lmm_ll_grouped" in text
     assert _LMM_VMEM_LIMIT > 16 * 1024 * 1024  # Mosaic's default refused it
+
+
+@pytest.mark.parametrize("chains", [8, 64])
+def test_grouped_bernoulli_kernel_compiles_packed_at_the_cells_tile(
+        one_chip, chains, monkeypatch):
+    """The flagship's kernel at `highest` with the six bf16 products packed
+    (two bf16 dots a tile) at its cells' tile and widths: the 8 chains of
+    `hier_n16m.nuts` and the 64 of `hier_n16m.sample`."""
+    from stark_tpu.ops.hier_fused import _grouped_call, grouped_mxu_form
+
+    monkeypatch.delenv("STARK_FUSED_PRECISION", raising=False)
+    d, groups, tile, k_loc = 32, 1000, 8192, 8
+    assert grouped_mxu_form(d, k_loc) == ("split6", 216)
+    n = 4 * tile
+
+    def shape(dims, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    def call(beta, alpha, xt, y, gl, first_gid):
+        return _grouped_call(beta, alpha, xt, y, gl, first_gid, k_loc=k_loc,
+                             lane_tile=tile, interpret=False)
+
+    text = jax.jit(call).lower(
+        shape((chains, d)), shape((chains, groups)), shape((d, n)),
+        shape((n,)), shape((n,), jnp.int32),
+        shape((n // tile,), jnp.int32)).compile().as_text()
+    assert "tpu_custom_call" in text and "stark_hier_ll_grouped" in text
