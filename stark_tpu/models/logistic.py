@@ -250,11 +250,19 @@ class FusedLogistic(TransposedXMixin, Logistic):
         ``y`` costs).  ``data["y"]`` stays the caller's array.  Written in
         ``jax.numpy``: rows sharded over a mesh are laid out shard by
         shard.  Data that already holds ``xT`` is returned as it is, with
-        or without the leaf."""
+        or without the leaf.  The caller's ``prepare_data`` span gets
+        ``mxu_form`` and ``mxu_rows``: how the chain-batched kernel will
+        contract a tile (`ops.logistic_fused.batched_mxu_form`), the same
+        on every shard."""
         if "xT" in data:
             return data
+        from .. import telemetry
+        from ..ops.logistic_fused import batched_mxu_form
+
         out = _transpose_x(data)
         out[Y_LANES] = jnp.asarray(data["y"]).astype(jnp.float32)[None, :]
+        form, rows = batched_mxu_form(int(np.shape(data["x"])[1]))
+        telemetry.note(mxu_form=form, mxu_rows=rows)
         return out
 
     def log_lik(self, p, data):

@@ -33,8 +33,17 @@ read unspecified values).
 Two kernels.  The chain-batched one (`_make_batched_kernel`,
 ``stark_logistic_ll``: what every vmapped ensemble, and so every benchmark
 cell of this model, runs) holds the (C, D) block of all chains' beta and
-does two MXU dots a tile at `_dot_precision()`: logits (C, D) x (D, TILE),
-then the gradient (C, TILE) x (TILE, D); one X pass serves all chains.
+does two MXU dots a tile: logits (C, D) x (D, TILE), then the gradient
+(C, TILE) x (TILE, D); one X pass serves all chains.  At ``highest``
+(`batched_mxu_form`) it forms that precision's six bf16 products itself,
+rounding to nearest, and packs them into the rows six short passes leave
+idle (`ops.precision.split6_operands`): the forward one bf16 dot over a
+6·D-row contraction, the backward one of the residual's three splits
+against the slab's three, the six products added in f32.  Compiled for a
+described v5e at C = 8, D = 32, TILE 8192 a tile went from 2 920 bundles
+(MXU 52.6 %, VALU 47.7 %, stores 46.9 %) to 1 943 (50.2 / 75.1 / 28.9 %),
+under Mosaic's default scoped-VMEM limit (PERF.md §5).  At ``high`` and
+``default`` the two dots stay f32 at that precision.
 The one-chain kernel (`_make_kernel`, ``stark_logistic_ll_1chain``) does
 its matvec on the VPU (multiply + sublane/lane reductions): with one
 chain the work is bandwidth-bound, so the MXU buys nothing, and Mosaic
@@ -68,6 +77,10 @@ from jax.experimental import pallas as pl
 from .precision import (
     dot_precision,
     precision_statics,
+    split6_backward as _split6_backward,
+    split6_forward as _split6_forward,
+    split6_operands as _split6_operands,
+    split6_rows as _split6_rows,
     stream_arg,
     x_stream_dtype,
 )
@@ -217,6 +230,18 @@ def _make_kernel(n, lane_tile, with_offset, link):
     return kernel
 
 
+def batched_mxu_form(d: int):
+    """(form, rows): how the chain-batched kernel contracts a tile at the
+    resolved `dot_precision`.  At ``highest``, ``"split6"``: the six bf16
+    products formed by the kernel and packed into one contraction of
+    `split6_rows` (6·D, 192 at D = 32; `ops.precision`); otherwise
+    ``"lax"``: one f32 dot over D at that precision.  The kernel builds
+    its operands from this, and `FusedLogistic.prepare_data` notes it."""
+    if _dot_precision() == jax.lax.Precision.HIGHEST:
+        return "split6", _split6_rows(d, 0)
+    return "lax", d
+
+
 def _make_batched_kernel(n, lane_tile, with_offset, link):
     """Chain-batched tile kernel: one X slab read serves ALL chains.
 
@@ -247,11 +272,21 @@ def _make_batched_kernel(n, lane_tile, with_offset, link):
         # (The add of a non-constant offset AFTER a complete dot lowers
         # fine on Mosaic — verified on-chip; the header's accumulator
         # caveat applies to accumulating INTO the dot.)
-        prec = _dot_precision()
-        logits = jax.lax.dot(
-            beta, xt, precision=prec,
-            preferred_element_type=jnp.float32,
-        )  # (C, TILE) — MXU
+        d = xt.shape[0]
+        form, rows = batched_mxu_form(d)
+        if form == "split6":
+            # `highest`'s six bf16 products, packed: the forward's
+            # contraction holds all six (rows deep), the backward streams
+            # the residual's three splits against the slab's three
+            fwd, bwd = _split6_operands(xt)
+            assert fwd.shape[0] == rows, (fwd.shape, rows)
+            logits = _split6_forward(beta, None, fwd)  # (C, TILE)
+        else:
+            prec = _dot_precision()
+            logits = jax.lax.dot(
+                beta, xt, precision=prec,
+                preferred_element_type=jnp.float32,
+            )  # (C, TILE) — MXU
         if off_ref is not None:
             logits = logits + jnp.where(mask, off_ref[...], 0.0)  # (C, TILE)
         val_terms, resid = _link_parts(link, y, logits, mask)  # (C, TILE)
@@ -259,10 +294,14 @@ def _make_batched_kernel(n, lane_tile, with_offset, link):
         if resid_ref is not None:
             resid_ref[...] = resid
         # (C, TILE) x (TILE, D) -> (C, D) — second MXU pass, in-VMEM
-        grad_ref[...] = jax.lax.dot(
-            resid, xt.T, precision=prec,
-            preferred_element_type=jnp.float32,
-        )[None]
+        if form == "split6":
+            grad, _ = _split6_backward(resid, bwd, d)
+        else:
+            grad = jax.lax.dot(
+                resid, xt.T, precision=prec,
+                preferred_element_type=jnp.float32,
+            )
+        grad_ref[...] = grad[None]
 
     return kernel
 

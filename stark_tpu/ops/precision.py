@@ -107,7 +107,9 @@ def split_bf16x3(x):
 # pack them into one contraction (forward) or one streamed block
 # (backward): one bf16 dot for each, not six passes.  An operand ``e``
 # that bfloat16 holds exactly (a one-hot) has no mid or lo term: only its
-# three products with the other side's splits enter.
+# three products with the other side's splits enter.  Without one (``e``
+# and its parameters ``a`` None, K = 0: the chain-batched logistic kernel)
+# only the split rows are packed.
 
 
 def split6_rows(d: int, k: int) -> int:
@@ -116,17 +118,23 @@ def split6_rows(d: int, k: int) -> int:
     return 6 * d + 3 * k
 
 
-def split6_operands(x, e):
+def split6_operands(x, e=None):
     """A tile's packed operands, formed once for both dots.
 
     ``x`` (D, T) float32 and ``e`` (K, T), exact in bfloat16 ->
     ``fwd`` (6D + 3K, T) bfloat16, rows [x_hi; x_mid; x_lo; e; e; x_hi;
     x_mid; x_hi; e] (`split6_forward`), and ``bwd`` (3D + K, T) bfloat16,
-    rows [x_hi; x_mid; x_lo; e] (`split6_backward`)."""
+    rows [x_hi; x_mid; x_lo; e] (`split6_backward`).  With ``e`` None
+    (K = 0) ``fwd`` is [x_hi; x_mid; x_lo; x_hi; x_mid; x_hi] and ``bwd``
+    [x_hi; x_mid; x_lo]."""
     hi, mid, lo = split_bf16x3(x)
-    e = e.astype(jnp.float32)
-    fwd = jnp.concatenate([hi, mid, lo, e, e, hi, mid, hi, e], axis=0)
-    bwd = jnp.concatenate([hi, mid, lo, e], axis=0)
+    if e is None:
+        fwd, bwd = [hi, mid, lo, hi, mid, hi], [hi, mid, lo]
+    else:
+        e = e.astype(jnp.float32)
+        fwd, bwd = [hi, mid, lo, e, e, hi, mid, hi, e], [hi, mid, lo, e]
+    fwd = jnp.concatenate(fwd, axis=0)
+    bwd = jnp.concatenate(bwd, axis=0)
     return fwd.astype(jnp.bfloat16), bwd.astype(jnp.bfloat16)
 
 
@@ -138,10 +146,15 @@ def split6_forward(w, a, fwd):
     The parameters' row [w_hi | w_hi | w_hi | a_hi | a_mid | w_mid | w_mid
     | w_lo | a_lo] meets the slab's blocks as hi·hi, hi·mid, hi·lo, mid·hi,
     mid·mid and lo·hi over ``x`` and the three terms of ``a`` over ``e``,
-    all added in the MXU's float32 accumulator."""
+    all added in the MXU's float32 accumulator.  With ``a`` None (a slab
+    made without ``e``) the row is [w_hi | w_hi | w_hi | w_mid | w_mid |
+    w_lo] and the result ``w @ x``."""
     wh, wm, wl = split_bf16x3(w)
-    ah, am, al = split_bf16x3(a)
-    params = jnp.concatenate([wh, wh, wh, ah, am, wm, wm, wl, al], axis=1)
+    if a is None:
+        params = jnp.concatenate([wh, wh, wh, wm, wm, wl], axis=1)
+    else:
+        ah, am, al = split_bf16x3(a)
+        params = jnp.concatenate([wh, wh, wh, ah, am, wm, wm, wl, al], axis=1)
     return jax.lax.dot(
         params.astype(jnp.bfloat16), fwd,
         precision=jax.lax.Precision.DEFAULT,
@@ -158,7 +171,8 @@ def split6_backward(r, bwd, d: int):
     nine (C, D) blocks and three (C, K); the six that `highest` forms and
     the three over ``e`` are added in float32, smallest first.  The MXU
     also forms r_mid·x_lo, r_lo·x_mid and r_lo·x_lo: they are left out, as
-    `highest` leaves them out."""
+    `highest` leaves them out.  A slab made without ``e`` (3D rows) gives
+    ``(r @ xᵀ, None)``."""
     c = r.shape[0]
     rows = jnp.concatenate(split_bf16x3(r), axis=0).astype(jnp.bfloat16)
     g = jax.lax.dot(
@@ -173,6 +187,8 @@ def split6_backward(r, bwd, d: int):
         (blk(1, 0) + blk(0, 1)) + blk(0, 0)
     )
     k = bwd.shape[0] - 3 * d
+    if k == 0:
+        return gx, None
     ge = (blk(2, 3, k) + blk(1, 3, k)) + blk(0, 3, k)
     return gx, ge
 
@@ -183,13 +199,15 @@ def dot_precision():
     f32 matmuls on the TPU MXU are EMULATED in bf16 passes: DEFAULT is
     one pass (inputs truncated to bf16), HIGH three passes (~f32-accurate),
     HIGHEST six (the six largest cross products of three-way splits).
-    At HIGHEST the grouped hierarchical kernel forms those six products
-    itself, packed into the MXU rows six short passes leave idle
-    (`split6_forward`, `split6_backward`; ops/hier_fused.py): its static
+    At HIGHEST the grouped hierarchical kernel and the chain-batched
+    logistic kernel form those six products themselves, packed into the
+    MXU rows six short passes leave idle (`split6_forward`,
+    `split6_backward`; `hier_fused.grouped_mxu_form`,
+    `logistic_fused.batched_mxu_form`): the grouped kernel's static
     schedule at `hier_n16m.sample`'s shapes went from 12 036 bundles a
-    tile, 97.9 % of them MXU, to 8 089 (my compiles, PR 41); the
-    chain-batched logistic kernel still runs its dots as six passes, and
-    reads 64.2 % of its HBM roofline (ledger, PR 40).  The knob exists
+    tile, 97.9 % of them MXU, to 8 089, and the logistic kernel's at
+    `logistic_n20m.sample`'s from 2 920 to 1 943 (compiles for a
+    described v5e; PERF.md §5).  The knob exists
     so the on-chip roofline can measure the precision/throughput trade
     and the sampler can adopt the cheapest setting whose posterior matches
     (tools/precision_parity.py is that gate).  Default stays HIGHEST:

@@ -516,6 +516,21 @@ def _link_op_case(op):
             p["beta"], p["off"], x.T, y
         )
         plain = lambda p: _bernoulli_logit_loglik(x @ p["beta"] + p["off"], y)
+    elif op.startswith("gaussian"):
+        sigma = 0.7
+
+        def plain_at(mu):
+            return jnp.sum(jax.scipy.stats.norm.logpdf(y, mu, sigma))
+
+        if op == "gaussian_loglik":
+            fused = lambda p: logistic_fused.gaussian_loglik(
+                p["beta"], x.T, y, sigma)
+            plain = lambda p: plain_at(x @ p["beta"])
+        else:
+            params["off"] = jax.random.normal(k[3], (chains, n))
+            fused = lambda p: logistic_fused.gaussian_offset_loglik(
+                p["beta"], p["off"], x.T, y, sigma)
+            plain = lambda p: plain_at(x @ p["beta"] + p["off"])
     else:
         g = np.sort(np.random.RandomState(0).randint(0, groups, size=n))
         lane_tile, k_loc, first_gid, gl = hier_fused.grouped_layout(g, d)
@@ -535,14 +550,18 @@ def _link_op_case(op):
 
 @pytest.mark.parametrize("batched", [False, True], ids=["one_chain", "vmapped"])
 @pytest.mark.parametrize(
-    "op", ["logistic_loglik", "logistic_offset_loglik", "hier_logistic_loglik"]
+    "op", ["logistic_loglik", "logistic_offset_loglik", "hier_logistic_loglik",
+           "gaussian_loglik", "gaussian_offset_loglik"]
 )
 def test_link_through_each_kernel_matches_plain_autodiff(op, batched):
     """All three kernels that share the link (`stark_logistic_ll_1chain`
     and `stark_logistic_ll` by the vmap rule, with and without offsets;
     `stark_hier_ll_grouped`), through their public ops under the
     interpreter: value and every gradient against autodiff of
-    models/logistic.py's plain log-likelihood."""
+    models/logistic.py's plain log-likelihood; and the Gaussian link's two
+    ops through the same two kernels, against the normal log-density.  At
+    the default precision the vmapped ones run the packed bf16 products
+    (`batched_mxu_form`)."""
     fused, plain, params = _link_op_case(op)
     if batched:
         run = lambda f: jax.vmap(jax.value_and_grad(f))(params)
@@ -592,3 +611,269 @@ def test_link_spends_two_exp_one_log_one_reciprocal():
               "expm1", "pow", "rsqrt", "sqrt", "integer_pow"}
     assert sorted(n for n in names if n in costly) == [
         "div", "exp", "exp", "log1p"], names
+
+
+# --- the chain-batched kernel's packed bf16 products ------------------------
+# At `highest` `stark_logistic_ll` forms the six bf16 products of its two
+# dots itself and packs them into one contraction each way
+# (`batched_mxu_form`, `ops.precision.split6_*` with no exact rows).
+
+
+def _packed_case(link, with_offsets, chains):
+    """(beta (chains, 5), xt, y, offsets or None): logits of scale ~4 so
+    both tails of the Bernoulli link carry weight; a ragged last tile at
+    the default lane tile."""
+    n, d = 8192 + 37, 5
+    k = jax.random.split(jax.random.PRNGKey(41), 4)
+    xt = jax.random.normal(k[0], (d, n))
+    beta = 1.8 * jax.random.normal(k[1], (chains, d))
+    offsets = (
+        jax.random.normal(k[2], (chains, n)) if with_offsets else None
+    )
+    y = jax.random.uniform(k[3], (n,))
+    if link == "bernoulli_logit":
+        y = (y < 0.4).astype(jnp.float32)
+    return beta, xt, y, offsets
+
+
+def _float64_parts(link, beta, xt, y, offsets):
+    """(value (C,), beta-gradient (C, D), residual (C, N)) of the kernel's
+    outputs in float64 from the same float32 inputs."""
+    x = np.asarray(xt, np.float64)
+    z = np.asarray(beta, np.float64) @ x
+    if offsets is not None:
+        z = z + np.asarray(offsets, np.float64)
+    y = np.asarray(y, np.float64)
+    if link == "bernoulli_logit":
+        val = (y * z - np.maximum(z, 0) - np.log1p(np.exp(-np.abs(z)))).sum(1)
+        resid = y - 1.0 / (1.0 + np.exp(-z))
+    else:
+        resid = y - z
+        val = (resid * resid).sum(1)
+    return val, resid @ x.T, resid
+
+
+def _batched(beta, xt, y, offsets, link):
+    from stark_tpu.ops import logistic_fused as lf
+
+    return jax.jit(functools.partial(
+        lf._batched_call, lane_tile=None, interpret=True, link=link,
+    ))(beta, xt, y, offsets)
+
+
+def _rel_err(got, ref):
+    got, ref = np.asarray(got, np.float64), np.asarray(ref, np.float64)
+    return float(np.max(np.abs(got - ref)) / np.max(np.abs(ref)))
+
+
+@pytest.mark.parametrize("chains", [1, 3, 8], ids=["c1", "c3", "c8"])
+@pytest.mark.parametrize("with_offsets", [False, True], ids=["noff", "off"])
+@pytest.mark.parametrize("link", ["bernoulli_logit", "gaussian"])
+def test_packed_batched_kernel_against_float64(link, with_offsets, chains,
+                                               monkeypatch):
+    """Every variant of `stark_logistic_ll` at `highest` (Bernoulli and
+    Gaussian link, with and without offsets; one chain, three padded to
+    eight, eight; a ragged last tile) against float64 of the same float32
+    inputs.  The value and the beta-gradient, sums over the rows, are no
+    further off than the parent's two f32 dots at `highest` put them on the
+    same inputs (the kernel with `batched_mxu_form` held at its ``lax``
+    form), beyond float32's rounding of the largest entry; the residual,
+    row by row, is within four float32 roundings of its largest entry."""
+    from stark_tpu.ops import logistic_fused as lf
+
+    monkeypatch.delenv("STARK_FUSED_PRECISION", raising=False)
+    assert lf.batched_mxu_form(5) == ("split6", 30)
+    case = _packed_case(link, with_offsets, chains)
+    ref = _float64_parts(link, *case)
+    packed = _batched(*case, link)
+    monkeypatch.setattr(lf, "batched_mxu_form", lambda d: ("lax", d))
+    plain = _batched(*case, link)
+    assert len(packed) == len(plain) == (3 if with_offsets else 2)
+    for name, p, q, r in zip(("value", "grad", "resid"), packed, plain, ref):
+        assert p.shape == q.shape == r.shape, name
+        assert np.all(np.isfinite(np.asarray(p))), name
+        if name == "resid":
+            assert _rel_err(p, r) <= 4 * 2.0**-23, (name, _rel_err(p, r))
+        else:
+            assert _rel_err(p, r) <= 1.1 * _rel_err(q, r) + 2.0**-23, (
+                name, _rel_err(p, r), _rel_err(q, r))
+
+
+@pytest.mark.parametrize("bad", [np.inf, np.nan], ids=["inf", "nan"])
+@pytest.mark.parametrize("with_offsets", [False, True], ids=["noff", "off"])
+@pytest.mark.parametrize("link", ["bernoulli_logit", "gaussian"])
+def test_packed_kernel_keeps_a_diverged_chain_to_itself(link, with_offsets,
+                                                        bad, monkeypatch):
+    """One chain of three diverged (a non-finite coefficient): its value is
+    non-finite, and every output of its neighbours is the sound run's to
+    the bit, though the packed dots carry all chains in one block each way."""
+    monkeypatch.delenv("STARK_FUSED_PRECISION", raising=False)
+    beta, xt, y, offsets = _packed_case(link, with_offsets, 3)
+    sound = _batched(beta, xt, y, offsets, link)
+    out = _batched(beta.at[1, 0].set(bad), xt, y, offsets, link)
+    assert not np.isfinite(np.asarray(out[0])[1])
+    for a, b in zip(out, sound):
+        np.testing.assert_array_equal(np.asarray(a)[[0, 2]],
+                                      np.asarray(b)[[0, 2]])
+
+
+@pytest.mark.parametrize("precision, form, rows", [
+    (None, "split6", 192),
+    ("highest", "split6", 192),
+    ("high", "lax", 32),
+    ("default", "lax", 32),
+])
+def test_batched_mxu_form_follows_the_resolved_precision(
+        precision, form, rows, monkeypatch):
+    """`batched_mxu_form` at the cells' width: the packed six products, a
+    6·D-row contraction, where STARK_FUSED_PRECISION resolves to `highest`
+    (its default); one f32 dot over D otherwise."""
+    from stark_tpu.ops.logistic_fused import batched_mxu_form
+    from stark_tpu.ops.precision import split6_rows
+
+    if precision is None:
+        monkeypatch.delenv("STARK_FUSED_PRECISION", raising=False)
+    else:
+        monkeypatch.setenv("STARK_FUSED_PRECISION", precision)
+    assert batched_mxu_form(32) == (form, rows)
+    assert split6_rows(32, 0) == 6 * 32
+
+
+def _kernel_dots(jaxpr):
+    (call,) = [e for e in jaxpr.eqns if e.primitive.name == "pallas_call"]
+    out = []
+
+    def walk(j):
+        for eqn in j.eqns:
+            if eqn.primitive.name == "dot_general":
+                out.append(eqn)
+            for sub in _sub_jaxprs(eqn):
+                walk(sub)
+
+    walk(call.params["jaxpr"])
+    return call.params["name"], out
+
+
+@pytest.mark.parametrize("precision", ["highest", "high", "default"])
+@pytest.mark.parametrize("with_offsets", [False, True], ids=["noff", "off"])
+def test_batched_kernel_holds_two_dots_at_every_precision(
+        precision, with_offsets, monkeypatch):
+    """Two dots a tile at every precision: at `highest` both take bfloat16
+    operands at one pass and accumulate in float32 (the packed six
+    products); at `high` and `default` both are the f32 dot at that
+    precision the kernel has always run."""
+    from _kernel_jaxpr_fixtures import _batched_jaxpr
+
+    monkeypatch.setenv("STARK_FUSED_PRECISION", precision)
+    name, dots = _kernel_dots(
+        _batched_jaxpr("bernoulli_logit", "off" if with_offsets else "noff"
+                       ).jaxpr)
+    assert name == "stark_logistic_ll" and len(dots) == 2
+    want = {"highest": (jnp.bfloat16, jax.lax.Precision.DEFAULT),
+            "high": (jnp.float32, jax.lax.Precision.HIGH),
+            "default": (jnp.float32, jax.lax.Precision.DEFAULT)}[precision]
+    for eqn in dots:
+        assert [v.aval.dtype for v in eqn.invars] == [want[0]] * 2
+        assert eqn.params["precision"] in (want[1], (want[1], want[1]))
+        assert eqn.params["preferred_element_type"] == jnp.float32
+        assert eqn.outvars[0].aval.dtype == jnp.float32
+
+
+def _recorded_cases():
+    from _kernel_jaxpr_fixtures import CASES
+
+    return [f"{case}@{precision}" for precision, case in CASES]
+
+
+@pytest.mark.parametrize("case", _recorded_cases())
+def test_kernel_jaxpr_is_the_recorded_one(case):
+    """The kernels the packed batched form must leave alone trace to the
+    jaxpr recorded before it existed (`tests/_kernel_jaxpr_fixtures.py`):
+    the grouped Bernoulli kernel at `highest`, which runs the same helpers
+    with exact rows, and the chain-batched kernel at `high` and `default`,
+    every link and offset variant."""
+    import json
+
+    from _kernel_jaxpr_fixtures import FIXTURE, kernel_jaxpr
+
+    with open(FIXTURE) as f:
+        recorded = json.load(f)
+    assert set(recorded) == set(_recorded_cases())
+    name, precision = case.split("@")
+    assert kernel_jaxpr(precision, name) == recorded[case]
+
+
+@pytest.mark.parametrize("values", ["cancelling", "normal"])
+def test_split6_without_exact_rows_is_highests_six_products(values):
+    """The packed helpers with no exact rows (K = 0), as the chain-batched
+    kernel runs them: the forward contraction over [x_hi; x_mid; x_lo;
+    x_hi; x_mid; x_hi] (6·D rows) and the backward over [x_hi; x_mid; x_lo]
+    (3·D) each give the float64 sum of `highest`'s six products of the
+    rounded splits to float32 rounding, and 0 where those cancel while the
+    three `highest` leaves out do not; no exact-row block comes back."""
+    from _bf16_splits import (
+        _NINE, _SIX, _cancelling_case, _np_products)
+    from stark_tpu.ops.precision import (
+        split6_backward, split6_forward, split6_operands, split6_rows)
+
+    rng = np.random.default_rng(7)
+    if values == "cancelling":
+        c, d, t = 8, 4, 16
+        w, _ = _cancelling_case(c, rng)
+        _, x = _cancelling_case(t, rng)
+        x = x.T  # (4, t): the second factor along the contraction
+        r, _ = _cancelling_case(c, rng)
+        _, xb = _cancelling_case(d, rng)  # (d, 4): streamed over 4 lanes
+    else:
+        c, d, t = 8, 32, 256
+        w = rng.standard_normal((c, d)).astype(np.float32)
+        x = rng.standard_normal((d, t)).astype(np.float32)
+        r = rng.standard_normal((c, t)).astype(np.float32)
+        xb = x
+    fwd, bwd = split6_operands(jnp.asarray(x))
+    assert fwd.dtype == bwd.dtype == jnp.bfloat16
+    assert fwd.shape == (split6_rows(d, 0), t) and bwd.shape == (3 * d, t)
+    got = np.asarray(split6_forward(jnp.asarray(w), None, fwd))
+    assert got.dtype == np.float32 and got.shape == (c, t)
+    _, bwd = split6_operands(jnp.asarray(xb))
+    gx, ge = split6_backward(jnp.asarray(r), bwd, d)
+    gx = np.asarray(gx)
+    assert ge is None and gx.shape == (c, d)
+    for out, a, b in ((got, w, x), (gx, r, xb.T)):
+        six, nine = _np_products(a, b, _SIX), _np_products(a, b, _NINE)
+        if values == "cancelling":
+            np.testing.assert_array_equal(six, 0.0)
+            np.testing.assert_array_equal(out, 0.0)
+            np.testing.assert_allclose(np.abs(nine),
+                                       4 * (2.0**-30 + 2.0**-40))
+        else:
+            size = np.abs(a).astype(np.float64) @ np.abs(b)
+            assert np.max(np.abs(out - six) / size) < 2.0**-21
+
+
+@pytest.mark.parametrize("precision, form, rows", [
+    (None, "split6", 6 * 8),
+    ("high", "lax", 8),
+    ("default", "lax", 8),
+])
+def test_prepare_span_names_the_batched_mxu_form(precision, form, rows,
+                                                 monkeypatch):
+    """`FusedLogistic`'s `prepare_data` says how `stark_logistic_ll` will
+    contract a tile, from the function the kernel builds its operands with,
+    on the `prepare_data` span; the layout's own fields are as they were."""
+    from stark_tpu import telemetry
+    from stark_tpu.model import prepare_model_data
+    from stark_tpu.models import FusedLogistic
+    from stark_tpu.ops.logistic_fused import batched_mxu_form
+
+    if precision is None:
+        monkeypatch.delenv("STARK_FUSED_PRECISION", raising=False)
+    else:
+        monkeypatch.setenv("STARK_FUSED_PRECISION", precision)
+    data, _ = synth_logistic_data(jax.random.PRNGKey(3), 300, 8)
+    prepared = prepare_model_data(FusedLogistic(8), dict(data))
+    (sp,) = [r for r in telemetry.span_log() if r.name == "prepare_data"][-1:]
+    assert (sp.fields["mxu_form"], sp.fields["mxu_rows"]) == (form, rows)
+    assert batched_mxu_form(8) == (form, rows)
+    assert sp.fields["kernel_layout"] == "xT,y_lanes"
+    assert prepared["xT"].shape == (8, 300)

@@ -1,9 +1,10 @@
-"""The chip's compiler on the grouped kernels at the benchmark's tile: the
-Gaussian one (PR 32), which Mosaic refused at its default scoped-VMEM limit,
-which no interpreted test could see, and the Bernoulli one's packed bf16
-products (PR 41).  Compiled here for a described TPU v5e, with no
-chip: nothing runs, so this says nothing of results or times.  One file, one
-fixture: only the worker that is given this file loads the TPU's library."""
+"""The chip's compiler on the fused kernels at the benchmark's tile: the
+grouped Gaussian one, which Mosaic refused at its default scoped-VMEM limit,
+which no interpreted test could see, and the packed bf16 products of the
+grouped Bernoulli one and of the chain-batched logistic one.  Compiled here
+for a described TPU v5e, with no chip: nothing runs, so this says nothing of
+results or times.  One file, one fixture: only the worker that is given this
+file loads the TPU's library."""
 
 import jax
 import jax.numpy as jnp
@@ -78,3 +79,33 @@ def test_grouped_bernoulli_kernel_compiles_packed_at_the_cells_tile(
         shape((n,)), shape((n,), jnp.int32),
         shape((n // tile,), jnp.int32)).compile().as_text()
     assert "tpu_custom_call" in text and "stark_hier_ll_grouped" in text
+
+
+@pytest.mark.parametrize("with_offsets", [False, True], ids=["noff", "off"])
+def test_batched_logistic_kernel_compiles_packed_at_the_cells_tile(
+        one_chip, with_offsets, monkeypatch):
+    """The chain-batched logistic kernel at `highest` with the six bf16
+    products packed (two bf16 dots a tile) at the logistic cells' shapes: 8
+    chains, D = 32, TILE 8192, `y` as the (1, N) operand the model lays
+    out; with offsets as the hierarchical and mixed models' offset paths
+    call it.  It fits Mosaic's default scoped-VMEM limit: the call passes
+    none."""
+    from stark_tpu.ops.logistic_fused import _batched_call, batched_mxu_form
+
+    monkeypatch.delenv("STARK_FUSED_PRECISION", raising=False)
+    c, d, tile = 8, 32, 8192
+    assert batched_mxu_form(d) == ("split6", 192)
+    n = 4 * tile
+
+    def shape(dims, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    def call(beta, xt, y, offsets):
+        return _batched_call(beta, xt, y, offsets, lane_tile=tile,
+                             interpret=False)
+
+    offsets = shape((c, n)) if with_offsets else None
+    text = jax.jit(call).lower(
+        shape((c, d)), shape((d, n)), shape((1, n)), offsets
+    ).compile().as_text()
+    assert "tpu_custom_call" in text and "stark_logistic_ll" in text
